@@ -204,11 +204,12 @@ def timing_program_terms(irs, n_archs: int = 1) -> dict:
     """Roofline terms for one batched static-timing pass over lowered
     PackIRs (``repro.core.pack_ir``), at ``n_archs`` delay rows.
 
-    The vectorized analyzer is a float64 gather/add/max workload: per LUT
-    row it gathers 6 arrivals + 6x3 edge components (3 adds each), a
-    6-way max and 3 node adds; per chain bit two 3-add operand edges, a
-    3-way max and the carry add.  Bytes count the arrival-buffer gathers/
-    scatters (8 B doubles) — intensity is low, so unlike the bitwise
+    The vectorized analyzer is an int32 gather/add/max workload
+    (centi-picosecond arrivals): per LUT row it gathers 6 arrivals + 6x3
+    edge components (3 adds each), a 6-way max and 3 node adds; per
+    chain bit two 3-add operand edges, a 3-way max and the carry add.
+    Bytes count the arrival-buffer gathers/scatters (4 B) — intensity is
+    low, so unlike the bitwise
     evaluator the timing pass is memory-bound, and batching arch rows
     amortizes the index traffic rather than the flops."""
     flops = 0
@@ -219,8 +220,8 @@ def timing_program_terms(irs, n_archs: int = 1) -> dict:
         levels = max(levels, ir.n_levels)
         for M, C, B in zip(m, c, b):
             flops += M * (6 * 3 + 5 + 3) + C * B * (2 * 3 + 2 + 1) + C * 3
-            bytes_ += M * (6 * 8 + 6 * 4 * 2 + 8) \
-                + C * B * (2 * 8 + 2 * 4 * 2 + 8) + C * (8 + 4 + 8)
+            bytes_ += M * (6 * 4 + 6 * 4 * 2 + 4) \
+                + C * B * (2 * 4 + 2 * 4 * 2 + 4) + C * (4 + 4 + 4)
     flops *= n_archs
     bytes_ *= n_archs
     return {

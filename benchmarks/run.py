@@ -33,9 +33,11 @@ Prints ``name,us_per_call,derived`` CSV lines.  Sections:
   roofline — reads dry-run artifacts if present (see launch/dryrun.py)
 
 Every section is failure-isolated — including its *import*: an exception
-anywhere in one figure reports a ``<section>,,failed(...)`` line on stderr
-and the run continues, so a CSV run always covers every section it can
-(an environment without jax still gets every jax-free section).
+anywhere in one figure, an import error among them, reports a
+``<section>,,failed(...)`` line on stderr, the run continues, and the
+run exits non-zero.  Both ``main`` and ``smoke`` turn on JAX's
+persistent compilation cache (:func:`repro.core.jax_cache.
+enable_compile_cache`).
 
 After each section the driver emits a ``<section>.timing_analysis`` CSV
 row: the static-timing wall time (and call count) that section spent in
@@ -88,45 +90,32 @@ SECTIONS = [
 ]
 
 
-def _timing_wall():
-    try:
-        from repro.core.timing import read_timing_wall
-
-        return read_timing_wall()
-    except ImportError:
-        return None
-
-
 def _section(name: str, module: str) -> str:
     import time
 
-    w0 = _timing_wall()
+    from repro.core.timing import read_timing_wall
+
+    w0 = read_timing_wall()
     t0 = time.perf_counter()
     try:
         importlib.import_module(f".{module}", package=__package__).main()
         elapsed = time.perf_counter() - t0
-        w1 = _timing_wall()
-        if w0 is not None and w1 is not None:
-            delta = w1["s"] - w0["s"]
-            # Non-overlap invariant: scope-aware accounting (see
-            # repro.core.timing.timing_section) guarantees each accounted
-            # span commits once, so a section's timing delta can never
-            # exceed the wall time the section actually ran for.  A
-            # violation means a nested accounting site double-counted.
-            # (explicit raise, not assert: must survive `python -O`)
-            if delta > elapsed + 1e-6:
-                raise RuntimeError(
-                    f"{name}: timing_analysis delta {delta:.3f}s exceeds "
-                    f"the section's elapsed {elapsed:.3f}s — TIMING_WALL "
-                    f"double-counted a nested section")
-            print(f"{name}.timing_analysis,{delta * 1e6:.0f},"
-                  f"calls={w1['calls'] - w0['calls']}")
+        w1 = read_timing_wall()
+        delta = w1["s"] - w0["s"]
+        # Non-overlap invariant: scope-aware accounting (see
+        # repro.core.timing.timing_section) guarantees each accounted
+        # span commits once, so a section's timing delta can never
+        # exceed the wall time the section actually ran for.  A
+        # violation means a nested accounting site double-counted.
+        # (explicit raise, not assert: must survive `python -O`)
+        if delta > elapsed + 1e-6:
+            raise RuntimeError(
+                f"{name}: timing_analysis delta {delta:.3f}s exceeds "
+                f"the section's elapsed {elapsed:.3f}s — TIMING_WALL "
+                f"double-counted a nested section")
+        print(f"{name}.timing_analysis,{delta * 1e6:.0f},"
+              f"calls={w1['calls'] - w0['calls']}")
         return "ok"
-    except ImportError as e:
-        # missing optional dependency (e.g. no jax): not a failure — the
-        # seed behavior for kernels/roofline, now uniform for all sections
-        print(f"{name},,skipped({type(e).__name__}: {e})", file=sys.stderr)
-        return "skipped"
     except Exception as e:  # noqa: BLE001 — report uniformly, keep going
         print(f"{name},,failed({type(e).__name__}: {e})", file=sys.stderr)
         return "failed"
@@ -149,15 +138,22 @@ def smoke() -> int:
     import os
     import subprocess
 
+    from repro.core.jax_cache import enable_compile_cache
+
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     src = os.path.join(root, "src")
     env["PYTHONPATH"] = src + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    # the pytest child runs on the CPU, so it can never take a chip; and
+    # this process does no jax work until the child has exited (every
+    # gate below runs after subprocess.run returns) — one process per chip
+    env["JAX_PLATFORMS"] = "cpu"
     print("== smoke: pytest fast tier ==", flush=True)
     tests = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q", "-m", "not slow"],
         cwd=root, env=env)
+    enable_compile_cache()
     print("== smoke: 2-point arch-grid sweep ==", flush=True)
     try:
         from .sweep_frontier import run as sweep_run
@@ -252,10 +248,8 @@ def _print_cache_table() -> None:
     hit/miss/eviction counters with the derived hit rate — the smoke
     run's reuse ledger (counters survive ``clear_caches``, so this is
     cumulative over every gate above)."""
-    try:
-        from repro.core.plan import cache_stats
-    except ImportError:
-        return
+    from repro.core.plan import cache_stats
+
     stats = cache_stats()
     if not stats:
         return
@@ -269,8 +263,11 @@ def _print_cache_table() -> None:
 
 
 def main() -> int:
+    from repro.core.jax_cache import enable_compile_cache
+
     if "--smoke" in sys.argv[1:]:
         return smoke()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     status = {name: _section(name, mod) for name, mod in SECTIONS}
     failed = [name for name, st in status.items() if st == "failed"]

@@ -39,8 +39,11 @@ Two views matter to the rest of the stack:
   packs once per key and re-times many delay rows (delays never affect
   packing).
 * :meth:`ArchParams.delay_table` — the Table II + free-parameter delays
-  as a flat float64 vector over :data:`DELAY_FIELDS`, the row format the
-  vectorized timing analyzer (:mod:`repro.core.timing_vec`) gathers from.
+  as a flat int64 vector of centi-picoseconds over :data:`DELAY_FIELDS`,
+  the row format both timing paths (:mod:`repro.core.timing`,
+  :mod:`repro.core.timing_vec`) compute in.  Every delay of the model is
+  a multiple of 0.01 ps, so integer arithmetic is exact in any order and
+  on any device; picoseconds appear only in the reported records.
 """
 from __future__ import annotations
 
@@ -55,6 +58,18 @@ DELAY_FIELDS = (
     "t_out_mux_extra", "t_route_global", "t_route_local",
     "t_wire_hop1", "t_wire_hop2", "t_wire_long",
 )
+
+#: timing's integer unit: centi-picoseconds per picosecond
+CPS_PER_PS = 100
+
+
+def _to_cps(ps: float) -> int:
+    """A delay in picoseconds as whole centi-picoseconds; a delay finer
+    than 0.01 ps is refused rather than rounded."""
+    cps = round(ps * CPS_PER_PS)
+    if abs(ps * CPS_PER_PS - cps) > 1e-6:
+        raise ValueError(f"delay {ps!r} ps is not a multiple of 0.01 ps")
+    return int(cps)
 
 
 @dataclass(frozen=True)
@@ -122,20 +137,13 @@ class ArchParams:
     def output_budget(self) -> int:
         return self.lb_outputs
 
-    def lut_delay(self, k: int) -> float:
-        if k <= 4:
-            return self.t_lut4
-        if k == 5:
-            return self.t_lut5
-        return self.t_lut6
-
     # -- data-driven views ---------------------------------------------------
     def delay_table(self) -> np.ndarray:
-        """All delay parameters as a float64 vector over DELAY_FIELDS —
-        one row of the batched delay tensor the vectorized timing
-        analyzer gathers from."""
-        return np.array([getattr(self, f) for f in DELAY_FIELDS],
-                        dtype=np.float64)
+        """All delay parameters as an int64 vector of centi-picoseconds
+        over DELAY_FIELDS — one row of the batched delay tensor the
+        timing analyzers gather from."""
+        return np.array([_to_cps(getattr(self, f)) for f in DELAY_FIELDS],
+                        dtype=np.int64)
 
     def structural_key(self) -> tuple:
         """The pack-affecting fields.  Two archs with equal structural

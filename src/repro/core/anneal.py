@@ -55,8 +55,10 @@ Backends
 ``backend="numpy"`` (canonical, bit-deterministic) runs one chain;
 ``backend="jax"`` runs a ``chains``-wide ensemble of independently
 seeded chains as one vmapped ``lax.scan`` program (dense degree-padded
-adjacency, scatter-min conflict claims, in-scan best tracking) and keeps
-the candidate with the lowest *exact* (numpy-recomputed) wirelength,
+adjacency, scatter-min conflict claims, in-scan best tracking).  The
+program is all int32 — fixed-point edge weights, Metropolis thresholds
+precomputed on the host — so every device runs the same trajectories.
+It keeps the candidate with the lowest *exact* (numpy-recomputed) cost,
 seed included — so legality and the never-worse guarantee are backend-
 independent even though the chains explore different trajectories.
 
@@ -67,12 +69,15 @@ refined placement, bit for bit (the contract
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from . import plan as _planner
-from .alm import DELAY_FIELDS, ArchParams
+from .alm import CPS_PER_PS, DELAY_FIELDS, ArchParams
 from .circuit_ir import CircuitIR
 
 #: instrumentation: refinement solves / criticality solves vs cache hits
@@ -143,11 +148,11 @@ def edge_criticality(ir: CircuitIR, arch: ArchParams) -> np.ndarray:
     idx = {f: i for i, f in enumerate(DELAY_FIELDS)}
     table = arch.delay_table()
     for f in _WIRE_FIELDS:
-        table[idx[f]] = 0.0
+        table[idx[f]] = 0
     comps = delay_components(table)
     arr = arrival_times_numpy(ir, comps)
     cp = float(arr[ir.po_sig].max()) if ir.po_sig.size else 0.0
-    cp = max(cp, 1.0)
+    cp = max(cp, float(CPS_PER_PS))
 
     E = ir.fanin_sig.size
     if not E:
@@ -370,37 +375,56 @@ def _anneal_chain_numpy(ptr, nbr, wts, edge_src, edge_dst, edge_w,
 # ---------------------------------------------------------------------------
 
 
-def _anneal_chains_jax(ptr, nbr, wts, edge_src, edge_dst, edge_w,
-                       x0, y0, W, H, digest, pkey, seed,
-                       steps, moves, t_final, chains):
-    """``chains`` independently-seeded annealing trajectories as one
-    vmapped ``lax.scan`` program.  Move streams are pregenerated with
-    the same blake2b-derived numpy generators the canonical backend
-    uses (chain index in the seed), so the program is pure data flow;
-    adjacency is degree-padded dense (pad neighbour 0 with weight 0).
-    Returns per-chain ``(cost, x, y)`` best snapshots as numpy."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+_INT32_MAX = 2 ** 31 - 1
+#: most fractional bits of the jax ensemble's fixed-point edge weights
+_WEIGHT_FRAC_BITS = 8
 
+
+def _fixed_point_weights(w: np.ndarray, W: int, H: int):
+    """Edge weights as int32 fixed point for the jax ensemble, and their
+    scale.  Integral weights (``mode="anneal"``) stay exact at scale 1;
+    others get the most fractional bits (up to 8) that keep every cost
+    and move delta inside int32 — a cost is at most ``sum(w) * (W + H)``
+    and a move delta at most four times that."""
+    bits = 0 if np.array_equal(w, np.round(w)) else _WEIGHT_FRAC_BITS
+    for b in range(bits, -1, -1):
+        wq = np.round(w * (1 << b)).astype(np.int64)
+        if 4 * int(wq.sum()) * (W + H) <= _INT32_MAX:
+            return wq.astype(np.int32), 1 << b
+    raise OverflowError(
+        f"annealing costs of {w.size} edges on a {W}x{H} grid overflow "
+        f"int32")
+
+
+def _anneal_inputs(ptr, nbr, wts, x0, y0, W, H, digest, pkey, seed,
+                   steps, moves, t_final, chains, scale):
+    """Host-side inputs of the jax ensemble: the degree-padded dense
+    adjacency (pad neighbour 0 with weight 0; weights already fixed
+    point at ``scale``), the initial slot occupancy, and per-chain move
+    streams pregenerated with the same blake2b-derived numpy generators
+    the canonical backend uses (chain index in the seed), so the device
+    program is pure data flow.  Metropolis acceptance ``u < exp(-d/T)``
+    is ``d < -T log u``; for the integer fixed-point delta that is
+    ``d < ceil(-scale * T * log u)``, precomputed here as ``THR``.
+    Returns ``(nbr_pad, w_pad, occ0, (A, DX, DY, THR))``, all int32."""
     L = x0.size
-    WH = W * H
     deg = np.diff(ptr).astype(np.int64)
     D = max(int(deg.max(initial=0)), 1)
     nbr_pad = np.zeros((L, D), dtype=np.int32)
-    w_pad = np.zeros((L, D), dtype=np.float64)
+    w_pad = np.zeros((L, D), dtype=np.int32)
+    wq = np.round(wts * scale).astype(np.int32)
     for i in range(L):
         s, e = int(ptr[i]), int(ptr[i + 1])
         nbr_pad[i, : e - s] = nbr[s:e]
-        w_pad[i, : e - s] = wts[s:e]
+        w_pad[i, : e - s] = wq[s:e]
 
     # per-chain pregenerated streams (identical draw order per chain)
-    occ0 = np.full(WH, -1, dtype=np.int32)
+    occ0 = np.full(W * H, -1, dtype=np.int32)
     occ0[x0.astype(np.int64) * H + y0.astype(np.int64)] = \
         np.arange(L, dtype=np.int32)
     A = np.empty((chains, steps, moves), dtype=np.int32)
-    DX = np.empty((chains, steps, moves), dtype=np.int64)
-    DY = np.empty((chains, steps, moves), dtype=np.int64)
+    DX = np.empty((chains, steps, moves), dtype=np.int32)
+    DY = np.empty((chains, steps, moves), dtype=np.int32)
     U = np.empty((chains, steps, moves), dtype=np.float64)
     temps = np.empty((chains, steps))
     wins = np.empty((chains, steps), dtype=np.int64)
@@ -415,12 +439,27 @@ def _anneal_chains_jax(ptr, nbr, wts, edge_src, edge_dst, edge_w,
             DX[ch, k] = rng.integers(-win, win + 1, moves)
             DY[ch, k] = rng.integers(-win, win + 1, moves)
             U[ch, k] = rng.random(moves)
+    with np.errstate(divide="ignore"):
+        thr = -np.log(U) * (scale * np.maximum(temps, 1e-9))[..., None]
+    THR = np.ceil(np.minimum(thr, _INT32_MAX)).astype(np.int32)
+    return nbr_pad, w_pad, occ0, (A, DX, DY, THR)
 
+
+@functools.partial(jax.jit, static_argnames=("W", "H"))
+def _anneal_ensemble(nbr_pad, w_pad, edge_src, edge_dst, edge_w, x0, y0,
+                     occ0, A, DX, DY, THR, *, W: int, H: int):
+    """``chains`` annealing trajectories on a ``W x H`` grid as one
+    vmapped ``lax.scan`` program over the :func:`_anneal_inputs`
+    streams.  All arithmetic is int32, so every device gives the same
+    trajectories.  Returns per-chain ``(cost, x, y)`` best snapshots."""
+    L = x0.shape[0]
+    WH = W * H
+    moves = A.shape[-1]
     ids = jnp.arange(moves)
 
     def step(carry, xs):
         x, y, occ, cost, best_cost, best_x, best_y = carry
-        a, dx, dy, u, T = xs
+        a, dx, dy, thr = xs
         tx = jnp.clip(x[a] + dx, 0, W - 1)
         ty = jnp.clip(y[a] + dy, 0, H - 1)
         tslot = tx * H + ty
@@ -443,11 +482,8 @@ def _anneal_chains_jax(ptr, nbr, wts, edge_src, edge_dst, edge_w,
         d_a = incident(a, tx, ty, b, sx, sy)
         bb = jnp.where(b >= 0, b, 0)
         d_b = incident(bb, sx, sy, a, tx, ty)
-        delta = d_a + jnp.where(b >= 0, d_b, 0.0)
-        accept = (~self_move) & (
-            (delta <= 0.0)
-            | (u < jnp.exp(-jnp.maximum(delta, 0.0)
-                           / jnp.maximum(T, 1e-9))))
+        delta = d_a + jnp.where(b >= 0, d_b, 0)
+        accept = (~self_move) & (delta < thr)
         dummy = L + WH
         res = jnp.stack([a, jnp.where(b >= 0, b, dummy),
                          L + sslot, L + tslot], axis=1)
@@ -479,29 +515,34 @@ def _anneal_chains_jax(ptr, nbr, wts, edge_src, edge_dst, edge_w,
         best_y = jnp.where(better, y, best_y)
         return (x, y, occ, cost, best_cost, best_x, best_y), None
 
-    def run_chain(a, dx, dy, u, temps_c):
-        x = jnp.asarray(x0, dtype=jnp.int64)
-        y = jnp.asarray(y0, dtype=jnp.int64)
-        occ = jnp.asarray(occ0)
-        cost0 = (edge_w * (jnp.abs(x[edge_src] - x[edge_dst])
-                           + jnp.abs(y[edge_src] - y[edge_dst]))).sum()
-        carry = (x, y, occ, cost0, cost0, x, y)
-        carry, _ = jax.lax.scan(step, carry, (a, dx, dy, u, temps_c))
+    def run_chain(a, dx, dy, thr):
+        cost0 = (edge_w * (jnp.abs(x0[edge_src] - x0[edge_dst])
+                           + jnp.abs(y0[edge_src] - y0[edge_dst]))).sum()
+        carry = (x0, y0, occ0, cost0, cost0, x0, y0)
+        carry, _ = jax.lax.scan(step, carry, (a, dx, dy, thr))
         _, _, _, _, bc, bx, by = carry
         return bc, bx, by
 
-    with enable_x64():
-        edge_src = jnp.asarray(edge_src)
-        edge_dst = jnp.asarray(edge_dst)
-        edge_w = jnp.asarray(edge_w)
-        nbr_pad = jnp.asarray(nbr_pad)
-        w_pad = jnp.asarray(w_pad)
-        bc, bx, by = jax.jit(jax.vmap(run_chain))(
-            jnp.asarray(A), jnp.asarray(DX), jnp.asarray(DY),
-            jnp.asarray(U), jnp.asarray(temps))
-        return (np.asarray(jax.device_get(bc), dtype=np.float64),
-                np.asarray(jax.device_get(bx), dtype=np.int64),
-                np.asarray(jax.device_get(by), dtype=np.int64))
+    return jax.vmap(run_chain)(A, DX, DY, THR)
+
+
+def _anneal_chains_jax(ptr, nbr, wts, edge_src, edge_dst, edge_w,
+                       x0, y0, W, H, digest, pkey, seed,
+                       steps, moves, t_final, chains):
+    """``chains`` independently-seeded annealing trajectories as one
+    device program (:func:`_anneal_ensemble`) on fixed-point weights
+    (:func:`_fixed_point_weights`).  Returns per-chain ``(x, y)`` best
+    snapshots as numpy."""
+    wq, scale = _fixed_point_weights(edge_w, W, H)
+    nbr_pad, w_pad, occ0, streams = _anneal_inputs(
+        ptr, nbr, wts, x0, y0, W, H, digest, pkey, seed, steps, moves,
+        t_final, chains, scale)
+    _, bx, by = _anneal_ensemble(
+        nbr_pad, w_pad, edge_src.astype(np.int32),
+        edge_dst.astype(np.int32), wq, x0.astype(np.int32),
+        y0.astype(np.int32), occ0, *streams, W=W, H=H)
+    return (np.asarray(jax.device_get(bx), dtype=np.int64),
+            np.asarray(jax.device_get(by), dtype=np.int64))
 
 
 # ---------------------------------------------------------------------------
@@ -562,14 +603,13 @@ def refine_placement(ir: CircuitIR, arch: ArchParams, seed_pl, *,
                                 + np.abs(y0[src] - y0[dst]))).sum())
 
     if backend == "jax":
-        bc, bx, by = _anneal_chains_jax(
+        bx, by = _anneal_chains_jax(
             ptr, nbr, wts, src, dst, edge_w, x0, y0, W, H,
             seed_pl.net_digest, pkey, seed, steps, moves, t_final,
             max(1, chains))
-        # exact numpy re-score (jit arithmetic is exact int/f64 already,
-        # but the seed must stay in the candidate pool either way)
+        # exact re-score with the unrounded weights, seed included
         cands = [(seed_cost(), x0, y0)]
-        for ch in range(bc.shape[0]):
+        for ch in range(bx.shape[0]):
             c = float((edge_w * (np.abs(bx[ch][src] - bx[ch][dst])
                                  + np.abs(by[ch][src] - by[ch][dst]))).sum())
             cands.append((c, bx[ch], by[ch]))

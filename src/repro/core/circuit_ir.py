@@ -54,7 +54,7 @@ pad/stack them as their batching needs dictate (the evaluator via
 ``timing_vec._pad_levels``).  Constant operands are kept **verbatim** in
 the signal columns (``ins`` / ``a_sig`` / ``b_sig`` / ``cin_sig``) with
 the null edge class 0: the evaluator must read CONST1's all-ones lane,
-and the timing executors gather an arrival of 0.0 through signal 0 *or*
+and the timing executors gather an arrival of 0 through signal 0 *or*
 1 with zero delay components either way — bit-identical to the oracle's
 "skip constants" reductions.
 
@@ -71,7 +71,7 @@ edge (constants / padding): all components zero.
 
 Node delay classes (``NDC_*``): absorbed LUTs add nothing (their delay
 is folded into the A–H→adder path); placed LUTs add
-``lut_delay(k) + t_alm_out + t_out_mux_extra``.
+``t_lut{4,5,6} + t_alm_out + t_out_mux_extra``.
 
 Instrumentation
 ---------------
@@ -658,7 +658,7 @@ def _patch_placement(base: CircuitIR, packed: "PackedCircuit") -> CircuitIR:
     single-fanout and consumed exactly at its absorbing half, so a global
     per-signal absorbed mask is equivalent to the per-half operand sets
     the object-graph walk used.  Constant operands keep class 0 (the
-    null edge: gathered arrival 0.0, zero components) — bit-identical to
+    null edge: gathered arrival 0, zero components) — bit-identical to
     the oracle's skip-constants reductions.
     """
     net = packed.net

@@ -739,9 +739,9 @@ def exhaustive_residue_report(src: Netlist, re_elab: ReElaboration,
             try:
                 bad = _vector_close_cone(src, re_elab, support, outs)
                 vector_cones += 1
-            except (KeyError, ImportError):
-                # extraction could not close the cone, or no jax on this
-                # host — the python-int path handles both
+            except KeyError:
+                # extraction could not close the cone — the python-int
+                # path handles it
                 bad = None
         if bad is None:
             mask = (1 << (1 << W)) - 1
@@ -783,13 +783,7 @@ def _resolve_use_jax(use_jax, src: Netlist, phys: Netlist) -> bool:
     dispatch/compile overhead to pay off; booleans force either path."""
     if use_jax != "auto":
         return bool(use_jax)
-    if src.n_signals + phys.n_signals < VECTOR_SIM_MIN_SIGNALS:
-        return False
-    try:
-        import jax  # noqa: F401
-    except ImportError:
-        return False
-    return True
+    return src.n_signals + phys.n_signals >= VECTOR_SIM_MIN_SIGNALS
 
 
 def equivalence_report(src: Netlist, re_elab: ReElaboration,

@@ -635,7 +635,7 @@ class FlowServer:
                     arch_index[job.arch.name] = len(arch_rows)
                     arch_rows.append(job.arch)
             tables = np.stack([a.delay_table() for a in arch_rows])
-            cps = np.zeros((len(irs), len(arch_rows)))
+            cps = np.zeros((len(irs), len(arch_rows)), dtype=np.int64)
             if self.timing_backend == "jax":
                 t1 = time.perf_counter()
                 # members keyed by full (pack digest, skey, seed) — two
@@ -668,8 +668,8 @@ class FlowServer:
                         cps[g, k] = critical_path_numpy(ir, comps)
                 walls["timing_s"] += time.perf_counter() - t1
             for job in class_jobs:
-                cp = float(cps[ir_index[(job.pack_digest, skey, job.seed)],
-                               arch_index[job.arch.name]])
+                cp = int(cps[ir_index[(job.pack_digest, skey, job.seed)],
+                             arch_index[job.arch.name]])
                 job.record = metrics_from_cp(job.ir, job.arch, cp)
                 _TIMING.put((job.pack_digest, job.arch.name, job.seed),
                             job.record)

@@ -301,7 +301,7 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                     programs[prog_key] = progs
                 wall["build_s"] += time.perf_counter() - t0
                 t0 = time.perf_counter()
-                cps = np.zeros((len(use_irs), len(sub_idx)))
+                cps = np.zeros((len(use_irs), len(sub_idx)), dtype=np.int64)
                 for members, prog in progs:
                     gcps = prog.run(tables)
                     for row, gi in enumerate(members):
@@ -309,7 +309,7 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                 wall["timing_s"] += time.perf_counter() - t0
             elif backend == "numpy":
                 t0 = time.perf_counter()
-                cps = np.zeros((len(use_irs), len(sub_idx)))
+                cps = np.zeros((len(use_irs), len(sub_idx)), dtype=np.int64)
                 for k in range(len(sub_idx)):
                     comps = delay_components(tables[k])
                     for g, ir in enumerate(use_irs):
@@ -319,7 +319,7 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                 raise ValueError(f"unknown sweep backend {backend!r}")
             for g, ir in enumerate(use_irs):
                 for k, ai in enumerate(sub_idx):
-                    rec = metrics_from_cp(ir, archs[ai], float(cps[g, k]))
+                    rec = metrics_from_cp(ir, archs[ai], int(cps[g, k]))
                     rec["net"] = flat[g].name
                     rec["suite"] = suites[g]
                     records[g][ai] = rec

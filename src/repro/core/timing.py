@@ -22,8 +22,13 @@ Two implementations share this recurrence:
   and the arrival recurrence runs as levelized array programs (numpy per
   circuit, or a ``lax.scan``/``vmap`` batched jit across circuits x
   architectures for design-space sweeps).  It is bit-identical to the
-  oracle — float64, same addition association order, exact max — which
-  tests assert for both the unplaced and the placed paths.
+  oracle, which tests assert for both the unplaced and the placed paths.
+
+Both compute in integer centi-picoseconds (:meth:`~repro.core.alm.
+ArchParams.delay_table`): every delay of the model is a multiple of
+0.01 ps, so sums are exact in any order and on any device, and the
+critical path becomes picoseconds once, in the record
+(:func:`metrics_from_cp`).
 
 :func:`analyze` dispatches (``method="vector"`` default, ``"oracle"`` for
 the reference, optional ``placement=``) and accounts every call's wall
@@ -34,7 +39,7 @@ from __future__ import annotations
 
 import time
 
-from .alm import ArchParams
+from .alm import CPS_PER_PS, DELAY_FIELDS, ArchParams
 from .netlist import CONST0, CONST1, Netlist
 from .packing import PackedCircuit
 
@@ -138,12 +143,10 @@ def analyze_placed_oracle(packed: PackedCircuit, placement) -> dict:
     """Ground-truth placed timing: :func:`analyze_oracle`'s walk with the
     placement-derived wire-tier delay on every inter-LB edge.
 
-    Wire delay is added between the route and pin components (the
-    vectorized association order ``(((arrival + route) + wire) + pin) +
-    path``) and only when both endpoints are placed in *different* LBs —
-    PIs, constants and intra-LB / absorbed edges never touch the fabric
-    grid.  At all-zero wire-tier delays this is bit-identical to
-    :func:`analyze_oracle` (``x + 0.0 == x``), which tests pin.
+    Wire delay is added on the global leg of an edge, and only when both
+    endpoints are placed in *different* LBs — PIs, constants and intra-LB
+    / absorbed edges never touch the fabric grid.  At all-zero wire-tier
+    delays this is identical to :func:`analyze_oracle`, which tests pin.
     """
     if placement.n_lbs != packed.n_lbs:
         raise ValueError(
@@ -152,9 +155,30 @@ def analyze_placed_oracle(packed: PackedCircuit, placement) -> dict:
     return analyze_oracle(packed, placement)
 
 
+def metrics_from_cp(arch: ArchParams, cp_cps: int, alms: int, lbs: int,
+                    area: float, adders: int, luts: int,
+                    concurrent_luts: int) -> dict:
+    """The timing + area record; the one place centi-picoseconds become
+    picoseconds (a path shorter than 1 ps reports 1 ps)."""
+    cp = max(int(cp_cps), CPS_PER_PS) / CPS_PER_PS
+    return {
+        "arch": arch.name,
+        "critical_path_ps": cp,
+        "fmax_mhz": 1e6 / cp,
+        "alms": alms,
+        "lbs": lbs,
+        "area_mwta": area,
+        "adp": area * cp,
+        "adders": adders,
+        "luts": luts,
+        "concurrent_luts": concurrent_luts,
+    }
+
+
 def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
     net = packed.net
     arch = packed.arch
+    d = dict(zip(DELAY_FIELDS, arch.delay_table().tolist()))   # cps
 
     # production site (alm index) per signal; PIs -> -1
     site: dict[int, int] = {}
@@ -174,28 +198,28 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
             return -1
         return packed.alm_lb[ai]
 
-    arr: dict[int, float] = {CONST0: 0.0, CONST1: 0.0}
+    arr: dict[int, int] = {CONST0: 0, CONST1: 0}
     for s in net.pis:
-        arr[s] = 0.0
+        arr[s] = 0
 
-    def edge_in(s: int, dst_lb: int, pin: str) -> float:
+    def edge_in(s: int, dst_lb: int, pin: str) -> int:
         """Arrival of signal s at an ALM input pin in LB dst_lb."""
         t = arr[s]
         src_lb = lb_of(site.get(s, -1))
         if s <= CONST1:
-            return 0.0
+            return 0
         if src_lb == dst_lb and src_lb >= 0:
-            t += arch.t_route_local
+            t += d["t_route_local"]
         else:
-            t += arch.t_route_global
+            t += d["t_route_global"]
             if placement is not None and src_lb >= 0 and dst_lb >= 0:
-                d = (abs(int(placement.lb_x[src_lb])
-                         - int(placement.lb_x[dst_lb]))
-                     + abs(int(placement.lb_y[src_lb])
-                           - int(placement.lb_y[dst_lb])))
-                t += (arch.t_wire_hop1 if d <= 1 else
-                      arch.t_wire_hop2 if d == 2 else arch.t_wire_long)
-        t += arch.t_lbin_to_z if pin == "z" else arch.t_lbin_to_ah
+                hops = (abs(int(placement.lb_x[src_lb])
+                            - int(placement.lb_x[dst_lb]))
+                        + abs(int(placement.lb_y[src_lb])
+                              - int(placement.lb_y[dst_lb])))
+                t += (d["t_wire_hop1"] if hops <= 1 else
+                      d["t_wire_hop2"] if hops == 2 else d["t_wire_long"])
+        t += d["t_lbin_to_z"] if pin == "z" else d["t_lbin_to_ah"]
         return t
 
     # map (chain,bit) -> half for feed info
@@ -207,7 +231,7 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
                 feed[h.fa] = (h.fa_feed, h.absorbed)
                 absorbed_all.update(h.absorbed)
 
-    out_extra = arch.t_out_mux_extra
+    out_extra = d["t_out_mux_extra"]
 
     for nd in net.topo_order():
         kind, idx = nd
@@ -220,24 +244,26 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
             dst_lb = lb_of(ai)
             k = len(net.lut_inputs[idx])
             t_in = max((edge_in(s, dst_lb, "ah") for s in net.lut_inputs[idx]
-                        if s > CONST1), default=0.0)
+                        if s > CONST1), default=0)
             # absorbed LUTs have their delay folded into t_ah_to_adder
             if idx in absorbed_all:
                 arr[out] = t_in
             else:
-                arr[out] = t_in + arch.lut_delay(k) + arch.t_alm_out + out_extra
+                t_lut = (d["t_lut4"] if k <= 4 else
+                         d["t_lut5"] if k == 5 else d["t_lut6"])
+                arr[out] = t_in + t_lut + d["t_alm_out"] + out_extra
         else:
             ch = net.chains[idx]
-            carry = 0.0
+            carry = 0
             if ch.cin > CONST1:
                 ai0 = packed.chain_site.get((idx, 0), -2)
-                carry = edge_in(ch.cin, lb_of(ai0), "ah") + arch.t_ah_to_adder
+                carry = edge_in(ch.cin, lb_of(ai0), "ah") + d["t_ah_to_adder"]
             for bi in range(len(ch.sums)):
                 ai = packed.chain_site.get((idx, bi), -2)
                 dst_lb = lb_of(ai)
                 fkind, absorbed = feed.get((idx, bi), ("lut", []))
                 ops = [ch.a[bi], ch.b[bi]]
-                t_op = 0.0
+                t_op = 0
                 absorbed_outs = {net.lut_out[li] for li in absorbed}
                 for s in ops:
                     if s <= CONST1:
@@ -247,40 +273,28 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
                         li = next(l for l in absorbed if net.lut_out[l] == s)
                         tin = max((edge_in(q, dst_lb, "ah")
                                    for q in net.lut_inputs[li] if q > CONST1),
-                                  default=0.0)
-                        t_op = max(t_op, tin + arch.t_ah_to_adder)
+                                  default=0)
+                        t_op = max(t_op, tin + d["t_ah_to_adder"])
                     elif fkind == "z":
                         t_op = max(t_op, edge_in(s, dst_lb, "z")
-                                   + arch.t_z_to_adder)
+                                   + d["t_z_to_adder"])
                     else:
                         t_op = max(t_op, edge_in(s, dst_lb, "ah")
-                                   + arch.t_ah_to_adder)
+                                   + d["t_ah_to_adder"])
                 t_here = max(t_op, carry)
-                arr[ch.sums[bi]] = t_here + arch.t_sum_out + out_extra
-                carry = t_here + arch.t_carry
+                arr[ch.sums[bi]] = t_here + d["t_sum_out"] + out_extra
+                carry = t_here + d["t_carry"]
             if ch.cout is not None:
-                arr[ch.cout] = carry + arch.t_sum_out + out_extra
+                arr[ch.cout] = carry + d["t_sum_out"] + out_extra
 
     # absorbed luts that never got arr (dangling) -> 0
-    cp = 0.0
+    cp = 0
     for bus in net.pos.values():
         for s in bus:
-            cp = max(cp, arr.get(s, 0.0))
-    cp = max(cp, 1.0)
-
-    area = packed.total_area
-    return {
-        "arch": arch.name,
-        "critical_path_ps": cp,
-        "fmax_mhz": 1e6 / cp,
-        "alms": packed.n_alms,
-        "lbs": packed.n_lbs,
-        "area_mwta": area,
-        "adp": area * cp,
-        "adders": net.n_adders,
-        "luts": net.n_luts,
-        "concurrent_luts": packed.concurrent_luts,
-    }
+            cp = max(cp, arr.get(s, 0))
+    return metrics_from_cp(arch, cp, packed.n_alms, packed.n_lbs,
+                           packed.total_area, net.n_adders, net.n_luts,
+                           packed.concurrent_luts)
 
 
 def channel_utilization(packed: PackedCircuit,

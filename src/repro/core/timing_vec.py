@@ -18,22 +18,23 @@ recurrence as array programs over :class:`~repro.core.circuit_ir.CircuitIR`
 
 Value identity
 --------------
-Both backends are **bit-identical** to the oracle (not merely close): all
-arithmetic is float64, additions compose in exactly the oracle's
-association order — ``(((arrival + route) + wire) + pin) + path`` per
-edge, ``((t_in + lut_delay) + t_alm_out) + t_out_mux_extra`` per node —
-and ``max`` is exact in any order.  Padding exploits the model invariant
-that delays are non-negative: padded slots gather signal 0 (CONST0,
-arrival 0.0) through the all-zero null edge class and wire tier 0,
-reproducing the oracle's ``default=0.0`` reductions exactly.
+Both backends are **bit-identical** to the oracle (not merely close):
+every delay is an integer number of centi-picoseconds
+(:meth:`~repro.core.alm.ArchParams.delay_table`), so sums are exact in
+any order and ``max`` is exact too — on the host (int64) and on the
+device (int32, which a TPU computes natively; :meth:`SuiteTimingProgram.
+run` checks the longest formable path fits).  Critical paths leave this
+module in centi-picoseconds; :func:`metrics_from_cp` turns them into the
+picosecond record.  Padding exploits the model invariant that delays are
+non-negative: padded slots gather signal 0 (CONST0, arrival 0) through
+the all-zero null edge class and wire tier 0, reproducing the oracle's
+``default=0`` reductions exactly.
 
 The *wire* term is the placement-derived inter-LB hop delay: each edge
 carries a wire tier (0..3, see ``TIER_*`` in :mod:`repro.core.circuit_ir`)
 gathered from a per-arch 4-entry component table.  Unplaced IRs carry
-tier 0 everywhere and tier 0's delay is identically 0.0, so — because
-``x + 0.0 == x`` exactly for every finite ``x >= 0`` — the placed path
-at zero wire-tier delay reproduces the placement-free timing bit for
-bit (the Fig-5/Table-III pins stay regression gates).
+tier 0 everywhere and tier 0's delay is 0, so the placed path at zero
+wire-tier delay reproduces the placement-free timing exactly.
 
 Delay tables are data, not structure: an edge stores a *class* (0..26,
 see :mod:`repro.core.circuit_ir`), the per-arch component table is built
@@ -48,6 +49,7 @@ from typing import Sequence
 
 import numpy as np
 
+from . import timing as _timing
 from .alm import ArchParams, DELAY_FIELDS
 from .circuit_ir import (N_EDGE_CLASSES, N_NODE_CLASSES, NDC_ABSORBED,
                          NDC_LUT4, NDC_LUT5, NDC_LUT6, CircuitIR)
@@ -84,20 +86,25 @@ def _pad_dim(n: int, floor: int = 4) -> int:
 
 
 def delay_components(tables: np.ndarray) -> dict[str, np.ndarray]:
-    """Expand delay-table rows ``[..., len(DELAY_FIELDS)]`` into the three
+    """Expand integer delay-table rows ``[..., len(DELAY_FIELDS)]``
+    (centi-picoseconds, :meth:`ArchParams.delay_table`) into the
     component tables the executors gather from (leading axes preserved):
 
     * ``edge [..., 27, 3]`` — (route, pin, path) components per edge class;
     * ``wire [..., 4]``     — inter-LB delay per wire tier (null/tile-local,
-      1-hop, 2-hop, long); tier 0 is identically 0.0 so unplaced edges
-      (and padding) add nothing;
+      1-hop, 2-hop, long); tier 0 is 0 so unplaced edges (and padding)
+      add nothing;
     * ``lut  [..., 4, 3]``  — (lut_delay, t_alm_out, t_out_mux_extra) per
       node delay class (all-zero for absorbed LUTs);
     * ``chain [..., 3]``    — (t_sum_out, t_out_mux_extra, t_carry).
     """
-    t = np.asarray(tables, dtype=np.float64)
+    t = np.asarray(tables)
+    if not np.issubdtype(t.dtype, np.integer):
+        raise TypeError("delay tables are integer centi-picoseconds "
+                        f"(ArchParams.delay_table()), got {t.dtype}")
+    t = t.astype(np.int64)
     lead = t.shape[:-1]
-    z = np.zeros(lead, dtype=np.float64)
+    z = np.zeros(lead, dtype=np.int64)
 
     def g(name):
         return t[..., _IDX[name]]
@@ -105,13 +112,13 @@ def delay_components(tables: np.ndarray) -> dict[str, np.ndarray]:
     route = np.stack([z, g("t_route_local"), g("t_route_global")], axis=-1)
     pin = np.stack([z, g("t_lbin_to_ah"), g("t_lbin_to_z")], axis=-1)
     path = np.stack([z, g("t_ah_to_adder"), g("t_z_to_adder")], axis=-1)
-    edge = np.zeros(lead + (N_EDGE_CLASSES, 3), dtype=np.float64)
+    edge = np.zeros(lead + (N_EDGE_CLASSES, 3), dtype=np.int64)
     for c in range(N_EDGE_CLASSES):
         edge[..., c, 0] = route[..., c // 9]
         edge[..., c, 1] = pin[..., (c // 3) % 3]
         edge[..., c, 2] = path[..., c % 3]
 
-    lut = np.zeros(lead + (N_NODE_CLASSES, 3), dtype=np.float64)
+    lut = np.zeros(lead + (N_NODE_CLASSES, 3), dtype=np.int64)
     for ndc, d in ((NDC_LUT4, g("t_lut4")), (NDC_LUT5, g("t_lut5")),
                    (NDC_LUT6, g("t_lut6"))):
         lut[..., ndc, 0] = d
@@ -133,12 +140,12 @@ def delay_components(tables: np.ndarray) -> dict[str, np.ndarray]:
 
 def arrival_times_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]
                         ) -> np.ndarray:
-    """Arrival time per signal, float64, oracle-identical."""
+    """Arrival time per signal, int64 centi-ps, oracle-identical."""
     edge, wire, lutc = comps["edge"], comps["wire"], comps["lut"]
-    t_sum, t_extra, t_carry = (float(comps["chain"][0]),
-                               float(comps["chain"][1]),
-                               float(comps["chain"][2]))
-    arr = np.zeros(ir.n_signals, dtype=np.float64)
+    t_sum, t_extra, t_carry = (int(comps["chain"][0]),
+                               int(comps["chain"][1]),
+                               int(comps["chain"][2]))
+    arr = np.zeros(ir.n_signals, dtype=np.int64)
     for ll, cl in zip(ir.lut_levels, ir.chain_levels):
         if ll.out.shape[0]:
             ec = edge[ll.cls]                          # [M, 6, 3]
@@ -158,7 +165,7 @@ def arrival_times_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]
             c = (((arr[cl.cin_sig] + ecin[:, 0]) + wire[cl.cin_hop])
                  + ecin[:, 1]) + ecin[:, 2]
             B = cl.a_sig.shape[1]
-            carries = np.zeros((C, B), dtype=np.float64)
+            carries = np.zeros((C, B), dtype=np.int64)
             for bi in range(B):
                 th = np.maximum(np.maximum(a_t[:, bi], b_t[:, bi]), c)
                 valid = cl.sums[:, bi] >= 0
@@ -173,32 +180,23 @@ def arrival_times_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]
     return arr
 
 
-def critical_path_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]) -> float:
+def critical_path_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]) -> int:
+    """Latest primary-output arrival, centi-ps (0 without outputs)."""
     arr = arrival_times_numpy(ir, comps)
-    cp = float(arr[ir.po_sig].max()) if ir.po_sig.size else 0.0
-    return max(cp, 1.0)
+    return int(arr[ir.po_sig].max()) if ir.po_sig.size else 0
 
 
-def metrics_from_cp(ir: CircuitIR, arch: ArchParams, cp: float) -> dict:
-    """The :func:`repro.core.timing.analyze` record for one (IR, arch, cp).
+def metrics_from_cp(ir: CircuitIR, arch: ArchParams, cp_cps: int) -> dict:
+    """The :func:`repro.core.timing.analyze` record for one (IR, arch,
+    critical path in centi-ps).
 
     ``n_alms``/``n_lbs``/``concurrent_luts`` come from the IR (structure);
     area comes from the arch row — within a structural class only the
     area constant and the delays differ, which is why one IR serves every
     grid row of its class."""
-    area = ir.n_alms * arch.alm_area_mwta
-    return {
-        "arch": arch.name,
-        "critical_path_ps": cp,
-        "fmax_mhz": 1e6 / cp,
-        "alms": ir.n_alms,
-        "lbs": ir.n_lbs,
-        "area_mwta": area,
-        "adp": area * cp,
-        "adders": ir.n_adders,
-        "luts": ir.n_luts,
-        "concurrent_luts": ir.concurrent_luts,
-    }
+    return _timing.metrics_from_cp(
+        arch, cp_cps, ir.n_alms, ir.n_lbs, ir.n_alms * arch.alm_area_mwta,
+        ir.n_adders, ir.n_luts, ir.concurrent_luts)
 
 
 def analyze_ir(ir: CircuitIR, arch: ArchParams, backend: str = "numpy") -> dict:
@@ -208,7 +206,7 @@ def analyze_ir(ir: CircuitIR, arch: ArchParams, backend: str = "numpy") -> dict:
         cp = critical_path_numpy(ir, comps)
     elif backend == "jax":
         prog = build_suite_timing_program([ir])
-        cp = float(prog.run(arch.delay_table()[None, :])[0, 0])
+        cp = int(prog.run(arch.delay_table()[None, :])[0, 0])
     else:
         raise ValueError(f"unknown timing backend {backend!r}")
     return metrics_from_cp(ir, arch, cp)
@@ -221,7 +219,7 @@ def analyze_ir(ir: CircuitIR, arch: ArchParams, backend: str = "numpy") -> dict:
 
 def _alloc_bucket(l: int, M1: int, C1: int, B1: int, sink: int):
     """One bucket's all-pad (null) 17-tuple: every gather reads signal 0
-    (CONST0, arrival 0.0) through edge class 0 / wire tier 0, every
+    (CONST0, arrival 0) through edge class 0 / wire tier 0, every
     scatter lands on ``sink`` — a no-op level row."""
     return (np.zeros((l, M1, 6), dtype=np.int32),       # l_ins
             np.zeros((l, M1, 6), dtype=np.int32),       # l_cls
@@ -293,7 +291,8 @@ class SuiteTimingProgram:
     """One batched timing program: G stacked circuits x K delay rows.
 
     ``run(delay_tables[K, len(DELAY_FIELDS)])`` returns critical paths
-    ``[G, K]`` (float64), bit-identical to the oracle per (circuit, arch).
+    ``[G, K]`` (int64 centi-ps), bit-identical to the oracle per
+    (circuit, arch).
     The program is jit-compiled once per (shape, K); re-running with new
     delay rows of the same K reuses the compile — an arch-grid sweep is
     pure data motion after the first call.
@@ -357,38 +356,50 @@ class SuiteTimingProgram:
             return arr, None
 
         def one(member_xs, po, edge, wire, lutc, chainc):
-            arr = jnp.zeros(n_sig + 1, dtype=jnp.float64)
+            arr = jnp.zeros(n_sig + 1, dtype=jnp.int32)
             for (hl, hc), xs in zip(flags, member_xs):
                 bk = functools.partial(body, hl=hl, hc=hc, edge=edge,
                                        wire=wire, lutc=lutc, chainc=chainc)
                 arr, _ = jax.lax.scan(bk, arr, xs)
-            return jnp.maximum(jnp.max(arr[po]), 1.0)
+            return jnp.max(arr[po])
 
         inner = jax.vmap(one, in_axes=(None, None, 0, 0, 0, 0))  # arch axis
         outer = jax.vmap(inner, in_axes=(0, 0, None, None, None, None))
         return jax.jit(outer)
 
     def run(self, delay_tables: np.ndarray) -> np.ndarray:
-        """Critical paths ``[G, K]`` for delay rows ``[K, |DELAY_FIELDS|]``."""
-        from jax.experimental import enable_x64
-
-        comps = delay_components(np.asarray(delay_tables, dtype=np.float64))
-        with enable_x64():
-            if self._jit is None:
-                jit = (_JIT_CACHE.get(self.shape_key)
-                       if self.shape_key is not None else None)
-                if jit is None:
-                    jit = self._build_jit()
-                    _COMPILE_COUNTS["jit_built"] += 1
-                    if self.shape_key is not None:
-                        _JIT_CACHE[self.shape_key] = jit
-                else:
-                    _COMPILE_COUNTS["jit_reused"] += 1
-                self._jit = jit
-            cps = self._jit(self._tensors, self._po, comps["edge"],
-                            comps["wire"], comps["lut"], comps["chain"])
-            # rows past n_members are pad members (cp 1.0), sliced away
-            return np.asarray(cps, dtype=np.float64)[:self.n_members]
+        """Critical paths ``[G, K]`` (centi-ps) for integer delay rows
+        ``[K, |DELAY_FIELDS|]``."""
+        comps = delay_components(delay_tables)
+        # arrivals are int32 on the device.  Each level adds at most one
+        # edge and either one LUT node or one full carry ripple, so this
+        # bounds every arrival the program can form.
+        levels = sum(l for l, _, _, _ in self.bucket_shapes)
+        bits = max(B for _, _, _, B in self.bucket_shapes)
+        chain = comps["chain"].reshape(-1, 3)
+        step = (comps["edge"].sum(-1).max() + comps["wire"].max()
+                + max(comps["lut"].sum(-1).max(),
+                      (bits * chain[:, 2] + chain[:, 0] + chain[:, 1]).max()))
+        if levels * int(step) >= 2 ** 31:
+            raise OverflowError(
+                f"timing program: {levels} levels x {int(step)} cps per "
+                f"level may overflow int32 arrivals")
+        if self._jit is None:
+            jit = (_JIT_CACHE.get(self.shape_key)
+                   if self.shape_key is not None else None)
+            if jit is None:
+                jit = self._build_jit()
+                _COMPILE_COUNTS["jit_built"] += 1
+                if self.shape_key is not None:
+                    _JIT_CACHE[self.shape_key] = jit
+            else:
+                _COMPILE_COUNTS["jit_reused"] += 1
+            self._jit = jit
+        cps = self._jit(self._tensors, self._po,
+                        *(comps[k].astype(np.int32)
+                          for k in ("edge", "wire", "lut", "chain")))
+        # rows past n_members are pad members, sliced away
+        return np.asarray(cps, dtype=np.int64)[:self.n_members]
 
 
 def build_suite_timing_program(irs: Sequence[CircuitIR],
@@ -441,7 +452,7 @@ def build_suite_timing_program(irs: Sequence[CircuitIR],
         tuple(jnp.asarray(np.stack([mb[bi][ai] for mb in members]))
               for ai in range(17))
         for bi in range(len(bounds)))
-    po = np.zeros((G_alloc, P), dtype=np.int32)    # pad -> CONST0 (arr 0.0)
+    po = np.zeros((G_alloc, P), dtype=np.int32)    # pad -> CONST0 (arr 0)
     for g, ir in enumerate(irs):
         po[g, :ir.po_sig.size] = ir.po_sig
     flags = tuple(

@@ -4,7 +4,12 @@ The functional simulator (``core/eval_jax.py``) evaluates topological levels
 of LUTs over packed test-vector lanes.  Per LUT the output is a
 sum-of-minterms over its input lanes — identical bitwise work for all LUTs
 in a level, so it vectorizes across (LUT, lane) tiles.  The truth tables
-ride along as scalar-prefetch-style operands (uint32 words per LUT).
+ride along as a 2-D ``[M, w]`` operand (one row of uint32 words per LUT),
+blocked ``(BM, w)`` beside the ``(BM, K, BN)`` lane block: a 1-D ``[M]``
+operand blocked ``(BM,)`` is refused by Mosaic whenever ``M > BM``, since
+XLA tiles the 1-D array differently from the block (e.g. ``T(1024)``
+against ``T(256)``).  ``M`` need not be a multiple of ``BM``: the last
+row tile is partial, its out-of-range rows are never written back.
 
 Two entry points:
 
@@ -28,7 +33,7 @@ BLOCK_N = 128   # lane words per tile
 
 
 def _kernel(tt_ref, in_ref, o_ref, *, k: int):
-    # tt_ref: [BM] uint32; in_ref: [BM, k, BN] uint32; o_ref: [BM, BN]
+    # tt_ref: [BM, 1] uint32; in_ref: [BM, k, BN] uint32; o_ref: [BM, BN]
     tts = tt_ref[...]
     ins = in_ref[...]
     BM, _, BN = ins.shape
@@ -40,7 +45,7 @@ def _kernel(tt_ref, in_ref, o_ref, *, k: int):
         for j in range(k):
             lane = ins[:, j, :]
             term = term & (lane if (m >> j) & 1 else ~lane)
-        out = out | (jnp.where(bit == 1, full, jnp.uint32(0))[:, None] & term)
+        out = out | (jnp.where(bit == 1, full, jnp.uint32(0)) & term)
     o_ref[...] = out
 
 
@@ -56,19 +61,20 @@ def lut_eval(inputs: jax.Array, tts: jax.Array,
         functools.partial(_kernel, k=K),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, K, bn), lambda i, j: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.uint32),
         interpret=interpret,
-    )(tts.astype(jnp.uint32), inputs.astype(jnp.uint32))
+    )(tts.astype(jnp.uint32)[:, None], inputs.astype(jnp.uint32))
 
 
-def _kernel6(tt_lo_ref, tt_hi_ref, in_ref, o_ref):
-    # tt_lo/hi_ref: [BM] uint32; in_ref: [BM, 6, BN]; o_ref: [BM, BN]
-    lo_t = tt_lo_ref[...]
-    hi_t = tt_hi_ref[...]
+def _kernel6(tt_ref, in_ref, o_ref):
+    # tt_ref: [BM, 2] uint32 (lo, hi); in_ref: [BM, 6, BN]; o_ref: [BM, BN]
+    tt = tt_ref[...]
+    lo_t = tt[:, 0:1]
+    hi_t = tt[:, 1:2]
     ins = in_ref[...]
     BM, _, BN = ins.shape
     lo = jnp.zeros((BM, BN), dtype=jnp.uint32)
@@ -81,8 +87,8 @@ def _kernel6(tt_lo_ref, tt_hi_ref, in_ref, o_ref):
             term = term & (lane if (m >> j) & 1 else ~lane)
         lo_bit = (lo_t >> jnp.uint32(m)) & jnp.uint32(1)
         hi_bit = (hi_t >> jnp.uint32(m)) & jnp.uint32(1)
-        lo = lo | (jnp.where(lo_bit == 1, full, jnp.uint32(0))[:, None] & term)
-        hi = hi | (jnp.where(hi_bit == 1, full, jnp.uint32(0))[:, None] & term)
+        lo = lo | (jnp.where(lo_bit == 1, full, jnp.uint32(0)) & term)
+        hi = hi | (jnp.where(hi_bit == 1, full, jnp.uint32(0)) & term)
     sel = ins[:, 5, :]
     o_ref[...] = (sel & hi) | (~sel & lo)
 
@@ -105,12 +111,11 @@ def lut_eval6(inputs: jax.Array, tt_lo: jax.Array, tt_hi: jax.Array,
         _kernel6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, 2), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, 6, bn), lambda i, j: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.uint32),
         interpret=interpret,
-    )(tt_lo.astype(jnp.uint32), tt_hi.astype(jnp.uint32),
+    )(jnp.stack([tt_lo.astype(jnp.uint32), tt_hi.astype(jnp.uint32)], axis=1),
       inputs.astype(jnp.uint32))

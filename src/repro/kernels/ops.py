@@ -1,10 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute under ``interpret=True`` — the
-kernel body runs as traced jnp ops, which validates BlockSpec indexing and
-kernel logic exactly.  On a TPU backend the same call sites compile to
-Mosaic.  ``use_pallas=False`` falls back to the jnp references (used by the
-dry-run lowering path, where interpret-mode pallas would bloat the HLO).
+On a TPU backend the kernels compile to Mosaic.  On any other backend
+(the CPU runs of the tests) they execute under ``interpret=True``: the
+kernel body runs as traced jnp ops, which checks BlockSpec indexing and
+kernel logic exactly but says nothing about Mosaic's layout rules — those
+are checked by compiling for a described TPU
+(``tests/core/test_chip_compile.py``).  ``use_pallas=False`` selects the
+jnp references instead.
 """
 from __future__ import annotations
 

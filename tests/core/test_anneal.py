@@ -17,7 +17,7 @@ the annealer).
 import numpy as np
 import pytest
 
-from repro.core.alm import ARCHS, make_arch
+from repro.core.alm import ARCHS, CPS_PER_PS, make_arch
 from repro.core.anneal import (ANNEAL_COUNTS, criticality_weights,
                                delay_signature, edge_criticality,
                                refine_placement)
@@ -111,8 +111,8 @@ def test_placed_timing_bit_identical_on_annealed_placements():
             pir = apply_placement(ir, pl)
             assert analyze_ir(pir, arch) == want
             prog = build_suite_timing_program([pir])
-            cp = float(prog.run(arch.delay_table()[None, :])[0, 0])
-            assert cp == want["critical_path_ps"]
+            cp = int(prog.run(arch.delay_table()[None, :])[0, 0])
+            assert cp / CPS_PER_PS == want["critical_path_ps"]
             if (arch.t_wire_hop1, arch.t_wire_hop2, arch.t_wire_long) \
                     == (0.0, 0.0, 0.0):
                 # Fig-5/Table-III pins: zero wire == unplaced, bitwise
@@ -223,3 +223,47 @@ def test_jax_ensemble_no_worse_than_single_chain_seed():
                  anneal_chains=2, anneal_steps=24)
     _legal(j, ir.n_lbs)
     assert j.wirelength(ir) <= seed_pl.wirelength(ir)
+
+
+def test_fixed_point_weights_exact_scaled_and_bounded():
+    """The jax ensemble's int32 weights: integral weights stay exact at
+    scale 1, fractional ones get 8 fractional bits, fewer when the cost
+    bound would leave int32, and a grid no scale fits is refused."""
+    from repro.core.anneal import _fixed_point_weights
+
+    wq, scale = _fixed_point_weights(np.ones(7), 4, 5)
+    assert scale == 1 and wq.dtype == np.int32 and (wq == 1).all()
+    w = np.array([1.0, 1.5, 4.99])
+    wq, scale = _fixed_point_weights(w, 4, 5)
+    assert scale == 256
+    assert np.array_equal(wq, np.round(w * 256).astype(np.int32))
+    big = np.full(1000, 1.5)
+    wq, scale = _fixed_point_weights(big, 2000, 2000)
+    assert 1 <= scale < 256
+    assert 4 * int(wq.sum()) * 4000 <= 2 ** 31 - 1
+    with pytest.raises(OverflowError):
+        _fixed_point_weights(np.ones(10 ** 6), 2000, 2000)
+
+
+def test_jax_timing_driven_refine_legal_never_worse_deterministic():
+    """Timing-driven weights reach the device as fixed point; the host
+    re-scores with the exact weights, so the weighted cost never exceeds
+    the seed's and repeated runs agree bit for bit."""
+    arch = ARCHS["dd5"]
+    ir = _ir(arch=arch)
+    seed_pl = place_ir(ir, arch, seed=0)
+    w = criticality_weights(ir, arch)
+    assert not np.array_equal(w, np.round(w))     # fractional weights
+    src, dst = _routed_edges(ir)
+
+    def cost(pl):
+        return float((w * (np.abs(pl.lb_x[src] - pl.lb_x[dst])
+                           + np.abs(pl.lb_y[src] - pl.lb_y[dst]))).sum())
+
+    a = refine_placement(ir, arch, seed_pl, mode="anneal_timing",
+                         backend="jax", chains=2, steps=24)
+    b = refine_placement(ir, arch, seed_pl, mode="anneal_timing",
+                         backend="jax", chains=2, steps=24)
+    _legal(a, ir.n_lbs)
+    assert cost(a) <= cost(seed_pl)
+    assert np.array_equal(a.lb_x, b.lb_x) and np.array_equal(a.lb_y, b.lb_y)

@@ -13,7 +13,7 @@ registry (the PR-5 stale-template regression, re-pinned for placements).
 """
 import numpy as np
 
-from repro.core.alm import ARCHS, make_arch
+from repro.core.alm import ARCHS, CPS_PER_PS, make_arch
 from repro.core.circuit_ir import (TIER_HOP1, TIER_LONG, TIER_NONE,
                                    apply_placement)
 from repro.core.circuits import kratos_gemm
@@ -146,8 +146,9 @@ def test_placed_timing_bit_identical_to_placed_oracle():
             got = analyze_ir(pir, arch)
             assert got == want, f"{net.name}@{aname} numpy"
             prog = build_suite_timing_program([pir])
-            cp = float(prog.run(arch.delay_table()[None, :])[0, 0])
-            assert cp == want["critical_path_ps"], f"{net.name}@{aname} jax"
+            cp = int(prog.run(arch.delay_table()[None, :])[0, 0])
+            assert cp / CPS_PER_PS == want["critical_path_ps"], \
+                f"{net.name}@{aname} jax"
             # wire delay can only lengthen paths
             assert want["critical_path_ps"] >= \
                 analyze_oracle(packed)["critical_path_ps"]

@@ -57,3 +57,21 @@ def test_fmax_in_plausible_range():
     net = kratos_gemm(m=8, n=8, width=6, sparsity=0.5)
     r = analyze(pack(net, BASELINE, seed=0))
     assert 40 < r["fmax_mhz"] < 400
+
+
+def test_delay_table_is_whole_centi_picoseconds():
+    """Timing computes in integer centi-ps: the delay table holds them
+    exactly, and a delay finer than 0.01 ps is refused, not rounded."""
+    import numpy as np
+    import pytest
+
+    from repro.core.alm import DELAY_FIELDS, make_arch
+
+    table = DD5.delay_table()
+    assert table.dtype == np.int64
+    row = dict(zip(DELAY_FIELDS, table.tolist()))
+    assert row["t_ah_to_adder"] == 20220 and row["t_lbin_to_ah"] == 7261
+    fan5 = make_arch("f5", bypass_inputs=2, addmux_fanin=5)  # 77.05 - 4.5
+    assert dict(zip(DELAY_FIELDS, fan5.delay_table()))["t_lbin_to_z"] == 7255
+    with pytest.raises(ValueError, match="0.01 ps"):
+        make_arch("fine", t_wire_hop1=25.001).delay_table()

@@ -94,6 +94,24 @@ def test_lut_eval6_fused_layout(m, nlanes):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("m,nlanes", [(257, 4), (2330, 8), (300, 130)])
+def test_lut_eval6_partial_tiles_match_ref(m, nlanes):
+    """Row (and lane) counts that are not a multiple of the kernel's
+    tile: the last tile is partial and must not leak into real rows."""
+    from repro.kernels.lut_eval import BLOCK_M, lut_eval6
+
+    assert m % BLOCK_M
+    r = rng(m + nlanes)
+    ins = r.integers(0, 2**32, size=(m, 6, nlanes), dtype=np.uint32)
+    tt_lo = r.integers(0, 2**32, size=(m,), dtype=np.uint32)
+    tt_hi = r.integers(0, 2**32, size=(m,), dtype=np.uint32)
+    got = lut_eval6(jnp.asarray(ins), jnp.asarray(tt_lo), jnp.asarray(tt_hi),
+                    interpret=True)
+    want = ref.lut_eval6_ref(jnp.asarray(ins), jnp.asarray(tt_lo),
+                             jnp.asarray(tt_hi))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_lut_eval6_shannon_select():
     # pin5 selects between the lo/hi table words: table = XOR2 in lo,
     # AND2 in hi, pin5 toggling per lane bit
