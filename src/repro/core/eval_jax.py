@@ -61,6 +61,7 @@ from . import plan as _planner
 from .circuit_ir import CircuitIR, levelize, lower_netlist_ir
 from .netlist import CONST0, CONST1, Netlist
 from .plan import segment_levels
+from .spans import span
 
 DEFAULT_MAX_BUCKETS = 3
 DEFAULT_MAX_GROUPS = 4
@@ -401,11 +402,14 @@ def _run_fused_batch(vals, bucket_arrays, *, flags, use_pallas):
 
 def _init_vals(plan: FusedPlan, pi_lanes: dict[int, np.ndarray],
                n_lane_words: int) -> jax.Array:
-    vals = np.zeros((plan.n_signals + 1, n_lane_words), dtype=np.uint32)
-    vals[CONST1] = 0xFFFFFFFF
-    for s, v in pi_lanes.items():
-        vals[s] = np.asarray(v, dtype=np.uint32)
-    return jnp.asarray(vals)
+    with span("repro.eval.fill") as sp:
+        vals = np.zeros((plan.n_signals + 1, n_lane_words), dtype=np.uint32)
+        vals[CONST1] = 0xFFFFFFFF
+        for s, v in pi_lanes.items():
+            vals[s] = np.asarray(v, dtype=np.uint32)
+        sp.set(bytes=vals.nbytes)
+    with span("repro.eval.put", bytes=vals.nbytes):
+        return jnp.asarray(vals).block_until_ready()
 
 
 def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
@@ -420,8 +424,9 @@ def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
     if plan is None:
         plan = plan_netlist(net)
     vals = _init_vals(plan, pi_lanes, n_lane_words)
-    out = _run_fused(vals, plan.device_arrays(), flags=plan.flags,
-                     use_pallas=use_pallas)
+    with span("repro.eval.run"):
+        out = _run_fused(vals, plan.device_arrays(), flags=plan.flags,
+                         use_pallas=use_pallas).block_until_ready()
     mark_program_run(program_signature(plan, n_lane_words, use_pallas))
     return out[:plan.n_signals]
 
@@ -525,17 +530,25 @@ class SuiteProgram:
         outs: list = [None] * len(self.n_signals)
         for members, (n_sig, stacked, flags,
                       member_plans) in zip(self.groups, self.programs):
-            vals = np.zeros((len(members), n_sig + 1, n_lane_words),
-                            dtype=np.uint32)
-            vals[:, CONST1] = 0xFFFFFFFF
-            for row, i in enumerate(members):
-                for s, v in pi_lanes_list[i].items():
-                    vals[row, s] = np.asarray(v, dtype=np.uint32)
-            out = _run_fused_batch(jnp.asarray(vals), stacked, flags=flags,
-                                   use_pallas=use_pallas)
-            # np.asarray blocks on the device result — timing loops over
-            # run() measure execution, not dispatch
-            out = np.asarray(out)
+            with span("repro.eval.fill") as sp:
+                vals = np.zeros((len(members), n_sig + 1, n_lane_words),
+                                dtype=np.uint32)
+                vals[:, CONST1] = 0xFFFFFFFF
+                for row, i in enumerate(members):
+                    for s, v in pi_lanes_list[i].items():
+                        vals[row, s] = np.asarray(v, dtype=np.uint32)
+                sp.set(bytes=vals.nbytes)
+            # the program consumes its input right away, and np.asarray
+            # blocks on the result: the two block_until_ready calls move
+            # no work, they only bound the transfer and the device run
+            with span("repro.eval.put", bytes=vals.nbytes):
+                dev_vals = jnp.asarray(vals).block_until_ready()
+            with span("repro.eval.run"):
+                out = _run_fused_batch(dev_vals, stacked, flags=flags,
+                                       use_pallas=use_pallas
+                                       ).block_until_ready()
+            with span("repro.eval.get", bytes=vals.nbytes):
+                out = np.asarray(out)
             # all members share the group layout, so member 0's plan IS
             # the group's program shape signature
             mark_program_run(program_signature(

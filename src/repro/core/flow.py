@@ -51,6 +51,7 @@ from .eval_jax import (DEFAULT_MAX_BUCKETS, DEFAULT_MAX_GROUPS, FusedPlan,
                        prepare_suite_program)
 from .netlist import Netlist, eval_netlist
 from .packing import PackedCircuit, pack
+from .spans import span
 from .timing import analyze
 
 #: the paper averages three placement seeds per figure
@@ -266,8 +267,12 @@ def evaluate_netlist(net: Netlist, pi_lanes: dict[int, np.ndarray],
     """
     if plan is None:
         plan = plan_netlist(net, max_buckets=max_buckets)
-    return np.asarray(eval_netlist_jax(net, pi_lanes, n_lane_words,
-                                       use_pallas=use_pallas, plan=plan))
+    out = eval_netlist_jax(net, pi_lanes, n_lane_words,
+                           use_pallas=use_pallas, plan=plan)
+    with span("repro.eval.get") as sp:
+        out = np.asarray(out)
+        sp.set(bytes=out.nbytes)
+    return out
 
 
 def prepare_suite(nets: list[Netlist],
@@ -425,12 +430,44 @@ def evaluate_suite(nets: list[Netlist],
     ``mode`` and (in auto) the ``cost_model`` record — both paths are
     bit-identical, so the choice is purely a throughput matter.
     """
+    with span("repro.eval.call", circuits=len(nets),
+              lane_words=int(n_lane_words)):
+        with span("repro.eval.plan"):
+            plans, groups, model, chosen = _plan_suite(
+                nets, program, mode, max_groups, max_buckets, warm,
+                n_lane_words, use_pallas)
+            if chosen == "grouped" and program is None:
+                program = prepare_suite_program(
+                    nets, max_buckets=max_buckets, plans=plans,
+                    groups=groups)
+        if chosen == "grouped":
+            outs, stats = eval_netlists_batched_jax(
+                nets, pi_lanes_list, n_lane_words, use_pallas=use_pallas,
+                return_stats=True, program=program)
+            stats = dict(stats)
+        else:
+            outs = [evaluate_netlist(n, ln, n_lane_words,
+                                     use_pallas=use_pallas, plan=pl)
+                    for n, ln, pl in zip(nets, pi_lanes_list, plans)]
+            # the per-circuit path runs one program per circuit — report
+            # that as the group count regardless of how this branch was
+            # reached (the cost model's candidate clustering, when auto
+            # computed one, is in stats["cost_model"])
+            stats = {"n_groups": len(nets), "groups": [],
+                     "n_programs": len(nets)}
+        stats["mode"] = chosen
+        if model is not None:
+            stats["cost_model"] = model
+    return outs, stats
+
+
+def _plan_suite(nets, program, mode, max_groups, max_buckets, warm,
+                n_lane_words, use_pallas):
+    """:func:`evaluate_suite`'s choice of path: ``(plans, groups,
+    model, chosen)`` — the envelope groups when a branch needs them, the
+    cost model's record in auto."""
     if program is not None:
-        outs, stats = eval_netlists_batched_jax(
-            nets, pi_lanes_list, n_lane_words, use_pallas=use_pallas,
-            return_stats=True, program=program)
-        stats = dict(stats, mode="grouped")
-        return outs, stats
+        return None, None, None, "grouped"
     if mode not in ("auto", "grouped", "per_circuit"):
         raise ValueError(f"unknown evaluate_suite mode {mode!r}")
     from .eval_jax import group_plans_by_envelope
@@ -449,29 +486,9 @@ def evaluate_suite(nets: list[Netlist],
                                      n_lane_words=n_lane_words,
                                      use_pallas=use_pallas)
         chosen = model["pick"]
-    if chosen == "grouped":
-        if groups is None:
-            groups = group_plans_by_envelope(plans, max_groups=max_groups)
-        program = prepare_suite_program(nets, max_buckets=max_buckets,
-                                        plans=plans, groups=groups)
-        outs, stats = eval_netlists_batched_jax(
-            nets, pi_lanes_list, n_lane_words, use_pallas=use_pallas,
-            return_stats=True, program=program)
-        stats = dict(stats)
-    else:
-        outs = [evaluate_netlist(n, ln, n_lane_words,
-                                 use_pallas=use_pallas, plan=pl)
-                for n, ln, pl in zip(nets, pi_lanes_list, plans)]
-        # the per-circuit path runs one program per circuit — report
-        # that as the group count regardless of how this branch was
-        # reached (the cost model's candidate clustering, when auto
-        # computed one, is in stats["cost_model"])
-        stats = {"n_groups": len(nets), "groups": [],
-                 "n_programs": len(nets)}
-    stats["mode"] = chosen
-    if model is not None:
-        stats["cost_model"] = model
-    return outs, stats
+    if chosen == "grouped" and groups is None:
+        groups = group_plans_by_envelope(plans, max_groups=max_groups)
+    return plans, groups, model, chosen
 
 
 # ---------------------------------------------------------------------------

@@ -50,9 +50,7 @@ import numpy as np
 
 from .alm import ArchParams
 from .netlist import CONST0, CONST1, Netlist
-
-#: diagnostic counters from the most recent :func:`pack` call
-LAST_PACK_DEBUG: dict[str, int] = {}
+from .spans import span
 
 #: drive the greedy re-cluster replay through the vectorized
 #: ClusterPlan columns (numpy candidate-LB gathers, CSR frontier bumps,
@@ -673,9 +671,33 @@ def _build_cluster_plan(net, alms, chain_alm_runs, chain_site, pairs,
                        skel_ah_len=skel_ah_len, skel_ah_pad=skel_ah_pad)
 
 
+#: the hosting counters of one re-clustering: hosting probes (calls of
+#: the per-ALM hosting scan), probes that hosted, hostings taken back
+#: (the first half of a split pair whose second half found no ALM), and
+#: ALMs the scan rejected, by reason.  The rejections count ALMs
+#: scanned, not probes.
+HOST_COUNTERS = ("host_probes", "hosted", "unhosted", "rej_mask",
+                 "rej_nofree", "rej_bypass", "rej_pin8", "rej_strictz",
+                 "rej_zbud", "rej_lbin")
+
+
 def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
              chain_site, lut_site, allow_unrelated=True,
              strict_phases=(True, False), pull_runs=True, replay=None):
+    """The greedy re-clustering, inside a ``repro.pack.cluster`` span
+    that carries its size and :data:`HOST_COUNTERS`."""
+    with span("repro.pack.cluster", atoms=len(plan.atoms)) as sp:
+        packed, counts = _cluster_greedy(
+            net, arch, alms, chain_alm_runs, plan, chain_site, lut_site,
+            allow_unrelated=allow_unrelated, strict_phases=strict_phases,
+            pull_runs=pull_runs, replay=replay)
+        sp.set(lbs=len(packed.lbs), **counts)
+    return packed
+
+
+def _cluster_greedy(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
+                    chain_site, lut_site, allow_unrelated, strict_phases,
+                    pull_runs, replay):
     atoms = plan.atoms
     n_atoms = len(atoms)
     vector = VECTOR_CLUSTER and plan.cand_ptr is not None
@@ -696,6 +718,9 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
     lb_list: list[LB] = []
     alm_lb: list[int] = [-1] * len(alms)
     concurrent = 0
+    # hosting counters (HOST_COUNTERS), plain locals of the hottest loop
+    host_probes = hosted = unhosted = rej_mask = rej_nofree = 0
+    rej_bypass = rej_pin8 = rej_strictz = rej_zbud = rej_lbin = 0
 
     if vector:
         # runtime copies of the skeleton host-feasibility rows, refreshed
@@ -816,7 +841,8 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
         return _host_in_one_alm(lut_list, lb_idx, strict_z, ok_mask)
 
     def _unhost(li: int, lb_idx: int, snapshot):
-        nonlocal concurrent
+        nonlocal concurrent, unhosted
+        unhosted += 1
         st = lbs_state[lb_idx]
         ai = lut_site.pop(li)
         alm_io_cache.pop(ai, None)
@@ -891,11 +917,11 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
 
     def _host_in_one_alm(lut_list: list[int], lb_idx: int,
                          strict_z: bool = False, ok_mask=None) -> bool:
-        nonlocal concurrent
+        nonlocal concurrent, host_probes, hosted, rej_mask, rej_nofree
+        nonlocal rej_bypass, rej_pin8, rej_strictz, rej_zbud, rej_lbin
         if not (arch.concurrent and allow_unrelated):
             return False
-        dbg = LAST_PACK_DEBUG
-        dbg["host_calls"] = dbg.get("host_calls", 0) + 1
+        host_probes += 1
         st = lbs_state[lb_idx]
         hostable = st.hostable
         i = 0
@@ -918,9 +944,10 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
                 # the batched mask already proved an ALM-level rejection
                 # (free halves / bypass width / 8-pin budget) — skip the
                 # per-ALM set builds; survivors re-derive them below
+                rej_mask += 1
                 continue
             if len(free_halves) < len(lut_list):
-                dbg["rej_nofree"] = dbg.get("rej_nofree", 0) + 1
+                rej_nofree += 1
                 continue
             # input budget at ALM level: all residents' A-H pins <= 8
             ah, z, _ = alm_io(ai)
@@ -942,21 +969,21 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
                     moved_z.add(s)
                     new_ah.discard(s)
             if over_bypass:
-                dbg["rej_bypass"] = dbg.get("rej_bypass", 0) + 1
+                rej_bypass += 1
                 continue
             if len(new_ah) > 8:
-                dbg["rej_pin8"] = dbg.get("rej_pin8", 0) + 1
+                rej_pin8 += 1
                 continue
             z_ext = (moved_z | z) - st.produced if arch.z_local_free else (moved_z | z)
             if strict_z and (z_ext - st.z_ext):
-                dbg["rej_strictz"] = dbg.get("rej_strictz", 0) + 1
+                rej_strictz += 1
                 continue
             if len(st.z_ext | z_ext) > arch.z_sources:
-                dbg["rej_zbud"] = dbg.get("rej_zbud", 0) + 1
+                rej_zbud += 1
                 continue
             new_in = set(new_ah) | moved_z
             if not st.fits_inputs(new_in - st.produced, z_ext):
-                dbg["rej_lbin"] = dbg.get("rej_lbin", 0) + 1
+                rej_lbin += 1
                 continue
             # commit
             alm_io_cache.pop(ai, None)
@@ -974,6 +1001,7 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
             st.add(new_in, new_prod, z_ext)
             if vector:
                 cols_dirty.add(ai)
+            hosted += 1
             return True
         if not hostable:
             host_capacity_lbs.discard(lb_idx)
@@ -1321,4 +1349,7 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
     return PackedCircuit(
         net=net, arch=arch, alms=alms, lbs=lb_list, lut_site=lut_site,
         chain_site=chain_site, alm_lb=alm_lb, concurrent_luts=concurrent,
-    )
+    ), dict(host_probes=host_probes, hosted=hosted, unhosted=unhosted,
+            rej_mask=rej_mask, rej_nofree=rej_nofree, rej_bypass=rej_bypass,
+            rej_pin8=rej_pin8, rej_strictz=rej_strictz, rej_zbud=rej_zbud,
+            rej_lbin=rej_lbin)

@@ -44,7 +44,7 @@ import numpy as np
 from . import plan as _planner
 from .alm import ArchParams
 from .netlist import CONST1, Netlist
-from .packing import (ALM, LAST_PACK_DEBUG, ClusterPlan, Half, PackedCircuit,
+from .packing import (ALM, ClusterPlan, Half, PackedCircuit,
                       _atom_sigs_of, _build_cluster_plan, _cluster,
                       _fanout_counts, _pair_luts)
 
@@ -250,7 +250,6 @@ def repack(prefix: PackPrefix, arch: ArchParams,
     budgets.  Byte-identical to ``pack(prefix.net, arch, prefix.seed)``
     by construction, at the cost of one skeleton copy instead of the
     whole prefix."""
-    LAST_PACK_DEBUG.clear()
     return _cluster(prefix.net, arch, _copy_skeleton(prefix.alms),
                     prefix.chain_alm_runs, prefix.plan,
                     dict(prefix.chain_site), dict(prefix.lut_site),
@@ -612,7 +611,6 @@ def repack_with_log(prefix: PackPrefix, arch: ArchParams,
     """:func:`repack` with decision recording — same pack, plus the
     :class:`RepackLog` a later :func:`repack_delta` replays against."""
     log = RepackLog(arch, allow_unrelated, strict_phases, pull_runs)
-    LAST_PACK_DEBUG.clear()
     pack = _cluster(prefix.net, arch, _copy_skeleton(prefix.alms),
                     prefix.chain_alm_runs, prefix.plan,
                     dict(prefix.chain_site), dict(prefix.lut_site),
@@ -985,7 +983,6 @@ def repack_delta(new_prefix: PackPrefix, base_log: RepackLog | None,
         pack = repack(new_prefix, arch, allow_unrelated=allow_unrelated)
         return pack, {"mode": "full", "reason": "no_log"}
     adv = ReplayAdvisor(base_log, dirty_atoms, max_div=max_div)
-    LAST_PACK_DEBUG.clear()
     pack = _cluster(new_prefix.net, arch, _copy_skeleton(new_prefix.alms),
                     new_prefix.chain_alm_runs, new_prefix.plan,
                     dict(new_prefix.chain_site), dict(new_prefix.lut_site),

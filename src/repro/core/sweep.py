@@ -32,6 +32,7 @@ from . import plan as _planner
 from .alm import ArchParams, group_archs_by_structure
 from .netlist import Netlist
 from .packing import PackedCircuit, pack
+from .spans import span
 
 #: packing prefixes per (circuit digest, seed) — the default store behind
 #: ``sweep_suite(prefixes=None)``.  Registry-backed so ONE
@@ -237,14 +238,14 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                 packs[(digests[g], skeys[c], seed)] = p
             circ_packs.append(p)
         wall["pack_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
         for c, p in enumerate(circ_packs):
             tpl = prefix.ir_template if prefix is not None else None
-            ir = p.lower_ir(template=tpl)
+            with span("repro.ir.lower", wall, "lower_s",
+                      incremental=int(tpl is not None)):
+                ir = p.lower_ir(template=tpl)
             if prefix is not None and prefix.ir_template is None:
                 prefix.ir_template = ir
             all_irs[c].append(ir)
-        wall["lower_s"] += time.perf_counter() - t0
     # --- phase 2: batched timing, class-outer ---------------------------
     # With placement, a class's rows are further subgrouped by grid
     # aspect: aspect reshapes the slot grid (hence every hop column) but
@@ -280,7 +281,6 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                 use_irs = irs
             tables = np.stack([archs[i].delay_table() for i in sub_idx])
             if backend == "jax":
-                t0 = time.perf_counter()
                 # pkey/refine last: positions of the pre-placement key
                 # elements (suite, skey, seed, buckets, groups) stay
                 # stable for callers/tests that probe grouping knobs by
@@ -290,23 +290,25 @@ def sweep_suite(nets, archs: Sequence[ArchParams], seed: int = 0,
                 prog_key = (suite_key, skey, seed, max_buckets,
                             max_groups, pkey,
                             refine if place else None)
-                progs = programs.get(prog_key)
-                if progs is None:
-                    groups = _envelope_groups(use_irs, max_groups)
-                    progs = [(members,
-                              build_suite_timing_program(
-                                  [use_irs[i] for i in members],
-                                  max_buckets=max_buckets))
-                             for members in groups]
-                    programs[prog_key] = progs
-                wall["build_s"] += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                cps = np.zeros((len(use_irs), len(sub_idx)), dtype=np.int64)
-                for members, prog in progs:
-                    gcps = prog.run(tables)
-                    for row, gi in enumerate(members):
-                        cps[gi] = gcps[row]
-                wall["timing_s"] += time.perf_counter() - t0
+                with span("repro.timing.build", wall, "build_s") as sp:
+                    progs = programs.get(prog_key)
+                    if progs is None:
+                        groups = _envelope_groups(use_irs, max_groups)
+                        progs = [(members,
+                                  build_suite_timing_program(
+                                      [use_irs[i] for i in members],
+                                      max_buckets=max_buckets))
+                                 for members in groups]
+                        programs[prog_key] = progs
+                    sp.set(groups=len(progs))
+                with span("repro.timing.run", wall, "timing_s",
+                          rows=len(sub_idx)):
+                    cps = np.zeros((len(use_irs), len(sub_idx)),
+                                   dtype=np.int64)
+                    for members, prog in progs:
+                        gcps = prog.run(tables)
+                        for row, gi in enumerate(members):
+                            cps[gi] = gcps[row]
             elif backend == "numpy":
                 t0 = time.perf_counter()
                 cps = np.zeros((len(use_irs), len(sub_idx)), dtype=np.int64)
