@@ -84,10 +84,9 @@ def _eval_programs_have_kernel(nets, stats, n_words: int) -> bool:
     texts = []
     if stats["mode"] == "grouped":
         prog = flow.prepare_suite(nets)
-        for members, (n_sig, stacked, flags, _) in zip(prog.groups,
-                                                       prog.programs):
+        for members, g in zip(prog.groups, prog.programs):
             texts.append(eval_jax._run_fused_batch.lower(
-                vals(len(members), n_sig + 1), stacked, flags=flags,
+                vals(len(members), g.n_sig + 1), g.stacked, flags=g.flags,
                 use_pallas=True).as_text())
     else:
         for net in nets:

@@ -41,16 +41,19 @@ the shared registry (:mod:`repro.core.plan` — ``eval_plans`` /
 ``eval_groups``), alongside the functional IRs; one
 :func:`repro.core.plan.clear_caches` invalidates everything.
 
-The value buffer is donated to the jit (``donate_argnums``), so evaluation
-reuses it in place.  The seed per-level dispatcher (one kernel launch per
-level from a Python loop) survives as :func:`eval_netlist_jax_levels` — the
-baseline the perf trajectory measures against — and the Python
-``eval_netlist`` oracle in ``netlist.py`` stays the ground truth in tests.
+The value buffer is built on the device (:func:`_device_vals`): only the
+primary inputs' lanes cross the host link, and the buffer is donated to
+the evaluation jit (``donate_argnums``), which reuses it in place.  The
+seed per-level dispatcher (one kernel launch per level from a Python loop)
+survives as :func:`eval_netlist_jax_levels` — the baseline the perf
+trajectory measures against — and the Python ``eval_netlist`` oracle in
+``netlist.py`` stays the ground truth in tests.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -400,16 +403,58 @@ def _run_fused_batch(vals, bucket_arrays, *, flags, use_pallas):
     )(vals, bucket_arrays)
 
 
-def _init_vals(plan: FusedPlan, pi_lanes: dict[int, np.ndarray],
-               n_lane_words: int) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("n_rows",))
+def _device_vals(pi_idx, pi_rows, *, n_rows):
+    """The value buffer, built on the device: zeros, ``CONST1`` all ones,
+    and each primary input's lanes at its signal row.  ``pi_idx[..., P]``
+    holds the signal rows, ``pi_rows[..., P, N]`` their lanes; a padded
+    slot's index (``>= n_rows``) writes nothing.  A leading axis of both
+    is the group's member axis.
+
+    ``CONST1`` goes in as one more scattered row: XLA folds a buffer that
+    is constant (zeros with a row of ones) into the program as a literal
+    of the buffer's size, which the compile cache then stores and loads.
+    """
+    def one(idx, rows):
+        n = rows.shape[-1]
+        idx = jnp.concatenate([jnp.full((1,), CONST1, dtype=idx.dtype), idx])
+        rows = jnp.concatenate(
+            [jnp.full((1, n), 0xFFFFFFFF, dtype=jnp.uint32), rows])
+        return jnp.zeros((n_rows, n), dtype=jnp.uint32).at[idx].set(
+            rows, mode="drop")
+    if pi_idx.ndim == 1:
+        return one(pi_idx, pi_rows)
+    return jax.vmap(one)(pi_idx, pi_rows)
+
+
+def _pi_slots(net: Netlist) -> dict[int, int]:
+    """Each primary input's slot in the packed PI rows: its place in
+    ``net.pis``."""
+    return {s: j for j, s in enumerate(net.pis)}
+
+
+def _fill_pi_rows(slots: list[dict[int, int]],
+                  pi_lanes_list: list[dict[int, np.ndarray]],
+                  n_pis: int, n_lane_words: int) -> np.ndarray:
+    """``[len(slots), n_pis, N]`` host lanes of the primary inputs, each
+    in its slot; an input missing from its dict stays 0.  A key that is
+    not a primary input of its netlist raises ``ValueError``."""
     with span("repro.eval.fill") as sp:
-        vals = np.zeros((plan.n_signals + 1, n_lane_words), dtype=np.uint32)
-        vals[CONST1] = 0xFFFFFFFF
-        for s, v in pi_lanes.items():
-            vals[s] = np.asarray(v, dtype=np.uint32)
-        sp.set(bytes=vals.nbytes)
-    with span("repro.eval.put", bytes=vals.nbytes):
-        return jnp.asarray(vals).block_until_ready()
+        rows = np.zeros((len(slots), n_pis, n_lane_words), dtype=np.uint32)
+        for r, (slot, lanes) in enumerate(zip(slots, pi_lanes_list)):
+            for s, v in lanes.items():
+                j = slot.get(s)
+                if j is None:
+                    raise ValueError(
+                        f"signal {s} is not a primary input of its netlist")
+                rows[r, j] = np.asarray(v, dtype=np.uint32)
+        sp.set(bytes=rows.nbytes)
+    return rows
+
+
+def _put(rows: np.ndarray) -> jax.Array:
+    with span("repro.eval.put", bytes=rows.nbytes):
+        return jnp.asarray(rows).block_until_ready()
 
 
 def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
@@ -417,14 +462,19 @@ def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
                      plan: FusedPlan | None = None) -> jax.Array:
     """Fused evaluation; returns ``vals[n_signals, n_lane_words]`` uint32.
 
-    ``pi_lanes[signal]`` is a uint32 vector of packed test vectors.  Pass a
-    precompiled ``plan`` to skip the content-digest cache lookup (the jit
-    cache amortizes compilation by shape either way).
+    ``pi_lanes[signal]`` is a uint32 vector of packed test vectors, keyed
+    by primary inputs of ``net`` (any other key raises ``ValueError``; a
+    missing input reads 0).  Pass a precompiled ``plan`` to skip the
+    content-digest cache lookup (the jit cache amortizes compilation by
+    shape either way).
     """
     if plan is None:
         plan = plan_netlist(net)
-    vals = _init_vals(plan, pi_lanes, n_lane_words)
+    rows = _put(_fill_pi_rows([_pi_slots(net)], [pi_lanes], len(net.pis),
+                              n_lane_words)[0])
     with span("repro.eval.run"):
+        vals = _device_vals(np.asarray(net.pis, dtype=np.int32), rows,
+                            n_rows=plan.n_signals + 1)
         out = _run_fused(vals, plan.device_arrays(), flags=plan.flags,
                          use_pallas=use_pallas).block_until_ready()
     mark_program_run(program_signature(plan, n_lane_words, use_pallas))
@@ -473,12 +523,25 @@ def group_layout(irs, max_buckets: int = DEFAULT_MAX_BUCKETS):
             "rows_per_member": _planner.padded_rows(bounds, envelopes)}
 
 
-def _build_group(nets: list[Netlist], max_buckets: int):
+class GroupProgram(NamedTuple):
+    """One envelope group's cached device program inputs."""
+
+    n_sig: int                    # value rows per member, sink excluded
+    stacked: tuple                # per-bucket stacked plan tensors
+    flags: tuple                  # static per-bucket (has_luts, has_chains)
+    member_plans: list            # each member's plan, padded to the group
+    pi_index: jax.Array           # [members, P] int32 PI signal rows
+    pi_slots: list                # per member: PI signal -> slot in P
+
+
+def _build_group(nets: list[Netlist], max_buckets: int) -> GroupProgram:
     """Stack one envelope group's member plans into vmappable tensors.
 
     Bucket boundaries are recomputed on the group's combined width profile
     and every member is padded to the group envelope; each member's sink
-    rows point at the shared ``n_sig`` row.
+    rows point at the shared ``n_sig`` row.  Each member's primary inputs
+    are indexed in ``net.pis`` order, padded to the group's largest PI
+    count with the out-of-range row ``n_sig + 1``.
     """
     irs = [lower_netlist_ir(net) for net in nets]
     n_sig = max(net.n_signals for net in nets)
@@ -497,11 +560,18 @@ def _build_group(nets: list[Netlist], max_buckets: int):
                                     for p in member_plans]))
               for ai in range(10))
         for bi in range(len(bounds)))
-    return n_sig, stacked, flags, member_plans
+    n_pis = max(len(net.pis) for net in nets)
+    pi_index = np.full((len(nets), n_pis), n_sig + 1, dtype=np.int32)
+    for row, net in enumerate(nets):
+        pi_index[row, :len(net.pis)] = net.pis
+    return GroupProgram(n_sig, stacked, flags, member_plans,
+                        jnp.asarray(pi_index),
+                        [_pi_slots(net) for net in nets])
 
 
 def get_group_program(nets: list[Netlist],
-                      max_buckets: int = DEFAULT_MAX_BUCKETS):
+                      max_buckets: int = DEFAULT_MAX_BUCKETS
+                      ) -> GroupProgram:
     """Cached stacked device tensors for one envelope group of netlists."""
     key = (tuple(netlist_digest(net) for net in nets), max_buckets)
     cached = _GROUP_CACHE.get(key)
@@ -517,42 +587,38 @@ class SuiteProgram:
 
     ``run`` evaluates new lanes without re-digesting, re-clustering or
     re-uploading anything — the handle benchmark loops should reuse.
+    Only the primary inputs' lanes cross to the device; the value buffer
+    is built there.
     """
 
     n_signals: list[int]          # per input circuit
     names: list[str]
     groups: list[list[int]]       # member indices per envelope group
-    programs: list[tuple]         # (n_sig, stacked, flags, member_plans)
+    programs: list[GroupProgram]
     stats: dict
 
     def run(self, pi_lanes_list: list[dict[int, np.ndarray]],
             n_lane_words: int, use_pallas: bool = True) -> list[np.ndarray]:
         outs: list = [None] * len(self.n_signals)
-        for members, (n_sig, stacked, flags,
-                      member_plans) in zip(self.groups, self.programs):
-            with span("repro.eval.fill") as sp:
-                vals = np.zeros((len(members), n_sig + 1, n_lane_words),
-                                dtype=np.uint32)
-                vals[:, CONST1] = 0xFFFFFFFF
-                for row, i in enumerate(members):
-                    for s, v in pi_lanes_list[i].items():
-                        vals[row, s] = np.asarray(v, dtype=np.uint32)
-                sp.set(bytes=vals.nbytes)
+        for members, g in zip(self.groups, self.programs):
+            rows = _put(_fill_pi_rows(
+                g.pi_slots, [pi_lanes_list[i] for i in members],
+                g.pi_index.shape[1], n_lane_words))
             # the program consumes its input right away, and np.asarray
             # blocks on the result: the two block_until_ready calls move
             # no work, they only bound the transfer and the device run
-            with span("repro.eval.put", bytes=vals.nbytes):
-                dev_vals = jnp.asarray(vals).block_until_ready()
             with span("repro.eval.run"):
-                out = _run_fused_batch(dev_vals, stacked, flags=flags,
+                vals = _device_vals(g.pi_index, rows, n_rows=g.n_sig + 1)
+                out = _run_fused_batch(vals, g.stacked, flags=g.flags,
                                        use_pallas=use_pallas
                                        ).block_until_ready()
-            with span("repro.eval.get", bytes=vals.nbytes):
+            with span("repro.eval.get") as sp:
                 out = np.asarray(out)
+                sp.set(bytes=out.nbytes)
             # all members share the group layout, so member 0's plan IS
             # the group's program shape signature
             mark_program_run(program_signature(
-                member_plans[0], n_lane_words, use_pallas,
+                g.member_plans[0], n_lane_words, use_pallas,
                 batch=len(members)))
             for row, i in enumerate(members):
                 outs[i] = out[row, :self.n_signals[i]]
@@ -577,8 +643,8 @@ def prepare_suite_program(nets: list[Netlist],
                                   max_buckets=max_buckets)
                 for members in groups]
     stats = {"n_groups": len(groups), "groups": []}
-    for members, (_, _, _, member_plans) in zip(groups, programs):
-        gp = member_plans[0]
+    for members, g in zip(groups, programs):
+        gp = g.member_plans[0]
         stats["groups"].append({
             "members": [nets[i].name for i in members],
             "n_buckets": len(gp.buckets),

@@ -112,13 +112,36 @@ def test_grouped_eval_compiles_for_v5e(one_chip, suite_nets, n_words,
     prog = prepare_suite_program(suite_nets)
     names = [[prog.names[i] for i in g] for g in prog.groups]
     gi = next(i for i, g in enumerate(names) if WIDEST in g)
-    n_sig, stacked, flags, _ = prog.programs[gi]
-    vals = jax.ShapeDtypeStruct((len(prog.groups[gi]), n_sig + 1, n_words),
+    g = prog.programs[gi]
+    vals = jax.ShapeDtypeStruct((len(prog.groups[gi]), g.n_sig + 1, n_words),
                                 jnp.uint32, sharding=one_chip)
     compiled = _run_fused_batch.lower(
-        vals, _shapes(stacked, one_chip), flags=flags,
+        vals, _shapes(g.stacked, one_chip), flags=g.flags,
         use_pallas=True).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("members,n_sig,n_pis", [
+    (1, 20_355, 96), (1, 59_829, 384), (1, 27_819, 192), (1, 8_164, 192),
+    (2, 59_829, 384)])
+def test_device_value_buffer_compiles_for_v5e(one_chip, members, n_sig,
+                                              n_pis):
+    """The value buffer built on the chip from the PI rows, at the jsc-fc1
+    to jsc-fc4 layers' signal and primary-input counts and 4,096 lane
+    words.  The program holds no constant of a buffer's size: XLA folded
+    jsc-fc4's zeros-and-ones buffer into a 133 MB literal once."""
+    import re
+
+    from repro.core.eval_jax import _device_vals
+
+    idx = jax.ShapeDtypeStruct((members, n_pis), jnp.int32,
+                               sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((members, n_pis, 4096), jnp.uint32,
+                                sharding=one_chip)
+    text = _device_vals.lower(idx, rows, n_rows=n_sig + 1).compile().as_text()
+    assert "scatter" in text
+    for dims in re.findall(r"\[([\d,]+)\]\S* constant\(", text):
+        assert np.prod([int(d) for d in dims.split(",")]) < n_sig, dims
 
 
 @pytest.fixture(scope="module")

@@ -6,9 +6,12 @@ import numpy as np
 import pytest
 
 from repro.core.circuits import koios_mac_array, kratos_gemm, sha_like
-from repro.core.eval_jax import (eval_netlist_jax, eval_netlist_jax_levels,
-                                 eval_netlists_batched_jax, plan_netlist)
-from repro.core.netlist import bus_to_ints, eval_netlist
+from repro.core.eval_jax import (_device_vals, _fill_pi_rows,
+                                 eval_netlist_jax, eval_netlist_jax_levels,
+                                 eval_netlists_batched_jax, plan_netlist,
+                                 prepare_suite_program)
+from repro.core.flow import _lanes_int
+from repro.core.netlist import CONST1, bus_to_ints, eval_netlist
 
 
 @pytest.mark.parametrize("mk", [
@@ -135,3 +138,69 @@ def test_grouped_eval_respects_max_groups_and_matches_single():
         for bus in net.pos.values():
             for s in bus:
                 assert np.array_equal(got[s], single[s]), (net.name, s)
+
+
+def _pi_group():
+    """Two circuits of different PI counts in one envelope group, so the
+    smaller one's PI slots are padded; one PI of it gets no lanes."""
+    nets = [kratos_gemm(m=3, n=3, width=4, sparsity=0.3),
+            sha_like(rounds=1)]
+    assert len({len(n.pis) for n in nets}) == 2
+    rng = np.random.default_rng(4)
+    NW = 2
+    lanes_list = [{s: rng.integers(0, 2**32, NW, dtype=np.uint32)
+                   for s in net.pis} for net in nets]
+    small = min(range(2), key=lambda i: len(nets[i].pis))
+    del lanes_list[small][nets[small].pis[1]]
+    prog = prepare_suite_program(nets, max_groups=1)
+    assert prog.groups == [[0, 1]]
+    return nets, lanes_list, NW, prog
+
+
+@pytest.mark.parametrize("mode", ["grouped", "per_circuit"])
+def test_device_built_buffer_evaluates_every_signal(mode):
+    """The value buffer built on the device from the PI rows alone gives
+    the oracle's value on every signal, a PI without lanes reading 0."""
+    nets, lanes_list, NW, prog = _pi_group()
+    if mode == "grouped":
+        outs = prog.run(lanes_list, NW)
+    else:
+        outs = [np.asarray(eval_netlist_jax(net, lanes, NW))
+                for net, lanes in zip(nets, lanes_list)]
+    for net, lanes, got in zip(nets, lanes_list, outs):
+        assert got.shape == (net.n_signals, NW)
+        pi_vals = {s: _lanes_int(lanes[s]) if s in lanes else 0
+                   for s in net.pis}
+        ref = eval_netlist(net, pi_vals, 32 * NW)
+        for s in range(net.n_signals):
+            assert _lanes_int(got[s]) == ref.get(s, 0), (net.name, s)
+
+
+def test_device_built_buffer_equals_host_built():
+    """Bit for bit the buffer a host would fill: zeros, CONST1 all ones,
+    each PI's lanes at its row; padded PI slots write nothing."""
+    nets, lanes_list, NW, prog = _pi_group()
+    (g,) = prog.programs
+    rows = _fill_pi_rows(g.pi_slots, lanes_list, g.pi_index.shape[1], NW)
+    padded = np.asarray(g.pi_index) > g.n_sig
+    assert padded.any()
+    rows[padded] = 0xDEADBEEF
+    got = np.asarray(_device_vals(g.pi_index, rows, n_rows=g.n_sig + 1))
+    want = np.zeros((len(nets), g.n_sig + 1, NW), dtype=np.uint32)
+    want[:, CONST1] = 0xFFFFFFFF
+    for row, lanes in enumerate(lanes_list):
+        for s, v in lanes.items():
+            want[row, s] = v
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("mode", ["grouped", "per_circuit"])
+def test_lanes_keyed_off_the_pis_raise(mode):
+    """A key that is no primary input has no row to go to."""
+    nets, lanes_list, NW, prog = _pi_group()
+    lanes_list[0][nets[0].lut_out[0]] = np.ones(NW, dtype=np.uint32)
+    with pytest.raises(ValueError, match="not a primary input"):
+        if mode == "grouped":
+            prog.run(lanes_list, NW)
+        else:
+            eval_netlist_jax(nets[0], lanes_list[0], NW)

@@ -120,10 +120,12 @@ def test_eval_spans_count_the_call(captured):
     moved = {name: sum(st["bytes"] for st in _stats(captured, name))
              for name in ("repro.eval.fill", "repro.eval.put",
                           "repro.eval.get")}
-    # both calls hold every circuit's signals, 4 words of 4 bytes each
+    # both calls send only the primary inputs' lanes, 4 words of 4 bytes
+    # each (one circuit per group, so no padded PI slot), and get back
+    # every signal
     assert moved["repro.eval.put"] == moved["repro.eval.fill"] \
-        >= 2 * sum(n.n_signals for n in nets) * 16
-    assert 0 < moved["repro.eval.get"] <= moved["repro.eval.put"]
+        == 2 * sum(len(n.pis) for n in nets) * 16
+    assert moved["repro.eval.get"] >= 2 * sum(n.n_signals for n in nets) * 16
 
 
 def test_spans_change_no_output(captured):
