@@ -62,6 +62,11 @@ DELAY_FIELDS = (
 #: timing's integer unit: centi-picoseconds per picosecond
 CPS_PER_PS = 100
 
+#: registers (flip-flops) per ALM, as in the Stratix 10 ALM.  Assumed for
+#: every architecture of the family: the paper's ALMs differ in their
+#: LUT/adder paths, not in their registers.
+FF_PER_ALM = 4
+
 
 def _to_cps(ps: float) -> int:
     """A delay in picoseconds as whole centi-picoseconds; a delay finer
@@ -70,6 +75,17 @@ def _to_cps(ps: float) -> int:
     if abs(ps * CPS_PER_PS - cps) > 1e-6:
         raise ValueError(f"delay {ps!r} ps is not a multiple of 0.01 ps")
     return int(cps)
+
+
+#: a register's clock-to-Q and setup delays (ps, and in the timing's
+#: centi-picoseconds).  Assumed constants, the same on every architecture,
+#: and not rows of the delay table: a timing path starts at a Q
+#: ``T_CLK_Q_PS`` after the clock edge and ends at a D ``T_SETUP_PS``
+#: before the next one.
+T_CLK_Q_PS = 120.0
+T_SETUP_PS = 60.0
+T_CLK_Q_CPS = _to_cps(T_CLK_Q_PS)
+T_SETUP_CPS = _to_cps(T_SETUP_PS)
 
 
 @dataclass(frozen=True)

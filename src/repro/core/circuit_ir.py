@@ -81,7 +81,7 @@ engine is asserted against it in ``tests/core/test_circuit_ir.py``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import TYPE_CHECKING
 
 import numpy as np
@@ -112,6 +112,9 @@ N_WIRE_TIERS = 4
 # node delay classes for LUT rows
 NDC_ABSORBED, NDC_LUT4, NDC_LUT5, NDC_LUT6 = range(4)
 N_NODE_CLASSES = 4
+
+
+_EMPTY_I32 = np.zeros(0, dtype=np.int32)
 
 
 def edge_class(route: int, pin: int, path: int) -> int:
@@ -234,6 +237,10 @@ class CircuitIR:
     grid_w: int = 0
     grid_h: int = 0
     placement_seed: int | None = None
+    # registers, one entry each: Q is a source row (a timing path starts
+    # there), D a sink row (a path ends there); empty without registers
+    reg_q: np.ndarray = field(default_factory=lambda: _EMPTY_I32)
+    reg_d: np.ndarray = field(default_factory=lambda: _EMPTY_I32)
 
     @property
     def placed(self) -> bool:
@@ -421,8 +428,19 @@ def _lower_functional(net: Netlist, digest: str) -> CircuitIR:
         lut_levels=tuple(lut_levels), chain_levels=tuple(chain_levels),
         po_sig=po_sig,
         n_alms=0, n_lbs=0, n_luts=net.n_luts, n_adders=net.n_adders,
-        concurrent_luts=0,
+        concurrent_luts=0, **_reg_columns(net),
     )
+
+
+def _reg_columns(net: Netlist) -> dict:
+    """``reg_q`` / ``reg_d`` of a netlist with registers (none without,
+    so a combinational IR keeps the field defaults)."""
+    if not net.regs:
+        return {}
+    net.check_regs_connected()
+    d, q = zip(*net.regs)
+    return {"reg_q": np.array(q, dtype=np.int32),
+            "reg_d": np.array(d, dtype=np.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -611,6 +629,8 @@ def _placement_columns(packed: "PackedCircuit") -> dict:
         if ch.cout is not None:
             sig_site[ch.cout] = packed.chain_site.get((ci, len(ch.sums) - 1),
                                                       -2)
+    for ri, (_, q) in enumerate(net.regs):     # Q leaves its FF's ALM
+        sig_site[q] = packed.reg_site.get(ri, -2)
 
     alm_lb_arr = np.asarray(packed.alm_lb, dtype=np.int32) \
         if packed.alm_lb else np.zeros(0, dtype=np.int32)
@@ -784,6 +804,7 @@ def _patch_placement(base: CircuitIR, packed: "PackedCircuit") -> CircuitIR:
         po_sig=base.po_sig,
         n_alms=packed.n_alms, n_lbs=packed.n_lbs, n_luts=net.n_luts,
         n_adders=net.n_adders, concurrent_luts=packed.concurrent_luts,
+        reg_q=base.reg_q, reg_d=base.reg_d,
     )
 
 

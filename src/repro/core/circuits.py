@@ -19,8 +19,8 @@ from __future__ import annotations
 
 import random
 
-from .netlist import (CONST0, Netlist, TT_AND2, TT_MAJ3, TT_NOT, TT_OR2,
-                      TT_XOR2, TT_XOR3, tt_from_fn)
+from .netlist import (CONST0, Netlist, TT_AND2, TT_MAJ3, TT_MUX, TT_NOT,
+                      TT_OR2, TT_XOR2, TT_XOR3, tt_from_fn)
 from .synth import synth_dot_const, synth_var_mult, Row, reduce_rows
 from .techmap import techmap
 
@@ -198,6 +198,69 @@ def koios_suite(algo="wallace", scale=1.0, seed=0) -> list[Netlist]:
         koios_mac_array("lstm-like", pes=max(2, int(4 * s)), width=8,
                         algo=algo, seed=seed + 4, ctrl_nodes=150),
     ]
+
+
+def systolic_ws(name="tpu-sa", rows=32, cols=32, width=8, psum_width=32,
+                algo="wallace") -> Netlist:
+    """A weight-stationary systolic matrix unit, as the TPU v1's (Jouppi et
+    al., ISCA 2017): ``rows x cols`` multiply-accumulate cells, activations
+    entering from the left, weights from the top, partial sums flowing
+    down the columns.  A sequential netlist: every connection between
+    cells goes through a register.  Operands are signed and weights are
+    double-buffered.
+
+    Cell ``(i, j)`` holds
+
+    * ``x_{i}_{j}`` — the activation (``width`` bits), loaded from the
+      left neighbour's (column 0: primary input ``x{i}``) every cycle;
+    * ``wsh_{i}_{j}`` — the shadow weight: while ``wshift[j]`` is high it
+      loads the cell above's (row 0: primary input ``w{j}``), else holds;
+    * ``swap_{i}_{j}`` — a one-bit swap token travelling right beside the
+      activation (column 0: primary input ``wswap[i]``);
+    * ``w_{i}_{j}`` — the active weight: loads the shadow weight in the
+      cycle the swap token is in the cell, else holds;
+    * ``p_{i}_{j}`` — the partial sum (``psum_width`` bits): the cell
+      above's (row 0: 0) plus ``x * w``, a signed ``width x width``
+      runtime multiplier (:func:`synth_var_mult`) sign-extended to
+      ``psum_width`` bits and added on a carry chain, wrapping around.
+
+    The primary outputs ``y{j}`` are the bottom row's partial sums.  Every
+    width is a register's: nothing is random, so there is no seed.
+    """
+    net = Netlist(name)
+    x_in = [net.add_pi_bus(f"x{i}", width) for i in range(rows)]
+    w_in = [net.add_pi_bus(f"w{j}", width) for j in range(cols)]
+    wshift = net.add_pi_bus("wshift", cols)
+    wswap = net.add_pi_bus("wswap", rows)
+    cells = [(i, j) for i in range(rows) for j in range(cols)]
+    xr = {c: net.add_reg_bus("x_%d_%d" % c, width) for c in cells}
+    aw = {c: net.add_reg_bus("w_%d_%d" % c, width) for c in cells}
+    sw = {c: net.add_reg_bus("wsh_%d_%d" % c, width) for c in cells}
+    tok = {c: net.add_reg_bus("swap_%d_%d" % c, 1)[0] for c in cells}
+    ps = {c: net.add_reg_bus("p_%d_%d" % c, psum_width) for c in cells}
+
+    for i, j in cells:
+        left = x_in[i] if j == 0 else xr[(i, j - 1)]
+        for q, d in zip(xr[(i, j)], left):
+            net.connect_reg(q, d)
+        net.connect_reg(tok[(i, j)], wswap[i] if j == 0 else tok[(i, j - 1)])
+        above = w_in[j] if i == 0 else sw[(i - 1, j)]
+        for q, d in zip(sw[(i, j)], above):
+            net.connect_reg(q, net.add_lut((wshift[j], q, d), TT_MUX))
+        for q, d in zip(aw[(i, j)], sw[(i, j)]):
+            net.connect_reg(q, net.add_lut((tok[(i, j)], q, d), TT_MUX))
+        prod = synth_var_mult(net, xr[(i, j)], aw[(i, j)], algo=algo,
+                              signed=True, out_width=2 * width)
+        ext = prod + [prod[-1]] * (psum_width - len(prod))
+        if i == 0:
+            acc = ext
+        else:
+            acc, _ = net.add_chain(ps[(i - 1, j)], ext)
+        for q, d in zip(ps[(i, j)], acc):
+            net.connect_reg(q, d)
+    for j in range(cols):
+        net.set_po_bus(f"y{j}", ps[(rows - 1, j)])
+    return techmap(net.sweep())
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,8 @@ def clone_netlist(net: Netlist) -> Netlist:
     c.pis = list(net.pis)
     c.pi_buses = {k: list(v) for k, v in net.pi_buses.items()}
     c.pos = {k: list(v) for k, v in net.pos.items()}
+    c.regs = list(net.regs)
+    c.reg_buses = {k: list(v) for k, v in net.reg_buses.items()}
     c.lut_inputs = list(net.lut_inputs)
     c.lut_tt = list(net.lut_tt)
     c.lut_out = list(net.lut_out)

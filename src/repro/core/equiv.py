@@ -21,7 +21,10 @@ Flow
    * **Z-fed chain operands** (``fa_feed == "z"``, DD only) — operands
      bypass the LUTs entirely and connect straight to the adder;
    * **hosted LUTs** and **6-LUT spans** — the concurrent-use masks of
-     mode-C halves / whole ALMs.
+     mode-C halves / whole ALMs;
+   * **registers** — one per source register, under the same bus name
+     and bit: registers correspond by name (no retiming), so each Q is a
+     free input of both sides and each D an output compared like a PO.
 
    Structural illegalities (a Z feed on a baseline ALM, a hosted LUT wider
    than its site, an absorbed LUT that is not 4-input single-fanout…)
@@ -64,9 +67,10 @@ Flow
 """
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, field
 
-from .alm import ARCHS, ArchParams
+from .alm import ARCHS, FF_PER_ALM, ArchParams
 from .netlist import (CONST0, CONST1, TT_BUF, TT_MAJ3, TT_XOR3, Netlist,
                       eval_netlist, tt_compose, tt_eval, tt_reduce)
 from .packing import PackedCircuit, pack
@@ -117,6 +121,11 @@ def reelaborate(packed: PackedCircuit) -> ReElaboration:
 
     for name, bus in src.pi_buses.items():
         nbus = phys.add_pi_bus(name, len(bus))
+        for s, ns in zip(bus, nbus):
+            sig_map[s] = ns
+    _check_ff_slots(packed)
+    for name, bus in src.reg_buses.items():
+        nbus = phys.add_reg_bus(name, len(bus))
         for s, ns in zip(bus, nbus):
             sig_map[s] = ns
 
@@ -204,7 +213,22 @@ def reelaborate(packed: PackedCircuit) -> ReElaboration:
 
     phys.pos = {name: [mapped(s) for s in bus]
                 for name, bus in src.pos.items()}
+    for d, q in src.regs:
+        phys.connect_reg(mapped(q), mapped(d))
     return ReElaboration(phys=phys, sig_map=sig_map, lut_role=lut_role)
+
+
+def _check_ff_slots(packed: PackedCircuit) -> None:
+    """Every register holds a flip-flop slot of a real ALM, at most
+    :data:`~repro.core.alm.FF_PER_ALM` to an ALM."""
+    for ri in range(len(packed.net.regs)):
+        ai = packed.reg_site.get(ri)
+        if ai is None or not 0 <= ai < len(packed.alms):
+            raise ReElaborationError(f"register {ri} has no flip-flop slot")
+    for ai, n in Counter(packed.reg_site.values()).items():
+        if n > FF_PER_ALM:
+            raise ReElaborationError(
+                f"ALM {ai} holds {n} registers > {FF_PER_ALM}")
 
 
 # ---------------------------------------------------------------------------
@@ -378,9 +402,14 @@ def _prove_nodes(src: Netlist, re_elab: ReElaboration,
 
 
 def _po_ok(src: Netlist, re_elab: ReElaboration) -> bool:
+    """Every primary output, and every register's D (the register of the
+    same name and bit), maps onto its physical counterpart."""
+    sig_map, phys = re_elab.sig_map, re_elab.phys
+    phys_d = {q: d for d, q in phys.regs}
     return all(
-        [re_elab.sig_map.get(s) for s in bus] == re_elab.phys.pos.get(name)
-        for name, bus in src.pos.items())
+        [sig_map.get(s) for s in bus] == phys.pos.get(name)
+        for name, bus in src.pos.items()) and all(
+        sig_map.get(d) == phys_d.get(sig_map.get(q)) for d, q in src.regs)
 
 
 def symbolic_equivalence_report(src: Netlist,
@@ -803,14 +832,17 @@ def equivalence_report(src: Netlist, re_elab: ReElaboration,
     rng = random.Random(seed)
     phys, sig_map = re_elab.phys, re_elab.sig_map
     use_jax = _resolve_use_jax(use_jax, src, phys)
-    pi_vals = {s: rng.getrandbits(n_vectors) for s in src.pis}
+    pi_vals = {s: rng.getrandbits(n_vectors) for s in src.sources}
     phys_pi_vals = {sig_map[s]: v for s, v in pi_vals.items()}
+    outputs = [s for bus in src.pos.values() for s in bus] + [
+        d for d, _ in src.regs]
 
     def mismatch_entry(s: int, diff: int) -> dict:
         vec = (diff & -diff).bit_length() - 1
         return {
             "signal": s, "phys_signal": sig_map[s], "vector": vec,
-            "pi_assignment": {p: (pi_vals[p] >> vec) & 1 for p in src.pis},
+            "pi_assignment": {p: (pi_vals[p] >> vec) & 1
+                              for p in src.sources},
         }
 
     mismatched: list[dict] = []
@@ -838,9 +870,7 @@ def equivalence_report(src: Netlist, re_elab: ReElaboration,
             diff = sum(int(diff_words[r, w]) << (32 * w)
                        for w in range(n_words))
             mismatched.append(mismatch_entry(int(idx_src[r]), diff))
-        po_ok = not any(
-            diff_words[row_of[s]].any()
-            for bus in src.pos.values() for s in bus)
+        po_ok = not any(diff_words[row_of[s]].any() for s in outputs)
     else:
         src_val = eval_netlist(src, pi_vals, n_vectors)
         phys_val = eval_netlist(phys, phys_pi_vals, n_vectors)
@@ -853,9 +883,9 @@ def equivalence_report(src: Netlist, re_elab: ReElaboration,
                     mismatch_entry(s, src_val[s] ^ phys_val[ps]))
                 if len(mismatched) >= 4:
                     break
-        po_ok = all(
-            src_val[s] == phys_val[sig_map[s]]
-            for bus in src.pos.values() for s in bus)
+        po_ok = all(src_val[s] == phys_val[sig_map[s]] for s in outputs)
+    if src.regs:
+        po_ok = po_ok and _po_ok(src, re_elab)
     return {
         "name": src.name,
         "equivalent": po_ok and not mismatched,

@@ -41,6 +41,10 @@ the shared registry (:mod:`repro.core.plan` — ``eval_plans`` /
 ``eval_groups``), alongside the functional IRs; one
 :func:`repro.core.plan.clear_caches` invalidates everything.
 
+Registered netlists are simulated over clock cycles by :func:`_run_seq`:
+one ``lax.scan`` over cycles whose step is the same fused multi-scan,
+with the registers' rows as the carry (:func:`run_sequential`).
+
 The value buffer is built on the device (:func:`_device_vals`): only the
 primary inputs' lanes cross the host link, and the buffer is donated to
 the evaluation jit (``donate_argnums``), which reuses it in place.  The
@@ -428,9 +432,10 @@ def _device_vals(pi_idx, pi_rows, *, n_rows):
 
 
 def _pi_slots(net: Netlist) -> dict[int, int]:
-    """Each primary input's slot in the packed PI rows: its place in
-    ``net.pis``."""
-    return {s: j for j, s in enumerate(net.pis)}
+    """Each source's slot in the packed PI rows: its place in
+    ``net.sources`` (the primary inputs, then any register's Q, which a
+    combinational evaluation reads as a free input)."""
+    return {s: j for j, s in enumerate(net.sources)}
 
 
 def _fill_pi_rows(slots: list[dict[int, int]],
@@ -470,10 +475,10 @@ def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
     """
     if plan is None:
         plan = plan_netlist(net)
-    rows = _put(_fill_pi_rows([_pi_slots(net)], [pi_lanes], len(net.pis),
-                              n_lane_words)[0])
+    rows = _put(_fill_pi_rows([_pi_slots(net)], [pi_lanes],
+                              len(net.sources), n_lane_words)[0])
     with span("repro.eval.run"):
-        vals = _device_vals(np.asarray(net.pis, dtype=np.int32), rows,
+        vals = _device_vals(np.asarray(net.sources, dtype=np.int32), rows,
                             n_rows=plan.n_signals + 1)
         out = _run_fused(vals, plan.device_arrays(), flags=plan.flags,
                          use_pallas=use_pallas).block_until_ready()
@@ -560,10 +565,10 @@ def _build_group(nets: list[Netlist], max_buckets: int) -> GroupProgram:
                                     for p in member_plans]))
               for ai in range(10))
         for bi in range(len(bounds)))
-    n_pis = max(len(net.pis) for net in nets)
+    n_pis = max(len(net.sources) for net in nets)
     pi_index = np.full((len(nets), n_pis), n_sig + 1, dtype=np.int32)
     for row, net in enumerate(nets):
-        pi_index[row, :len(net.pis)] = net.pis
+        pi_index[row, :len(net.sources)] = net.sources
     return GroupProgram(n_sig, stacked, flags, member_plans,
                         jnp.asarray(pi_index),
                         [_pi_slots(net) for net in nets])
@@ -682,6 +687,92 @@ def eval_netlists_batched_jax(nets: list[Netlist],
     if return_stats:
         return outs, program.stats
     return outs
+
+
+# ---------------------------------------------------------------------------
+# cycle simulation of registered netlists
+# ---------------------------------------------------------------------------
+
+_SEQ_CACHE = _planner.register_cache("seq_programs", cap=16)
+
+
+class SeqProgram(NamedTuple):
+    """One registered netlist's cycle program: its fused plan and the
+    value-buffer rows of its sources and sinks, on the device."""
+
+    plan: FusedPlan
+    pi_idx: jax.Array             # [P] int32 PI rows, ``net.pis`` order
+    q_idx: jax.Array              # [R] int32 register Q rows
+    d_idx: jax.Array              # [R] int32 register D rows
+    po_idx: jax.Array             # [O] int32 PO rows, ``net.pos`` order
+
+
+def seq_program(net: Netlist) -> SeqProgram:
+    """The cycle program of ``net`` (content-cached, on the same plan as
+    the combinational evaluator)."""
+    key = netlist_digest(net)
+    hit = _SEQ_CACHE.get(key)
+    if hit is not None:
+        return hit
+    net.check_regs_connected()
+
+    def rows(sigs):
+        return jnp.asarray(np.asarray(sigs, dtype=np.int32).reshape(-1))
+
+    prog = SeqProgram(
+        plan=plan_netlist(net), pi_idx=rows(net.pis),
+        q_idx=rows([q for _, q in net.regs]),
+        d_idx=rows([d for d, _ in net.regs]),
+        po_idx=rows([s for bus in net.pos.values() for s in bus]))
+    _SEQ_CACHE.put(key, prog)
+    return prog
+
+
+@functools.partial(jax.jit, static_argnames=("flags", "use_pallas",
+                                             "n_rows"))
+def _run_seq(pi_stream, pi_idx, q_idx, d_idx, po_idx, bucket_arrays, *,
+             flags, use_pallas, n_rows):
+    """``lax.scan`` over cycles, the register rows the carry.  A cycle
+    builds the value buffer from its PI rows, the registers' Q and
+    ``CONST1`` (:func:`_device_vals`), runs the fused bucket scans, and
+    gathers the next Q (the D rows) and the PO rows.  Every register
+    starts at 0.  ``pi_stream`` is ``[T, P, N]``; returns ``[T, O, N]``."""
+    src_idx = jnp.concatenate([pi_idx, q_idx])
+
+    def cycle(state, pi_rows):
+        vals = _device_vals(src_idx, jnp.concatenate([pi_rows, state]),
+                            n_rows=n_rows)
+        vals = _multi_scan(vals, bucket_arrays, flags, use_pallas)
+        return vals[d_idx], vals[po_idx]
+
+    state = jnp.zeros((d_idx.shape[0], pi_stream.shape[-1]),
+                      dtype=jnp.uint32)
+    _, pos = jax.lax.scan(cycle, state, pi_stream)
+    return pos
+
+
+def run_sequential(prog: SeqProgram, pi_stream: np.ndarray,
+                   use_pallas: bool = True) -> np.ndarray:
+    """Simulate ``pi_stream`` (``[T, P, N]`` uint32 lanes of the primary
+    inputs per cycle, ``net.pis`` order) and return the primary outputs'
+    lanes per cycle, ``[T, O, N]`` uint32 in ``net.pos`` order.  The
+    stream crosses to the device once and the outputs come back once: no
+    state crosses between cycles."""
+    n_pis = int(prog.pi_idx.shape[0])
+    if pi_stream.ndim != 3 or pi_stream.shape[1] != n_pis:
+        raise ValueError(f"PI stream of shape {pi_stream.shape}, the "
+                         f"netlist has {n_pis} primary inputs")
+    with span("repro.seq.put", bytes=pi_stream.nbytes):
+        dev = jnp.asarray(pi_stream, dtype=jnp.uint32).block_until_ready()
+    with span("repro.seq.run"):
+        out = _run_seq(dev, prog.pi_idx, prog.q_idx, prog.d_idx,
+                       prog.po_idx, prog.plan.device_arrays(),
+                       flags=prog.plan.flags, use_pallas=use_pallas,
+                       n_rows=prog.plan.n_signals + 1).block_until_ready()
+    with span("repro.seq.get") as sp:
+        out = np.asarray(out)
+        sp.set(bytes=out.nbytes)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,10 @@ The stages:
   compatible-envelope groups, so Kratos + Koios + VTR evaluate per arch
   as a handful of vmapped jit programs; plans and grouped tensors are
   content-cached, so repeated figures reuse compiles.
+* **cycle simulation** — :func:`evaluate_sequential` steps registered
+  netlists over clock cycles on the device: one jitted scan over cycles
+  per netlist, the fused bucket program its step, the registers its
+  carry (:func:`repro.core.eval_jax.run_sequential`).
 * :func:`oracle_check` closes the loop: any JAX-side result can be
   proven bit-identical to the pure-Python ``eval_netlist`` oracle.
 
@@ -48,7 +52,7 @@ from .equiv import check_pack_equivalence
 from .eval_jax import (DEFAULT_MAX_BUCKETS, DEFAULT_MAX_GROUPS, FusedPlan,
                        SuiteProgram, eval_netlist_jax,
                        eval_netlists_batched_jax, plan_netlist,
-                       prepare_suite_program)
+                       prepare_suite_program, run_sequential, seq_program)
 from .netlist import Netlist, eval_netlist
 from .packing import PackedCircuit, pack
 from .spans import span
@@ -489,6 +493,29 @@ def _plan_suite(nets, program, mode, max_groups, max_buckets, warm,
     if chosen == "grouped" and groups is None:
         groups = group_plans_by_envelope(plans, max_groups=max_groups)
     return plans, groups, model, chosen
+
+
+def evaluate_sequential(nets: list[Netlist], pi_streams: list[np.ndarray],
+                        use_pallas: bool = True
+                        ) -> tuple[list[np.ndarray], dict]:
+    """Cycle-by-cycle simulation of registered netlists on the device.
+
+    ``pi_streams[i]`` is ``[T, len(nets[i].pis), N]`` uint32: each primary
+    input's packed lanes (32 independent vectors a word) in each of ``T``
+    cycles, in ``net.pis`` order.  Every register starts at 0.  Returns
+    ``(outs, stats)``: ``outs[i]`` is ``[T, n_pos, N]`` uint32, the
+    primary outputs' lanes in each cycle (in ``net.pos`` bus order, before
+    the clock edge that ends the cycle), equal to
+    :func:`repro.core.netlist.eval_sequential`; ``stats`` counts the step
+    programs' real and padded LUT rows."""
+    programs = [seq_program(n) for n in nets]
+    outs = [run_sequential(prog, np.asarray(stream, dtype=np.uint32),
+                           use_pallas=use_pallas)
+            for prog, stream in zip(programs, pi_streams)]
+    stats = {"real_lut_rows": sum(p.plan.real_luts for p in programs),
+             "padded_lut_rows": sum(p.plan.padded_lut_rows
+                                    for p in programs)}
+    return outs, stats
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,11 @@ The IR models exactly the primitives that matter for the paper's experiments:
 * **carry chains** — runs of 1-bit full adders with a ripple carry, the
   hard-adder resource of a Stratix-like ALM (2 FA bits per ALM).
 * **primary inputs / outputs** — grouped into named buses.
+* **registers** — D flip-flops on one clock with reset value 0, grouped
+  into named buses of their Q outputs.  A Q is a source (like a primary
+  input) and a D a sink (like a primary output) of the combinational
+  logic between clock edges; enables and muxes are LUT logic in front of
+  D.  :func:`eval_sequential` steps a registered netlist over cycles.
 
 Signals are dense integer ids.  Signal 0 is constant-0 and signal 1 is
 constant-1.  Structural hashing deduplicates identical LUTs and identical
@@ -224,10 +229,15 @@ class Netlist:
         self.lut_tt: list[int] = []
         self.lut_out: list[int] = []
         self.chains: list[Chain] = []
+        # registers: (d, q) per register, d = -1 until connect_reg; the
+        # named buses hold Q signals
+        self.regs: list[tuple[int, int]] = []
+        self.reg_buses: dict[str, list[int]] = {}
         # structural hashing
         self._lut_cache: dict[tuple, int] = {}
         self._chain_cache: dict[tuple, int] = {}
         # signal -> driver: ("pi",idx) ("lut",idx) ("chain",ci,bi) ("cout",ci)
+        # ("reg",idx)
         self.driver: dict[int, tuple] = {}
 
     # -- construction -------------------------------------------------------
@@ -248,6 +258,50 @@ class Netlist:
 
     def set_po_bus(self, name: str, bus: Sequence[int]) -> None:
         self.pos[name] = list(bus)
+
+    def add_reg_bus(self, name: str, width: int) -> list[int]:
+        """``width`` registers named ``name``; returns their Q signals.
+        Each D is connected later with :meth:`connect_reg`, so feedback
+        can be closed after the logic in front of D is built."""
+        bus = []
+        for _ in range(width):
+            q = self.new_sig()
+            self.driver[q] = ("reg", len(self.regs))
+            self.regs.append((-1, q))
+            bus.append(q)
+        self.reg_buses[name] = bus
+        return bus
+
+    def connect_reg(self, q: int, d: int) -> None:
+        """Drive the register whose output is ``q`` from signal ``d``."""
+        drv = self.driver.get(q)
+        if drv is None or drv[0] != "reg":
+            raise ValueError(f"signal {q} is not a register output")
+        if self.regs[drv[1]][0] != -1:
+            raise ValueError(f"register {q} is already connected")
+        self.regs[drv[1]] = (d, q)
+
+    def check_regs_connected(self) -> None:
+        """Raise ``ValueError`` if a register's D was never connected."""
+        if any(d < 0 for d, _ in self.regs):
+            raise ValueError(f"{self.name}: a register has no D connected")
+
+    @property
+    def sources(self) -> list[int]:
+        """Signals the combinational logic reads as free inputs: the
+        primary inputs, then every register's Q."""
+        if not self.regs:
+            return self.pis
+        return self.pis + [q for _, q in self.regs]
+
+    def _reg_fields(self) -> tuple:
+        """The registers' part of a digest: empty without registers, so
+        a combinational netlist digests as it always did."""
+        if not self.regs:
+            return ()
+        return (tuple(self.regs),
+                tuple(sorted((k, tuple(v))
+                             for k, v in self.reg_buses.items())))
 
     def add_lut(self, inputs: Sequence[int], tt: int) -> int:
         inputs, tt = tt_reduce(inputs, tt)
@@ -323,7 +377,7 @@ class Netlist:
                               c.cin, c.cout) for c in self.chains),
                        tuple(sorted((k, tuple(v))
                                     for k, v in self.pos.items()))
-                       )).encode())
+                       ) + self._reg_fields()).encode())
         return h.hexdigest()
 
     def pack_digest(self) -> str:
@@ -349,7 +403,7 @@ class Netlist:
                               c.cin, c.cout) for c in self.chains),
                        tuple(sorted((k, tuple(v))
                                     for k, v in self.pos.items()))
-                       )).encode())
+                       ) + self._reg_fields()).encode())
         return h.hexdigest()
 
     def lower_ir(self):
@@ -450,10 +504,12 @@ class Netlist:
         return order
 
     def sweep(self) -> "Netlist":
-        """Dead-code elimination: keep only logic reachable from the POs."""
+        """Dead-code elimination: keep only logic reachable from the POs
+        and the registers' D inputs."""
         live: set[int] = set()
         for bus in self.pos.values():
             live.update(bus)
+        live.update(d for d, _ in self.regs)
         produced_by: dict[int, tuple] = {}
         for nd in self.node_list():
             for s in self.node_outputs(nd):
@@ -477,6 +533,7 @@ class Netlist:
         out.pi_buses = dict(self.pi_buses)
         for s in self.pis:
             out.driver[s] = self.driver[s]
+        copy_regs(self, out)
         for i in range(self.n_luts):
             if ("lut", i) in live_nodes:
                 idx = len(out.lut_out)
@@ -496,6 +553,15 @@ class Netlist:
                     out.driver[ch.cout] = ("cout", ci)
         out.pos = {k: list(v) for k, v in self.pos.items()}
         return out
+
+
+def copy_regs(src: Netlist, out: Netlist) -> None:
+    """Give ``out`` (a rebuild of ``src`` over the same signal ids) the
+    registers of ``src``."""
+    out.regs = list(src.regs)
+    out.reg_buses = {k: list(v) for k, v in src.reg_buses.items()}
+    for i, (_, q) in enumerate(src.regs):
+        out.driver[q] = ("reg", i)
 
 
 # ---------------------------------------------------------------------------
@@ -540,6 +606,29 @@ def eval_netlist(net: Netlist, pi_values: dict[int, int], n_vectors: int = 1):
             if ch.cout is not None:
                 val[ch.cout] = c
     return val
+
+
+def eval_sequential(net: Netlist, pi_streams: dict[int, Sequence[int]],
+                    n_cycles: int, n_vectors: int = 1) -> list[dict[int, int]]:
+    """Step a registered netlist over ``n_cycles`` clock cycles with
+    :func:`eval_netlist`, bit-parallel over ``n_vectors`` vectors.
+
+    ``pi_streams[signal][t]`` is a primary input's value (an int, bit
+    ``v`` for vector ``v``) in cycle ``t``; a missing input reads 0.
+    Every register starts at 0.  Returns, per cycle, every primary
+    output's value in that cycle (before the clock edge that ends it).
+    """
+    net.check_regs_connected()
+    state = {q: 0 for _, q in net.regs}
+    po_sigs = [s for bus in net.pos.values() for s in bus]
+    out = []
+    for t in range(n_cycles):
+        ins = {s: v[t] for s, v in pi_streams.items()}
+        ins.update(state)
+        val = eval_netlist(net, ins, n_vectors)
+        out.append({s: val.get(s, 0) for s in po_sigs})
+        state = {q: val.get(d, 0) for d, q in net.regs}
+    return out
 
 
 def bus_to_ints(val: dict[int, int], bus: Sequence[int], n_vectors: int) -> list[int]:

@@ -19,6 +19,9 @@ timing-driven packer; we model its resource behaviour, not its annealing):
    pins, debiting the LB's AddMux-crossbar budget (``z_sources`` distinct
    LB-external signals; in-LB producers ride the direct-link taps for free
    when ``z_local_free``).
+6. **Registers** take flip-flop slots last (``alm.FF_PER_ALM`` per ALM,
+   :func:`_pack_registers`): in the ALM that produces their D, else in
+   their LB, else in a new ALM.
 
 The baseline architecture rejects step 5 structurally — that is the paper's
 entire premise.
@@ -48,7 +51,7 @@ from itertools import islice
 
 import numpy as np
 
-from .alm import ArchParams
+from .alm import FF_PER_ALM, ArchParams
 from .netlist import CONST0, CONST1, Netlist
 from .spans import span
 
@@ -148,6 +151,9 @@ class PackedCircuit:
     chain_site: dict[tuple[int, int], int]  # (chain, bit) -> alm idx
     alm_lb: list[int]              # alm idx -> lb idx
     concurrent_luts: int           # unrelated LUTs co-packed with active FAs
+    #: register idx -> alm idx of its flip-flop slot (empty without
+    #: registers)
+    reg_site: dict[int, int] = field(default_factory=dict)
 
     _ir: object | None = field(default=None, repr=False, compare=False)
 
@@ -189,10 +195,21 @@ class PackedCircuit:
     def total_area(self) -> float:
         return self.n_alms * self.arch.alm_area_mwta
 
+    def lb_regs(self, lb_idx: int) -> list[int]:
+        """Registers whose flip-flops lie in LB ``lb_idx``."""
+        by_lb = self.__dict__.get("_by_lb")
+        if by_lb is None:
+            by_lb = {}
+            for ri, ai in self.reg_site.items():
+                by_lb.setdefault(self.alm_lb[ai], []).append(ri)
+            self.__dict__["_by_lb"] = by_lb
+        return by_lb.get(lb_idx, [])
+
     def produced_in_lb(self, lb_idx: int) -> set[int]:
         out: set[int] = set()
         for ai in self.lbs[lb_idx].alms:
             out.update(self.alms[ai].output_signals(self.net))
+        out.update(self.net.regs[ri][1] for ri in self.lb_regs(lb_idx))
         return out
 
     def lb_external_ins(self, lb_idx: int) -> set[int]:
@@ -202,6 +219,8 @@ class PackedCircuit:
             ah, z = self.alms[ai].input_signals(self.net)
             need.update(ah)
             need.update(z)
+        need.update(d for ri in self.lb_regs(lb_idx)
+                    if (d := self.net.regs[ri][0]) > CONST1)
         return need - produced
 
     def stats(self) -> dict:
@@ -251,6 +270,8 @@ def _fanout_counts(net: Netlist) -> dict[int, int]:
     for bus in net.pos.values():
         for s in bus:
             fanout[s] += 1
+    for d, _ in net.regs:
+        fanout[d] += 1
     return fanout
 
 
@@ -691,8 +712,105 @@ def _cluster(net, arch, alms, chain_alm_runs, plan: ClusterPlan,
             net, arch, alms, chain_alm_runs, plan, chain_site, lut_site,
             allow_unrelated=allow_unrelated, strict_phases=strict_phases,
             pull_runs=pull_runs, replay=replay)
+        if net.regs:
+            _pack_registers(packed)
         sp.set(lbs=len(packed.lbs), **counts)
     return packed
+
+
+def _pack_registers(packed: PackedCircuit) -> None:
+    """Give every register a flip-flop slot, :data:`FF_PER_ALM` per ALM,
+    after the logic is clustered.
+
+    A register goes to the ALM whose LUT or adder produces its D, if that
+    ALM has a free slot.  Otherwise it goes to a free slot in the same LB
+    (for a D that no logic produces, a primary input or another
+    register's Q: the LB of the register's first consumer), then to a new
+    ALM in that LB while it has room, then to a new ALM in an LB of
+    registers.  A D produced outside the LB costs one of its inputs: an
+    LB whose input budget is spent takes no such register."""
+    net, arch = packed.net, packed.arch
+    alms, lbs, alm_lb = packed.alms, packed.lbs, packed.alm_lb
+    prod: dict[int, int] = {}
+    for li, ai in packed.lut_site.items():
+        prod[net.lut_out[li]] = ai
+    for (ci, bi), ai in packed.chain_site.items():
+        ch = net.chains[ci]
+        prod[ch.sums[bi]] = ai
+        if ch.cout is not None and bi == len(ch.sums) - 1:
+            prod[ch.cout] = ai
+    q_reg = {q: ri for ri, (_, q) in enumerate(net.regs)}
+    user: dict[int, int] = {}
+    for li, ins in enumerate(net.lut_inputs):
+        for s in ins:
+            if s in q_reg and s not in user:
+                user[s] = packed.lut_site[li]
+    for (ci, bi), ai in sorted(packed.chain_site.items()):
+        ch = net.chains[ci]
+        for s in (ch.a[bi], ch.b[bi], ch.cin if bi == 0 else CONST0):
+            if s in q_reg and s not in user:
+                user[s] = ai
+    used = [0] * len(alms)
+    ext: dict[int, set[int]] = {}
+    site = packed.reg_site
+
+    def lb_of_signal(s: int) -> int | None:
+        ai = prod.get(s)
+        if ai is None and s in q_reg:
+            ai = site.get(q_reg[s])
+        return None if ai is None else alm_lb[ai]
+
+    def lb_ext(lb: int) -> set[int]:
+        """The LB's external inputs: its logic's, then each D placed."""
+        if lb not in ext:
+            need: set[int] = set()
+            made: set[int] = set()
+            for ai in lbs[lb].alms:
+                ah, z = alms[ai].input_signals(net)
+                need |= ah | z
+                made |= alms[ai].output_signals(net)
+            ext[lb] = need - made
+        return ext[lb]
+
+    def fits(lb: int, d: int) -> bool:
+        if d <= CONST1 or lb_of_signal(d) == lb:
+            return True
+        e = lb_ext(lb)
+        return d in e or len(e) < arch.input_budget
+
+    def free_slot(lb: int) -> int | None:
+        for ai in lbs[lb].alms:
+            if used[ai] < FF_PER_ALM:
+                return ai
+        if len(lbs[lb].alms) < arch.alms_per_lb:
+            alms.append(ALM(halves=(Half(), Half())))
+            alm_lb.append(lb)
+            lbs[lb].alms.append(len(alms) - 1)
+            used.append(0)
+            return len(alms) - 1
+        return None
+
+    overflow = None
+    for ri, (d, q) in enumerate(net.regs):
+        home = prod.get(d)
+        ai = home if home is not None and used[home] < FF_PER_ALM else None
+        if ai is None:
+            lb = (alm_lb[home] if home is not None
+                  else alm_lb[user[q]] if q in user else None)
+            if lb is not None and fits(lb, d):
+                ai = free_slot(lb)
+        if ai is None:
+            if overflow is not None and fits(overflow, d):
+                ai = free_slot(overflow)
+            if ai is None:
+                overflow = len(lbs)
+                lbs.append(LB())
+                ai = free_slot(overflow)
+        lb = alm_lb[ai]
+        if d > CONST1 and lb_of_signal(d) != lb:
+            lb_ext(lb).add(d)
+        site[ri] = ai
+        used[ai] += 1
 
 
 def _cluster_greedy(net, arch, alms, chain_alm_runs, plan: ClusterPlan,

@@ -629,7 +629,8 @@ def netlist_structural_diff(base: Netlist, new: Netlist) -> dict | None:
     truth-table-only edits (pack-irrelevant — zero dirty atoms)."""
     if (base.n_signals != new.n_signals or base.n_luts != new.n_luts
             or len(base.chains) != len(new.chains)
-            or base.pis != new.pis or base.pos != new.pos):
+            or base.pis != new.pis or base.pos != new.pos
+            or base.regs != new.regs):
         return None
     for c0, c1 in zip(base.chains, new.chains):
         if (list(c0.a) != list(c1.a) or list(c0.b) != list(c1.b)

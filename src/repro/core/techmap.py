@@ -16,7 +16,8 @@ from __future__ import annotations
 
 from collections import defaultdict
 
-from .netlist import CONST1, MAX_LUT_K, Netlist, tt_compose, tt_reduce
+from .netlist import (CONST1, MAX_LUT_K, Netlist, copy_regs, tt_compose,
+                      tt_reduce)
 
 
 def techmap(net: Netlist, max_k: int = MAX_LUT_K) -> Netlist:
@@ -33,6 +34,8 @@ def techmap(net: Netlist, max_k: int = MAX_LUT_K) -> Netlist:
     for bus in net.pos.values():
         for s in bus:
             fanout[s] += 1
+    for d, _ in net.regs:      # a register's D is a sink, like a PO
+        fanout[d] += 1
 
     drv_lut: dict[int, int] = {net.lut_out[i]: i for i in range(net.n_luts)}
 
@@ -83,6 +86,7 @@ def techmap(net: Netlist, max_k: int = MAX_LUT_K) -> Netlist:
         chain_or_po_sigs.add(ch.cin)
     for bus in net.pos.values():
         chain_or_po_sigs.update(bus)
+    chain_or_po_sigs.update(d for d, _ in net.regs)
 
     for _round in range(4):
         # consumer index over live defs
@@ -138,6 +142,7 @@ def techmap(net: Netlist, max_k: int = MAX_LUT_K) -> Netlist:
     out.pi_buses = dict(net.pi_buses)
     for s in net.pis:
         out.driver[s] = net.driver[s]
+    copy_regs(net, out)
     for vi in order:
         if vi in dead:
             continue

@@ -24,6 +24,10 @@ Two implementations share this recurrence:
   architectures for design-space sweeps).  It is bit-identical to the
   oracle, which tests assert for both the unplaced and the placed paths.
 
+A path starts at a primary input (time 0) or at a register's Q
+(:data:`T_CLK_Q_CPS`) and ends at a primary output or at a register's D
+(its arrival plus :data:`T_SETUP_CPS`).
+
 Both compute in integer centi-picoseconds (:meth:`~repro.core.alm.
 ArchParams.delay_table`): every delay of the model is a multiple of
 0.01 ps, so sums are exact in any order and on any device, and the
@@ -39,7 +43,8 @@ from __future__ import annotations
 
 import time
 
-from .alm import CPS_PER_PS, DELAY_FIELDS, ArchParams
+from .alm import (CPS_PER_PS, DELAY_FIELDS, T_CLK_Q_CPS, T_SETUP_CPS,
+                  ArchParams)
 from .netlist import CONST0, CONST1, Netlist
 from .packing import PackedCircuit
 
@@ -192,6 +197,8 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
             site[s] = packed.chain_site.get((ci, bi), -2)
         if ch.cout is not None:
             site[ch.cout] = packed.chain_site.get((ci, len(ch.sums) - 1), -2)
+    for ri, (_, q) in enumerate(net.regs):
+        site[q] = packed.reg_site.get(ri, -2)
 
     def lb_of(ai: int) -> int:
         if ai < 0:
@@ -201,6 +208,8 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
     arr: dict[int, int] = {CONST0: 0, CONST1: 0}
     for s in net.pis:
         arr[s] = 0
+    for _, q in net.regs:
+        arr[q] = T_CLK_Q_CPS
 
     def edge_in(s: int, dst_lb: int, pin: str) -> int:
         """Arrival of signal s at an ALM input pin in LB dst_lb."""
@@ -292,6 +301,8 @@ def analyze_oracle(packed: PackedCircuit, placement=None) -> dict:
     for bus in net.pos.values():
         for s in bus:
             cp = max(cp, arr.get(s, 0))
+    for d, _ in net.regs:
+        cp = max(cp, arr.get(d, 0) + T_SETUP_CPS)
     return metrics_from_cp(arch, cp, packed.n_alms, packed.n_lbs,
                            packed.total_area, net.n_adders, net.n_luts,
                            packed.concurrent_luts)

@@ -50,7 +50,7 @@ from typing import Sequence
 import numpy as np
 
 from . import timing as _timing
-from .alm import ArchParams, DELAY_FIELDS
+from .alm import T_CLK_Q_CPS, T_SETUP_CPS, ArchParams, DELAY_FIELDS
 from .circuit_ir import (N_EDGE_CLASSES, N_NODE_CLASSES, NDC_ABSORBED,
                          NDC_LUT4, NDC_LUT5, NDC_LUT6, CircuitIR)
 from .plan import bucket_envelopes, combined_profile, segment_levels
@@ -146,6 +146,7 @@ def arrival_times_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]
                                int(comps["chain"][1]),
                                int(comps["chain"][2]))
     arr = np.zeros(ir.n_signals, dtype=np.int64)
+    arr[ir.reg_q] = T_CLK_Q_CPS
     for ll, cl in zip(ir.lut_levels, ir.chain_levels):
         if ll.out.shape[0]:
             ec = edge[ll.cls]                          # [M, 6, 3]
@@ -181,9 +182,13 @@ def arrival_times_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]
 
 
 def critical_path_numpy(ir: CircuitIR, comps: dict[str, np.ndarray]) -> int:
-    """Latest primary-output arrival, centi-ps (0 without outputs)."""
+    """Latest primary-output arrival or register-D arrival plus setup,
+    centi-ps (0 without either)."""
     arr = arrival_times_numpy(ir, comps)
-    return int(arr[ir.po_sig].max()) if ir.po_sig.size else 0
+    cp = int(arr[ir.po_sig].max()) if ir.po_sig.size else 0
+    if ir.reg_d.size:
+        cp = max(cp, int(arr[ir.reg_d].max()) + T_SETUP_CPS)
+    return cp
 
 
 def metrics_from_cp(ir: CircuitIR, arch: ArchParams, cp_cps: int) -> dict:
@@ -308,6 +313,9 @@ class SuiteTimingProgram:
     #: full compiled-shape signature; programs with equal signatures
     #: share one jit executable through the module ``_JIT_CACHE``
     shape_key: tuple | None = None
+    #: ``(reg_q, reg_d, d_offset)`` stacked ``[G, R]`` where a member has
+    #: registers, else empty: the program then is the combinational one
+    _regs: tuple = field(default=(), repr=False)
 
     def _build_jit(self):
         import functools
@@ -355,16 +363,24 @@ class SuiteTimingProgram:
                 arr = arr.at[cout].set((cy_last + t_sum) + t_extra)
             return arr, None
 
-        def one(member_xs, po, edge, wire, lutc, chainc):
+        def one(member_xs, po, edge, wire, lutc, chainc, *regs):
             arr = jnp.zeros(n_sig + 1, dtype=jnp.int32)
+            if regs:
+                arr = arr.at[regs[0]].set(T_CLK_Q_CPS)
             for (hl, hc), xs in zip(flags, member_xs):
                 bk = functools.partial(body, hl=hl, hc=hc, edge=edge,
                                        wire=wire, lutc=lutc, chainc=chainc)
                 arr, _ = jax.lax.scan(bk, arr, xs)
-            return jnp.max(arr[po])
+            cp = jnp.max(arr[po])
+            if regs:
+                cp = jnp.maximum(cp, jnp.max(arr[regs[1]] + regs[2]))
+            return cp
 
-        inner = jax.vmap(one, in_axes=(None, None, 0, 0, 0, 0))  # arch axis
-        outer = jax.vmap(inner, in_axes=(0, 0, None, None, None, None))
+        n_regs = len(self._regs)
+        inner = jax.vmap(one, in_axes=(None, None, 0, 0, 0, 0)
+                         + (None,) * n_regs)                    # arch axis
+        outer = jax.vmap(inner, in_axes=(0, 0, None, None, None, None)
+                         + (0,) * n_regs)
         return jax.jit(outer)
 
     def run(self, delay_tables: np.ndarray) -> np.ndarray:
@@ -380,6 +396,8 @@ class SuiteTimingProgram:
         step = (comps["edge"].sum(-1).max() + comps["wire"].max()
                 + max(comps["lut"].sum(-1).max(),
                       (bits * chain[:, 2] + chain[:, 0] + chain[:, 1]).max()))
+        if self._regs:
+            step += T_CLK_Q_CPS + T_SETUP_CPS
         if levels * int(step) >= 2 ** 31:
             raise OverflowError(
                 f"timing program: {levels} levels x {int(step)} cps per "
@@ -397,7 +415,8 @@ class SuiteTimingProgram:
             self._jit = jit
         cps = self._jit(self._tensors, self._po,
                         *(comps[k].astype(np.int32)
-                          for k in ("edge", "wire", "lut", "chain")))
+                          for k in ("edge", "wire", "lut", "chain")),
+                        *self._regs)
         # rows past n_members are pad members, sliced away
         return np.asarray(cps, dtype=np.int64)[:self.n_members]
 
@@ -460,8 +479,33 @@ def build_suite_timing_program(irs: Sequence[CircuitIR],
          any(mb[bi][11].min() < sink or (mb[bi][10] < sink).any()
              for mb in members[:G]))                         # any real chain
         for bi in range(len(bounds)))
+    regs = _stack_regs(irs, G_alloc, sink, pad_shapes)
     _COMPILE_COUNTS["programs"] += 1
     return SuiteTimingProgram(
         n_sig=n_sig, n_members=G, flags=flags, bucket_shapes=tuple(shapes),
         _tensors=tensors, _po=jnp.asarray(po),
-        shape_key=(n_sig, G_alloc, P, flags, tuple(shapes)))
+        shape_key=(n_sig, G_alloc, P, flags, tuple(shapes))
+        + ((regs[0].shape,) if regs else ()),
+        _regs=regs)
+
+
+def _stack_regs(irs, G_alloc: int, sink: int, pad_shapes: bool) -> tuple:
+    """``(reg_q, reg_d, d_offset)`` of the members, ``[G_alloc, R]``
+    int32, or ``()`` when no member has registers.  A pad slot's Q writes
+    the sink and its D reads CONST0 with an offset no path can beat."""
+    import jax.numpy as jnp
+
+    R = max(ir.reg_q.size for ir in irs)
+    if not R:
+        return ()
+    if pad_shapes:
+        R = _pad_dim(R, floor=1)
+    q = np.full((G_alloc, R), sink, dtype=np.int32)
+    d = np.zeros((G_alloc, R), dtype=np.int32)
+    off = np.full((G_alloc, R), -2 ** 30, dtype=np.int32)
+    for g, ir in enumerate(irs):
+        n = ir.reg_q.size
+        q[g, :n] = ir.reg_q
+        d[g, :n] = ir.reg_d
+        off[g, :n] = T_SETUP_CPS
+    return tuple(jnp.asarray(a) for a in (q, d, off))

@@ -10,6 +10,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import json
 import os
 
 import numpy as np
@@ -197,3 +198,40 @@ def test_anneal_ensemble_compiles_for_v5e(one_chip, widest_pack):
     ints = (nbr_pad, w_pad, src, dst, wq, x0, y0, occ0) + streams
     args = _shapes(tuple(np.asarray(a, np.int32) for a in ints), one_chip)
     _anneal_ensemble.lower(*args, W=W, H=H).compile()
+
+
+@pytest.fixture(scope="module")
+def stream_cell():
+    """The stream cell's systolic tile, as its configuration makes it,
+    with its cycle program, and the traffic's cycles and lane words."""
+    from repro.core import circuits
+    from repro.core.eval_jax import seq_program
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "bench", "configs",
+                           "tpu-sa32-s10.json")) as f:
+        suite = json.load(f)["suites"][0]
+    with open(os.path.join(root, "bench", "traffic", "stream.json")) as f:
+        traffic = json.load(f)
+    net = getattr(circuits, suite["generator"])(**suite["kwargs"])
+    return net, seq_program(net), traffic
+
+
+def test_cycle_program_compiles_for_v5e(one_chip, stream_cell, monkeypatch):
+    """The scan over cycles at the stream cell's envelope (the 32x32
+    tile, its cycles and lane words), the Mosaic kernel inside."""
+    from repro.core.eval_jax import _run_seq
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    net, prog, traffic = stream_cell
+    stream = jax.ShapeDtypeStruct(
+        (traffic["cycles"], len(net.pis), traffic["lane_words"]),
+        jnp.uint32, sharding=one_chip)
+    compiled = _run_seq.lower(
+        stream, *_shapes((prog.pi_idx, prog.q_idx, prog.d_idx,
+                          prog.po_idx), one_chip),
+        _shapes(prog.plan.arrays(), one_chip), flags=prog.plan.flags,
+        use_pallas=True, n_rows=prog.plan.n_signals + 1).compile()
+    assert "tpu_custom_call" in compiled.as_text()
