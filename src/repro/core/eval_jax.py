@@ -17,13 +17,17 @@ fused engine is preserved while the padding waste drops to the per-bucket
 optimum:
 
 * a scan step gathers the level's LUT input lanes from the signal-value
-  buffer, runs one fused ``lut_eval6`` kernel call, and scatters the
-  outputs;
+  buffer, runs one fused ``lut_eval6`` kernel call, and writes the
+  outputs back: by scatter to each signal's row in the combinational
+  programs, whose buffer is returned by signal id, and by one slice into
+  the level's own block in the cycle program, whose row numbering is
+  private (:class:`SlotPlan`);
 * the level's carry chains ripple inside the same scan step (a nested
   bit-scan over the stacked ``[C_b, B_b]`` layout — one scan for *all*
   chains of the level);
-* padded rows read constant-0 lanes and write a reserved sink row, so each
-  scan body is shape-uniform with zero per-level Python dispatch.
+* padded rows read constant-0 lanes and write a reserved sink row (or
+  their own rows of the level's block), so each scan body is
+  shape-uniform with zero per-level Python dispatch.
 
 Suite-scale batched evaluation
 ------------------------------
@@ -42,8 +46,9 @@ the shared registry (:mod:`repro.core.plan` — ``eval_plans`` /
 :func:`repro.core.plan.clear_caches` invalidates everything.
 
 Registered netlists are simulated over clock cycles by :func:`_run_seq`:
-one ``lax.scan`` over cycles whose step is the same fused multi-scan,
-with the registers' rows as the carry (:func:`run_sequential`).
+one ``lax.scan`` over cycles whose step is the same fused multi-scan on
+a renumbered value buffer (:func:`slot_plan`), with the buffer and the
+registers' rows as the carry (:func:`run_sequential`).
 
 The value buffer is built on the device (:func:`_device_vals`): only the
 primary inputs' lanes cross the host link, and the buffer is donated to
@@ -350,31 +355,45 @@ def plan_netlist(net: Netlist,
 # ---------------------------------------------------------------------------
 
 
-def _fused_body(vals, xs, *, has_luts: bool, has_chains: bool,
-                use_pallas: bool):
-    """One level: fused LUT kernel + stacked chain ripple.  ``vals`` is the
-    ``[n_signals + 1, N]`` value buffer (last row = padding sink)."""
+def _lut_outputs(vals, ins, tt_lo, tt_hi, use_pallas):
+    """The level's LUT outputs, ``[M, N]``: its input lanes gathered from
+    the value buffer, then one fused ``lut_eval6`` call."""
     from repro.kernels import ops
 
+    gathered = vals[ins]                             # [M, 6, N]
+    return ops.lut_eval6(gathered, tt_lo, tt_hi, use_pallas=use_pallas)
+
+
+def _ripple(vals, a_idx, b_idx, cin_idx):
+    """Every chain of the level rippled at once: the sums and the carries
+    out of each bit, both ``[B, C, N]`` (bit-major)."""
+    av = vals[a_idx]                                 # [C, B, N]
+    bv = vals[b_idx]
+    c0 = vals[cin_idx]                               # [C, N]
+
+    def ripple(c, ab):
+        aa, bb = ab
+        s = aa ^ bb ^ c
+        cy = (aa & bb) | (c & (aa ^ bb))
+        return cy, (s, cy)
+
+    _, (ss, cys) = jax.lax.scan(
+        ripple, c0, (av.swapaxes(0, 1), bv.swapaxes(0, 1)))
+    return ss, cys
+
+
+def _fused_body(vals, xs, *, has_luts: bool, has_chains: bool,
+                use_pallas: bool):
+    """One level of a :class:`FusedPlan`: fused LUT kernel + stacked chain
+    ripple, each output scattered to its signal's row.  ``vals`` is the
+    ``[n_signals + 1, N]`` value buffer (last row = padding sink)."""
     (ins, tt_lo, tt_hi, out_idx, a_idx, b_idx, cin_idx, sums_idx, cout_idx,
      last_idx) = xs
     if has_luts:
-        gathered = vals[ins]                         # [M, 6, N]
-        out = ops.lut_eval6(gathered, tt_lo, tt_hi, use_pallas=use_pallas)
+        out = _lut_outputs(vals, ins, tt_lo, tt_hi, use_pallas)
         vals = vals.at[out_idx].set(out)
     if has_chains:
-        av = vals[a_idx]                             # [C, B, N]
-        bv = vals[b_idx]
-        c0 = vals[cin_idx]                           # [C, N]
-
-        def ripple(c, ab):
-            aa, bb = ab
-            s = aa ^ bb ^ c
-            cy = (aa & bb) | (c & (aa ^ bb))
-            return cy, (s, cy)
-
-        _, (ss, cys) = jax.lax.scan(
-            ripple, c0, (av.swapaxes(0, 1), bv.swapaxes(0, 1)))
+        ss, cys = _ripple(vals, a_idx, b_idx, cin_idx)
         vals = vals.at[sums_idx].set(ss.swapaxes(0, 1))
         # cout is the carry *after the chain's last real bit* — padded tail
         # bits add 0+0 and would zero the carry, so index, don't take last
@@ -384,12 +403,34 @@ def _fused_body(vals, xs, *, has_luts: bool, has_chains: bool,
     return vals, None
 
 
-def _multi_scan(vals, bucket_arrays, flags, use_pallas):
-    """Back-to-back lax.scans, one per width bucket, in topological order."""
+def _slot_body(vals, xs, *, has_luts: bool, has_chains: bool,
+               use_pallas: bool):
+    """One level of a :class:`SlotPlan`: the same kernel and ripple, each
+    output block written by one slice at the level's row ``base``."""
+    base, ins, tt_lo, tt_hi, a_idx, b_idx, cin_idx, last_idx = xs
+    if has_luts:
+        out = _lut_outputs(vals, ins, tt_lo, tt_hi, use_pallas)
+        vals = jax.lax.dynamic_update_slice(vals, out, (base, 0))
+        base = base + ins.shape[0]
+    if has_chains:
+        ss, cys = _ripple(vals, a_idx, b_idx, cin_idx)
+        B, C, N = ss.shape
+        vals = jax.lax.dynamic_update_slice(
+            vals, ss.reshape(B * C, N), (base, 0))
+        cout_v = jnp.take_along_axis(cys, last_idx[None, :, None],
+                                     axis=0)[0]
+        vals = jax.lax.dynamic_update_slice(
+            vals, cout_v, (base + B * C, 0))
+    return vals, None
+
+
+def _multi_scan(vals, bucket_arrays, flags, use_pallas, body=_fused_body):
+    """Back-to-back lax.scans, one per width bucket, in topological order;
+    ``body`` is the level step of the plan the arrays come from."""
     for (hl, hc), xs in zip(flags, bucket_arrays):
-        body = functools.partial(_fused_body, has_luts=hl, has_chains=hc,
+        step = functools.partial(body, has_luts=hl, has_chains=hc,
                                  use_pallas=use_pallas)
-        vals, _ = jax.lax.scan(body, vals, xs)
+        vals, _ = jax.lax.scan(step, vals, xs)
     return vals
 
 
@@ -696,33 +737,104 @@ def eval_netlists_batched_jax(nets: list[Netlist],
 _SEQ_CACHE = _planner.register_cache("seq_programs", cap=16)
 
 
-class SeqProgram(NamedTuple):
-    """One registered netlist's cycle program: its fused plan and the
-    value-buffer rows of its sources and sinks, on the device."""
+@dataclass
+class SlotPlan:
+    """A cycle program's private numbering of the value buffer, so that
+    every write is one slice.
 
-    plan: FusedPlan
-    pi_idx: jax.Array             # [P] int32 PI rows, ``net.pis`` order
-    q_idx: jax.Array              # [R] int32 register Q rows
+    Rows ``0, 1`` are ``CONST0`` / ``CONST1`` and the sources follow
+    (``net.sources``: the primary inputs, then every register's Q): the
+    *source block*, written once a cycle.  Then, per bucket and per level
+    in scan order, one block per level: its ``M`` LUT outputs (real rows
+    first), its ``C x B`` chain sums bit-major (the ripple's ``[B, C, N]``
+    output as it is), then its ``C`` carries out.  Every read index maps
+    through ``slot``; a signal that is neither a source nor written maps
+    to ``CONST0`` and reads 0.  No caller sees a row number: the program
+    returns the outputs only.
+    """
+
+    n_rows: int                   # value-buffer height
+    flags: tuple                  # static per-bucket (has_luts, has_chains)
+    buckets: tuple                # per bucket: (base, ins, tt_lo, tt_hi,
+    #                               a, b, cin, last), base [l] int32
+    slot: np.ndarray              # [n_signals] int32: each signal's row
+    real_luts: int
+    padded_lut_rows: int
+    _dev: tuple | None = field(default=None, repr=False)
+
+    def device_arrays(self):
+        if self._dev is None:
+            self._dev = tuple(tuple(jnp.asarray(a) for a in bk)
+                              for bk in self.buckets)
+        return self._dev
+
+
+def slot_plan(plan: FusedPlan, sources) -> SlotPlan:
+    """Renumber a :class:`FusedPlan`'s rows into level blocks (see
+    :class:`SlotPlan`); its gathers, truth tables and padding stay."""
+    src = np.asarray(sources, dtype=np.int64)
+    slot = np.full(plan.n_signals + 1, CONST0, dtype=np.int64)
+    slot[CONST1] = CONST1
+    slot[src] = 2 + np.arange(src.size)
+    top = 2 + src.size
+    bases = []
+    for bk in plan.buckets:
+        l, M, C, B = bk.shape
+        m = M if bk.has_luts else 0
+        ch = C * B + C if bk.has_chains else 0
+        base = top + (m + ch) * np.arange(l)
+        if bk.has_luts:
+            rows = base[:, None] + np.arange(M)
+            real = bk.lut_out != plan.sink
+            slot[bk.lut_out[real]] = rows[real]
+        if bk.has_chains:
+            s0 = base + m
+            rows = (s0[:, None, None] + np.arange(C)[None, :, None]
+                    + C * np.arange(B)[None, None, :])
+            real = bk.ch_sums != plan.sink
+            slot[bk.ch_sums[real]] = rows[real]
+            rows = s0[:, None] + C * B + np.arange(C)
+            real = bk.ch_cout != plan.sink
+            slot[bk.ch_cout[real]] = rows[real]
+        bases.append(base)
+        top += l * (m + ch)
+    slot = slot.astype(np.int32)
+    buckets = tuple(
+        (base.astype(np.int32), slot[bk.lut_ins], bk.lut_tt_lo,
+         bk.lut_tt_hi, slot[bk.ch_a], slot[bk.ch_b], slot[bk.ch_cin],
+         bk.ch_last)
+        for base, bk in zip(bases, plan.buckets))
+    return SlotPlan(n_rows=top, flags=plan.flags, buckets=buckets,
+                    slot=slot[:plan.n_signals], real_luts=plan.real_luts,
+                    padded_lut_rows=plan.padded_lut_rows)
+
+
+class SeqProgram(NamedTuple):
+    """One registered netlist's cycle program: its slot plan and the
+    rows of its sinks in it, on the device."""
+
+    plan: SlotPlan
+    n_pis: int
     d_idx: jax.Array              # [R] int32 register D rows
     po_idx: jax.Array             # [O] int32 PO rows, ``net.pos`` order
 
 
 def seq_program(net: Netlist) -> SeqProgram:
-    """The cycle program of ``net`` (content-cached, on the same plan as
-    the combinational evaluator)."""
+    """The cycle program of ``net`` (content-cached), on the slot plan of
+    the combinational evaluator's fused plan."""
     key = netlist_digest(net)
     hit = _SEQ_CACHE.get(key)
     if hit is not None:
         return hit
     net.check_regs_connected()
+    plan = slot_plan(plan_netlist(net), net.sources)
 
     def rows(sigs):
-        return jnp.asarray(np.asarray(sigs, dtype=np.int32).reshape(-1))
+        return jnp.asarray(plan.slot[np.asarray(sigs, dtype=np.int64)
+                                     .reshape(-1)])
 
     prog = SeqProgram(
-        plan=plan_netlist(net), pi_idx=rows(net.pis),
-        q_idx=rows([q for _, q in net.regs]),
-        d_idx=rows([d for d, _ in net.regs]),
+        plan=plan, n_pis=len(net.pis), d_idx=rows([d for d, _ in net.regs]),
         po_idx=rows([s for bus in net.pos.values() for s in bus]))
     _SEQ_CACHE.put(key, prog)
     return prog
@@ -730,24 +842,35 @@ def seq_program(net: Netlist) -> SeqProgram:
 
 @functools.partial(jax.jit, static_argnames=("flags", "use_pallas",
                                              "n_rows"))
-def _run_seq(pi_stream, pi_idx, q_idx, d_idx, po_idx, bucket_arrays, *,
-             flags, use_pallas, n_rows):
-    """``lax.scan`` over cycles, the register rows the carry.  A cycle
-    builds the value buffer from its PI rows, the registers' Q and
-    ``CONST1`` (:func:`_device_vals`), runs the fused bucket scans, and
-    gathers the next Q (the D rows) and the PO rows.  Every register
-    starts at 0.  ``pi_stream`` is ``[T, P, N]``; returns ``[T, O, N]``."""
-    src_idx = jnp.concatenate([pi_idx, q_idx])
+def _run_seq(pi_stream, d_idx, po_idx, bucket_arrays, *, flags, use_pallas,
+             n_rows):
+    """``lax.scan`` over cycles of a :class:`SlotPlan`, the value buffer
+    and the register rows the carry.  A cycle writes the source block
+    (``CONST0``, ``CONST1``, its PI rows, the registers' Q) by one slice,
+    runs the fused bucket scans, whose levels write their blocks by slice
+    too (:func:`_slot_body`), and gathers the next Q (the D rows) and the
+    PO rows.  Every other row is rewritten before it is read, so the
+    buffer is carried, not rebuilt.  Every register starts at 0.
+    ``pi_stream`` is ``[T, P, N]``; returns ``[T, O, N]``.
 
-    def cycle(state, pi_rows):
-        vals = _device_vals(src_idx, jnp.concatenate([pi_rows, state]),
-                            n_rows=n_rows)
-        vals = _multi_scan(vals, bucket_arrays, flags, use_pallas)
-        return vals[d_idx], vals[po_idx]
+    The combinational programs (:func:`_run_fused`,
+    :func:`_run_fused_batch`) return the whole buffer by signal id, so
+    their levels scatter instead (:func:`_fused_body`)."""
+    n = pi_stream.shape[-1]
+    consts = jnp.broadcast_to(
+        jnp.array([[0], [0xFFFFFFFF]], dtype=jnp.uint32), (2, n))
 
-    state = jnp.zeros((d_idx.shape[0], pi_stream.shape[-1]),
-                      dtype=jnp.uint32)
-    _, pos = jax.lax.scan(cycle, state, pi_stream)
+    def cycle(carry, pi_rows):
+        vals, state = carry
+        vals = jax.lax.dynamic_update_slice(
+            vals, jnp.concatenate([consts, pi_rows, state]), (0, 0))
+        vals = _multi_scan(vals, bucket_arrays, flags, use_pallas,
+                           body=_slot_body)
+        return (vals, vals[d_idx]), vals[po_idx]
+
+    vals = jnp.zeros((n_rows, n), dtype=jnp.uint32)
+    state = jnp.zeros((d_idx.shape[0], n), dtype=jnp.uint32)
+    _, pos = jax.lax.scan(cycle, (vals, state), pi_stream)
     return pos
 
 
@@ -757,18 +880,20 @@ def run_sequential(prog: SeqProgram, pi_stream: np.ndarray,
     inputs per cycle, ``net.pis`` order) and return the primary outputs'
     lanes per cycle, ``[T, O, N]`` uint32 in ``net.pos`` order.  The
     stream crosses to the device once and the outputs come back once: no
-    state crosses between cycles."""
-    n_pis = int(prog.pi_idx.shape[0])
-    if pi_stream.ndim != 3 or pi_stream.shape[1] != n_pis:
+    state crosses between cycles.  The ``repro.seq.run`` span counts the
+    value-buffer rows the call writes, by slice and by scatter."""
+    if pi_stream.ndim != 3 or pi_stream.shape[1] != prog.n_pis:
         raise ValueError(f"PI stream of shape {pi_stream.shape}, the "
-                         f"netlist has {n_pis} primary inputs")
+                         f"netlist has {prog.n_pis} primary inputs")
+    plan = prog.plan
     with span("repro.seq.put", bytes=pi_stream.nbytes):
         dev = jnp.asarray(pi_stream, dtype=jnp.uint32).block_until_ready()
-    with span("repro.seq.run"):
-        out = _run_seq(dev, prog.pi_idx, prog.q_idx, prog.d_idx,
-                       prog.po_idx, prog.plan.device_arrays(),
-                       flags=prog.plan.flags, use_pallas=use_pallas,
-                       n_rows=prog.plan.n_signals + 1).block_until_ready()
+    # every row of the buffer is written by slice once a cycle
+    with span("repro.seq.run", slice_rows=pi_stream.shape[0] * plan.n_rows,
+              scatter_rows=0):
+        out = _run_seq(dev, prog.d_idx, prog.po_idx, plan.device_arrays(),
+                       flags=plan.flags, use_pallas=use_pallas,
+                       n_rows=plan.n_rows).block_until_ready()
     with span("repro.seq.get") as sp:
         out = np.asarray(out)
         sp.set(bytes=out.nbytes)

@@ -31,8 +31,10 @@ The stages:
   content-cached, so repeated figures reuse compiles.
 * **cycle simulation** — :func:`evaluate_sequential` steps registered
   netlists over clock cycles on the device: one jitted scan over cycles
-  per netlist, the fused bucket program its step, the registers its
-  carry (:func:`repro.core.eval_jax.run_sequential`).
+  per netlist, the fused bucket program its step (on a private row
+  numbering, so each level writes its outputs by slice), the value
+  buffer and the registers its carry
+  (:func:`repro.core.eval_jax.run_sequential`).
 * :func:`oracle_check` closes the loop: any JAX-side result can be
   proven bit-identical to the pure-Python ``eval_netlist`` oracle.
 

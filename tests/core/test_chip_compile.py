@@ -218,20 +218,70 @@ def stream_cell():
     return net, seq_program(net), traffic
 
 
+def _buffer_writes(text: str, n_rows: int, n_words: int) -> dict:
+    """Counts of the compiled program's operations, fused ones included,
+    that write a ``[.., n_rows, n_words]`` value buffer, by kind."""
+    import re
+
+    buf = rf"u32\[(?:\d+,)?{n_rows},{n_words}\]"
+    return {kind: len(re.findall(rf"= {buf}\S* {kind}\(", text))
+            for kind in ("scatter", "dynamic-update-slice")}
+
+
 def test_cycle_program_compiles_for_v5e(one_chip, stream_cell, monkeypatch):
     """The scan over cycles at the stream cell's envelope (the 32x32
-    tile, its cycles and lane words), the Mosaic kernel inside."""
+    tile, its cycles and lane words), the Mosaic kernel inside.  Its
+    levels write the value buffer by slice: no scatter writes it, and no
+    constant of a buffer's size is folded in."""
+    import re
+
     from repro.core.eval_jax import _run_seq
     from repro.kernels import ops
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     net, prog, traffic = stream_cell
+    n_words = traffic["lane_words"]
     stream = jax.ShapeDtypeStruct(
-        (traffic["cycles"], len(net.pis), traffic["lane_words"]),
-        jnp.uint32, sharding=one_chip)
+        (traffic["cycles"], len(net.pis), n_words), jnp.uint32,
+        sharding=one_chip)
     compiled = _run_seq.lower(
-        stream, *_shapes((prog.pi_idx, prog.q_idx, prog.d_idx,
-                          prog.po_idx), one_chip),
-        _shapes(prog.plan.arrays(), one_chip), flags=prog.plan.flags,
-        use_pallas=True, n_rows=prog.plan.n_signals + 1).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        stream, *_shapes((prog.d_idx, prog.po_idx), one_chip),
+        _shapes(prog.plan.buckets, one_chip), flags=prog.plan.flags,
+        use_pallas=True, n_rows=prog.plan.n_rows).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "scatter" not in text
+    assert _buffer_writes(text, prog.plan.n_rows, n_words)[
+        "dynamic-update-slice"] > 0
+    for dims in re.findall(r"\[([\d,]+)\]\S* constant\(", text):
+        assert np.prod([int(d) for d in dims.split(",")]) < prog.plan.n_rows
+
+
+def test_eval_program_still_scatters_for_v5e(one_chip, monkeypatch):
+    """The grouped evaluator at the eval cell's envelope (the jsc-mlp-s10
+    layers' widest group, 4,096 lane words) returns its buffer by signal
+    id, so its levels keep writing it by scatter."""
+    from repro.core import circuits
+    from repro.core.eval_jax import _run_fused_batch, prepare_suite_program
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "bench", "configs",
+                           "jsc-mlp-s10.json")) as f:
+        suites = json.load(f)["suites"]
+    nets = [getattr(circuits, s["generator"])(**s["kwargs"]) for s in suites]
+    with open(os.path.join(root, "bench", "traffic", "eval-wide.json")) as f:
+        n_words = json.load(f)["lane_words"]
+    prog = prepare_suite_program(nets)
+    gi = max(range(len(prog.groups)),
+             key=lambda i: prog.programs[i].n_sig)
+    g = prog.programs[gi]
+    vals = jax.ShapeDtypeStruct((len(prog.groups[gi]), g.n_sig + 1, n_words),
+                                jnp.uint32, sharding=one_chip)
+    text = _run_fused_batch.lower(
+        vals, _shapes(g.stacked, one_chip), flags=g.flags,
+        use_pallas=True).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _buffer_writes(text, g.n_sig + 1, n_words)["scatter"] > 0

@@ -262,6 +262,195 @@ def test_array_variants_simulate_like_the_plain_stepping(rows, cols):
         [[ref[t][s] for s in po] for t in range(T)], dtype=np.uint32))
 
 
+def _shift_register() -> Netlist:
+    """Registers fed straight by a primary input and by another Q, with
+    no logic at all: an empty plan."""
+    net = Netlist("shift")
+    x = net.add_pi_bus("x", 2)
+    r = net.add_reg_bus("r", 3)
+    net.connect_reg(r[0], x[0])
+    net.connect_reg(r[1], r[0])
+    net.connect_reg(r[2], x[1])
+    net.set_po_bus("y", [r[1], r[2], x[0]])
+    return net
+
+
+def _chains(want_cout: bool) -> Netlist:
+    """An accumulator through a chain, with or without its carry out,
+    and a level holding both LUTs and a chain that reads a LUT output."""
+    net = Netlist("acc")
+    x = net.add_pi_bus("x", 3)
+    acc = net.add_reg_bus("acc", 3)
+    s, co = net.add_chain(acc, x, want_cout=want_cout)
+    for q, d in zip(acc, s):
+        net.connect_reg(q, d)
+    t = net.add_lut((x[0], x[1]), TT_XOR2)           # level 1
+    u = net.add_lut((x[1], acc[2]), TT_AND2)         # level 1
+    v = net.add_lut((t, u, x[2]), TT_MUX)            # level 2
+    s2, co2 = net.add_chain([t, u], [x[2], acc[0]])  # level 2, reads t, u
+    net.set_po_bus("y", s + s2 + [v] + ([co] if want_cout else [co2 or 0]))
+    return net
+
+
+def _undriven() -> Netlist:
+    """Signals nothing drives, read by a LUT, a chain, a D and a PO."""
+    net = Netlist("undriven")
+    x = net.add_pi_bus("x", 2)
+    r = net.add_reg_bus("r", 2)
+    u, w = net.new_sig(), net.new_sig()
+    a = net.add_lut((u, x[0], r[1]), TT_MUX)
+    s, _ = net.add_chain([a, w], [x[1], r[0]], cin=u)
+    net.connect_reg(r[0], s[1])
+    net.connect_reg(r[1], w)
+    net.set_po_bus("y", s + [a, u, r[1]])
+    return net
+
+
+SMALL_NETS = {"shift": _shift_register, "chain-cout": lambda: _chains(True),
+              "chain-no-cout": lambda: _chains(False),
+              "undriven": _undriven}
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+@pytest.mark.parametrize("kind", sorted(SMALL_NETS))
+def test_small_registered_netlists_simulate_like_the_plain_stepping(
+        kind, use_pallas):
+    """Edge cases of the cycle program's slot plan against the plain
+    stepping on random streams; an undriven signal reads 0 in both."""
+    net = SMALL_NETS[kind]()
+    T, N = 7, 2
+    stream = np.random.default_rng(len(kind)).integers(
+        0, 2**32, (T, len(net.pis), N), dtype=np.uint32)
+    outs, _ = flow.evaluate_sequential([net], [stream],
+                                       use_pallas=use_pallas)
+    driven = set(net.sources) | set(net.lut_out) | {
+        s for ch in net.chains for s in ch.sums + [ch.cout]}
+    zeros = {s: [0] * T for s in range(2, net.n_signals) if s not in driven}
+    assert (kind == "undriven") == bool(zeros)
+    pis = {s: [int(stream[t, i, 0]) | int(stream[t, i, 1]) << 32
+               for t in range(T)] for i, s in enumerate(net.pis)}
+    ref = eval_sequential(net, {**pis, **zeros}, T, 64)
+    po = [s for bus in net.pos.values() for s in bus]
+    got = np.array([[ref[t][s] for s in po] for t in range(T)],
+                   dtype=np.uint64)
+    words = outs[0].astype(np.uint64)
+    assert np.array_equal(got, words[:, :, 0] | words[:, :, 1] << 32)
+
+
+def _blocks(plan):
+    """Each padded level's ``(start, end)`` rows of its block."""
+    out = []
+    for (hl, hc), (base, ins, *_rest, a, _b, _cin, _last) in zip(
+            plan.flags, plan.buckets):
+        size = (ins.shape[1] if hl else 0) + (
+            a.shape[1] * (a.shape[2] + 1) if hc else 0)
+        out += [(int(b), int(b) + size) for b in base]
+    return out
+
+
+@pytest.mark.parametrize("kind", ["sa4"] + sorted(SMALL_NETS))
+def test_slot_layout_is_sound(arrays, kind):
+    """Sources sit contiguously at row 2; the level blocks follow without
+    overlap; every signal a level, a D or a PO reads has one row of its
+    own (or ``CONST0`` where nothing drives it), inside the block of the
+    level that writes it; D and PO rows map through the same slots."""
+    from repro.core.eval_jax import plan_netlist, seq_program
+
+    net = arrays[4] if kind == "sa4" else SMALL_NETS[kind]()
+    prog = seq_program(net)
+    plan, fused = prog.plan, plan_netlist(net)
+    S = len(net.sources)
+    assert np.array_equal(plan.slot[net.sources], 2 + np.arange(S))
+    assert list(plan.slot[:2]) == [0, 1]
+    blocks = _blocks(plan)
+    ends = [2 + S] + [e for _, e in blocks]
+    assert all(s == e for (s, _), e in zip(blocks, ends))
+    assert ends[-1] == plan.n_rows
+    # every written row lies in its level's block, one signal a row
+    written = {}
+    for bk, (base, *_r) in zip(fused.buckets, plan.buckets):
+        for r, b in enumerate(base):
+            lo, hi = next(blk for blk in blocks if blk[0] == b)
+            outs = [bk.lut_out[r], bk.ch_sums[r].ravel(), bk.ch_cout[r]]
+            for s in np.concatenate(outs):
+                if s != fused.sink:
+                    assert lo <= plan.slot[s] < hi
+                    written[int(s)] = int(plan.slot[s])
+    rows = list(written.values()) + list(plan.slot[net.sources])
+    assert len(set(rows)) == len(rows)
+    reads = {int(s) for bk in fused.buckets
+             for arr in (bk.lut_ins, bk.ch_a, bk.ch_b, bk.ch_cin)
+             for s in arr.ravel()} | {d for d, _ in net.regs} | {
+        s for bus in net.pos.values() for s in bus}
+    for s in reads - {0, 1, fused.sink}:
+        if s in written or s in net.sources:
+            assert plan.slot[s] >= 2
+        else:
+            assert kind == "undriven" and plan.slot[s] == 0
+    assert np.array_equal(np.asarray(prog.d_idx),
+                          plan.slot[[d for d, _ in net.regs]])
+    assert np.array_equal(np.asarray(prog.po_idx), plan.slot[
+        [s for bus in net.pos.values() for s in bus]])
+
+
+def test_cycle_program_writes_by_slice_only(arrays, monkeypatch):
+    """The ``repro.seq.run`` span counts the rows a call writes: cycles x
+    (the source block, ``CONST0``/``CONST1`` included, and every level's
+    outputs, padding included), all by slice."""
+    from repro.core import eval_jax
+
+    seen = []
+    real = eval_jax.span
+
+    def record(name, *args, **stats):
+        seen.append((name, stats))
+        return real(name, *args, **stats)
+
+    monkeypatch.setattr(eval_jax, "span", record)
+    net = arrays[2]
+    stream, _ = _schedule(net, 2, 1, 1, seed=3)
+    flow.evaluate_sequential([net], [stream], use_pallas=False)
+    fused = eval_jax.plan_netlist(net)
+    blocks = sum(bk.n_levels * (bk.shape[1] * bk.has_luts
+                                + (bk.shape[2] * bk.shape[3] + bk.shape[2])
+                                * bk.has_chains) for bk in fused.buckets)
+    run = [st for name, st in seen if name == "repro.seq.run"]
+    assert run == [{"slice_rows": stream.shape[0]
+                    * (2 + len(net.sources) + blocks), "scatter_rows": 0}]
+
+
+def test_slot_level_writes_its_luts_before_its_chains():
+    """A chain that reads a LUT output of its own level (a plan built by
+    hand: levelization puts such a chain one level later) reads the
+    value the LUT wrote, on both write paths."""
+    import jax.numpy as jnp
+
+    from repro.core import eval_jax
+
+    # signals: 0, 1, a = 2, b = 3, lut t = a ^ b = 4, chain sum = t + b = 5
+    bk = eval_jax.PlanBucket(
+        n_levels=1, has_luts=True, has_chains=True,
+        lut_ins=np.array([[[2, 3, 0, 0, 0, 0]]], np.int32),
+        lut_tt_lo=np.array([[0b0110]], np.uint32),
+        lut_tt_hi=np.array([[0b0110]], np.uint32),
+        lut_out=np.array([[4]], np.int32),
+        ch_a=np.array([[[4]]], np.int32), ch_b=np.array([[[3]]], np.int32),
+        ch_cin=np.array([[0]], np.int32), ch_sums=np.array([[[5]]], np.int32),
+        ch_cout=np.array([[6]], np.int32), ch_last=np.array([[0]], np.int32))
+    fused = eval_jax.FusedPlan(n_signals=6, n_levels=1, buckets=(bk,))
+    a, b = np.uint32(0b1100), np.uint32(0b1010)
+    want = {4: a ^ b, 5: a ^ b ^ b}
+    vals = jnp.zeros((7, 1), jnp.uint32).at[2:4, 0].set(jnp.array([a, b]))
+    out = eval_jax._multi_scan(vals, fused.arrays(), fused.flags, False)
+    assert {s: out[s, 0] for s in want} == want
+    plan = eval_jax.slot_plan(fused, [2, 3])
+    vals = jnp.zeros((plan.n_rows, 1), jnp.uint32).at[2:4, 0].set(
+        jnp.array([a, b]))
+    out = eval_jax._multi_scan(vals, plan.buckets, plan.flags, False,
+                               body=eval_jax._slot_body)
+    assert {s: out[plan.slot[s], 0] for s in want} == want
+
+
 def test_evaluate_sequential_refuses_a_wrong_stream(arrays):
     net = arrays[2]
     with pytest.raises(ValueError):
