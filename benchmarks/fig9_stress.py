@@ -6,10 +6,10 @@ ALMs saturate; concurrently packed 5-LUTs saturate at ~375 (75 %).
 The *layered* saturated stress circuit (500 adders + 500 LUTs feeding two
 3x-smaller layers — a wide-then-narrow level profile) doubles as the
 standard workload for the netlist-evaluation engine: ``run_eval_benchmark``
-times the width-bucketed fused evaluator against the seed per-level
-dispatcher on it, proves pack/re-elaborate equivalence through the
-``core.flow`` pipeline, and reports the engine's roofline terms — including
-the per-bucket padding waste next to the old single-envelope waste.
+times the width-bucketed fused evaluator on it, proves pack/re-elaborate
+equivalence through the ``core.flow`` pipeline, and reports the engine's
+roofline terms — including the per-bucket padding waste next to the old
+single-envelope waste.
 """
 from __future__ import annotations
 
@@ -47,18 +47,18 @@ def eval_workload(n_adders: int = 500, n_luts: int = 500, seed: int = 0,
 def run_eval_benchmark(n_lane_words: int = 8, use_pallas: bool = True,
                        reps: int = 3, check_equiv: bool = True,
                        verbose: bool = True) -> dict:
-    """Time fused vs per-level evaluation of the stress workload.
+    """Time the evaluation of the stress workload.
 
-    Returns a record with best-of-``reps`` wall times (post-warmup, so the
-    fused number excludes its one-time compile), the speedup, the fused
-    engine's analytic roofline terms (bucketed and single-envelope padding
-    waste side by side), and — when ``check_equiv`` — the pack/
-    re-elaborate equivalence verdicts for baseline and DD5.
+    Returns a record with the best-of-``reps`` wall time (post-warmup, so
+    it excludes the one-time compile), the evaluator's analytic roofline
+    terms (bucketed and single-envelope padding waste side by side), and
+    — when ``check_equiv`` — the pack/re-elaborate equivalence verdicts
+    for baseline and DD5.
     """
     import jax
 
     from repro.core.equiv import check_pack_equivalence
-    from repro.core.eval_jax import eval_netlist_jax_levels, plan_netlist
+    from repro.core.eval_jax import eval_netlist_jax, plan_netlist
     from .roofline import netlist_eval_terms
 
     net = eval_workload()
@@ -72,19 +72,15 @@ def run_eval_benchmark(n_lane_words: int = 8, use_pallas: bool = True,
                            n=reps, warmup=1)
         return best
 
-    t_levels = bench(lambda: eval_netlist_jax_levels(
+    t_fused = bench(lambda: eval_netlist_jax(
         net, lanes, n_lane_words, use_pallas=use_pallas))
-    t_fused = bench(lambda: flow.evaluate_netlist(
-        net, lanes, n_lane_words, use_pallas=use_pallas, plan=plan))
     rec = {
         "workload": f"fig9_stress({net.name}: 500+ adders, 500+ luts, "
                     f"layered)",
         "n_lane_words": n_lane_words,
         "n_vectors": n_lane_words * 32,
         "use_pallas": use_pallas,
-        "t_levels_s": t_levels,
         "t_fused_s": t_fused,
-        "speedup": t_levels / t_fused,
         "roofline": netlist_eval_terms(net, n_lane_words, plan=plan),
     }
     if check_equiv:
@@ -93,9 +89,7 @@ def run_eval_benchmark(n_lane_words: int = 8, use_pallas: bool = True,
             ["equivalent"] for arch in (BASELINE, DD5)
         }
     if verbose:
-        emit("fig9_eval/levels", t_levels * 1e6, "seed per-level dispatcher")
         emit("fig9_eval/fused", t_fused * 1e6,
-             f"speedup={rec['speedup']:.1f}x;"
              f"equiv={rec.get('equiv', 'skipped')}")
     return rec
 

@@ -8,8 +8,10 @@ static-timing analyzer (``timing_vec.analyze_ir`` — the placement
 columns).  This check packs two small circuits, lowers each once, and
 proves from the *same IR object*:
 
-* evaluation output bit-identical to the pure-python ``eval_netlist``
-  oracle on every primary output (``flow.oracle_check``);
+* the evaluation plan equal, tensor for tensor, to the one the evaluator
+  runs for the netlist, and that evaluation bit-identical to the
+  pure-python ``eval_netlist`` oracle on every primary output
+  (``flow.oracle_check``);
 * the timing record bit-identical to ``timing.analyze_oracle``;
 * the lowering counters show exactly one functional lowering per
   circuit and one placement patch per (circuit, class) — no duplicate
@@ -25,7 +27,7 @@ from repro.core import flow
 from repro.core.alm import ARCHS
 from repro.core.circuit_ir import read_lower_counts, reset_lower_counts
 from repro.core.circuits import kratos_gemm, sha_like
-from repro.core.eval_jax import eval_netlist_jax, plan_from_ir
+from repro.core.eval_jax import eval_netlist_jax, plan_from_ir, plan_netlist
 from repro.core.packing import pack
 from repro.core.plan import clear_caches
 from repro.core.timing import analyze_oracle
@@ -46,11 +48,14 @@ def run(verbose: bool = True) -> dict:
     for net in nets:
         packed = pack(net, arch, seed=0)
         ir = packed.lower_ir()                      # the ONE lowering
-        # eval lane: plan built from the same IR object
-        plan = plan_from_ir(ir)
+        # eval lane: the plan built from the same IR object is the plan
+        # the evaluator runs for the netlist
+        got, want = plan_from_ir(ir), plan_netlist(net)
+        eval_ok &= got.flags == want.flags and all(
+            np.array_equal(x, y) for bg, bw in zip(got.arrays(), want.arrays())
+            for x, y in zip(bg, bw))
         lanes = flow.random_lanes(net, N_LANE_WORDS, seed=0)
-        vals = np.asarray(eval_netlist_jax(net, lanes, N_LANE_WORDS,
-                                           plan=plan))
+        vals = eval_netlist_jax(net, lanes, N_LANE_WORDS)
         eval_ok &= flow.oracle_check(net, lanes, vals, N_LANE_WORDS)
         # timing lane: same IR object, vs the python oracle
         rec = analyze_ir(ir, arch)

@@ -31,11 +31,6 @@ def main():
     us = _time(ops.popcount_matmul, x, w, mode="and")
     emit("kernel/popcount_matmul_256x256x256b", us, "mode=and")
 
-    ins = jnp.asarray(r.integers(0, 2**32, (2048, 4, 8), dtype=np.uint32))
-    tts = jnp.asarray(r.integers(0, 2**16, (2048,), dtype=np.uint32))
-    us = _time(ops.lut_eval, ins, tts)
-    emit("kernel/lut_eval_2048x4x8", us, "k=4")
-
     xf = jnp.asarray(r.standard_normal((128, 256)).astype(np.float32))
     planes = jnp.asarray(r.integers(0, 2, (4, 256, 128)).astype(np.float32))
     scale = jnp.ones(128, jnp.float32)

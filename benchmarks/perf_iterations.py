@@ -13,33 +13,17 @@ Each variant is re-lowered and re-compiled through the same dry-run path
 analytic roofline terms quantify the delta; the compiled HLO collective
 inventory is the cross-check.
 
-Cell 4 is the netlist-evaluation engine itself (the paper-side hot path):
-fused single-jit evaluator vs the seed per-level dispatcher on the Fig. 9
-stress workload, gated on pack/re-elaborate equivalence.
-
-Cell 5 (``suite-eval``) is the suite-scale flow: evaluate the re-elaborated
-Kratos + Koios + VTR suites per arch as a handful of envelope-grouped
-vmapped jit programs (``core.flow.evaluate_suite``) vs one fused program
-per circuit, gated on pack equivalence exactly like cell 4, with every
-grouped result proven bit-identical to the Python oracle.  Records land in
-``experiments/perf/suite_eval_grouped.json``.
-
 NOTE: the model cells must run in a fresh process (``run_variant`` imports
-launch.dryrun, which sets the 512-device XLA flag on first use).  Run
-``python -m benchmarks.perf_iterations netlist-eval`` (cell 4) or
-``python -m benchmarks.perf_iterations suite-eval`` (cell 5) alone — those
-paths never import dryrun, so timings see the real host device.
+launch.dryrun, which sets the 512-device XLA flag on first use).
 """
 import dataclasses
 import json
 import os
-import sys
 
 from repro.configs.base import get_config
 from repro.train.optimizer import OptConfig
 from repro.train.step import TrainConfig
 
-from .common import min_of_n
 from .roofline import analytic_terms
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "perf")
@@ -80,261 +64,6 @@ def show(rec):
           f"memory={t['t_memory']:.3e}s coll={t['t_collective']:.3e}s "
           f"dominant={dom[2:]} | HLO coll/dev: {kinds}", flush=True)
     return t
-
-
-def run_netlist_eval_cell(force: bool = False) -> dict:
-    """Cell 4: hypothesis — the seed evaluator is dispatch-bound (one kernel
-    launch per LUT level and one scan per chain); change — fuse all levels
-    into a single-jit ``lax.scan`` over padded tensors; before/after —
-    recorded below (acceptance gate: fused >= 2x on the Fig. 9 workload,
-    with pack equivalence proven so the speed is not bought with wrong
-    answers)."""
-    from .fig9_stress import run_eval_benchmark
-
-    # the model cells force 512 fake host devices (launch.dryrun sets
-    # XLA_FLAGS at import); timings taken under that env are not
-    # comparable to real-device runs, so tag the record with the env and
-    # never serve a cached record from the other one
-    env = _device_env()
-    os.makedirs(OUT, exist_ok=True)
-    suffix = "" if env == "host" else f"_{env}"
-    path = os.path.join(OUT, f"netlist_eval_fused{suffix}.json")
-    if os.path.exists(path) and not force:
-        with open(path) as f:
-            cached = json.load(f)
-        if cached.get("device_env") == env:
-            return cached
-    rec = {"tag": "netlist_eval_fused", "device_env": env}
-    for use_pallas in (True, False):
-        r = run_eval_benchmark(use_pallas=use_pallas, verbose=False)
-        key = "pallas" if use_pallas else "jnp"
-        rec[key] = r
-        print(f"netlist_eval[{key:6s}] levels={r['t_levels_s']*1e3:9.1f}ms "
-              f"fused={r['t_fused_s']*1e3:7.2f}ms "
-              f"speedup={r['speedup']:8.1f}x equiv={r['equiv']}", flush=True)
-    rec["speedup_min"] = min(rec["pallas"]["speedup"], rec["jnp"]["speedup"])
-    rec["pass_2x_gate"] = rec["speedup_min"] >= 2.0
-    with open(path, "w") as f:
-        json.dump(rec, f, indent=1)
-    return rec
-
-
-def _device_env() -> str:
-    return ("512dev" if "xla_force_host_platform_device_count"
-            in os.environ.get("XLA_FLAGS", "") else "host")
-
-
-def run_suite_eval_cell(force: bool = False, n_lane_words: int = 4,
-                        reps: int = 3) -> dict:
-    """Cell 5: hypothesis — per-circuit fused eval leaves suite-scale
-    throughput on the table (one compile + one dispatch per circuit, and a
-    worst-case [L, M_max, 6] envelope wastes padded rows); change — width-
-    bucketed plans + envelope-grouped vmapped evaluation via
-    ``core.flow.evaluate_suite``; before/after — recorded below, gated on
-    pack equivalence and on grouped-vs-oracle bit-identity."""
-    import time
-
-    import jax
-    import numpy as np
-
-    from repro.core import flow
-    from repro.core.equiv import (equivalence_report, reelaborate,
-                                  symbolic_equivalence_report)
-    from repro.core.packing import pack as pack_fn
-    from repro.core.alm import ARCHS
-
-    from .common import suites
-    from .roofline import netlist_eval_terms
-
-    env = _device_env()
-    os.makedirs(OUT, exist_ok=True)
-    suffix = "" if env == "host" else f"_{env}"
-    path = os.path.join(OUT, f"suite_eval_grouped{suffix}.json")
-    if os.path.exists(path) and not force:
-        with open(path) as f:
-            cached = json.load(f)
-        if cached.get("device_env") == env:
-            return cached
-    nets = [net for nets_ in suites("wallace").values() for net in nets_]
-    rec = {"tag": "suite_eval_grouped", "device_env": env,
-           "n_lane_words": n_lane_words, "n_circuits": len(nets),
-           "archs": {}}
-    for arch_name in ("baseline", "dd5"):
-        arch = ARCHS[arch_name]
-        phys_nets, methods, gate_ok = [], {}, True
-        for net in nets:
-            re_elab = reelaborate(pack_fn(net, arch, seed=0))
-            srep = symbolic_equivalence_report(net, re_elab)
-            if srep["equivalent"]:
-                methods[net.name] = "symbolic"
-            else:
-                rep = equivalence_report(net, re_elab, n_vectors=64)
-                methods[net.name] = "simulate"
-                gate_ok &= rep["equivalent"]
-            phys_nets.append(re_elab.phys)
-        lanes = [flow.random_lanes(p, n_lane_words, seed=i)
-                 for i, p in enumerate(phys_nets)]
-        # plans and the grouped suite program are prepared once, outside
-        # the timed region, so both sides time evaluation (results are
-        # materialized as np arrays — no async dispatch escapes the clock)
-        prog = flow.prepare_suite(phys_nets)
-        plans = [flow.plan_netlist(p) for p in phys_nets]
-        stats = prog.stats
-
-        def grouped():
-            return flow.evaluate_suite(phys_nets, lanes, n_lane_words,
-                                       program=prog)[0]
-
-        def per_circuit():
-            return [flow.evaluate_netlist(p, ln, n_lane_words, plan=pl)
-                    for p, ln, pl in zip(phys_nets, lanes, plans)]
-
-        # the backend-aware cost model (ROADMAP item): its warm-path
-        # pick is recorded next to both measured warm walls below, so
-        # the model is auditable against the walls it predicts
-        model = flow.eval_mode_cost_model(phys_nets, plans=plans,
-                                          warm=True)
-        # suite-per-arch wall time, COLD: one full pass including jit
-        # compiles — the number a figure run actually pays.  Grouped
-        # compiles <= 4 programs; per-circuit compiles one per circuit.
-        jax.clear_caches()
-        t0 = time.perf_counter()
-        outs_g = grouped()
-        t_cold_grouped = time.perf_counter() - t0
-        jax.clear_caches()
-        t0 = time.perf_counter()
-        per_circuit()
-        t_cold_single = time.perf_counter() - t0
-        # WARM steady-state (compiles cached), min-of-``reps`` via the
-        # shared gate timer
-        t_grouped, _ = min_of_n(grouped, n=reps)
-        t_single, _ = min_of_n(per_circuit, n=reps)
-        warm_winner = "grouped" if t_grouped <= t_single else "per_circuit"
-        warm_gap = abs(t_grouped - t_single) / max(t_grouped, t_single)
-        oracle_ok = all(
-            flow.oracle_check(p, ln, vals, n_lane_words)
-            for p, ln, vals in zip(phys_nets, lanes, outs_g))
-        real = sum(p.n_luts + p.n_adders for p in phys_nets)
-        padded_grouped = sum(g["padded_lut_rows"] + g["padded_chain_bits"]
-                             for g in stats["groups"])
-        terms = [netlist_eval_terms(p, n_lane_words) for p in phys_nets]
-        waste_single = float(np.mean(
-            [t["padding_waste_single_envelope"] for t in terms]))
-        rec["archs"][arch_name] = {
-            "equiv_gate_ok": gate_ok,
-            "equiv_methods": methods,
-            "n_groups": stats["n_groups"],
-            "groups": stats["groups"],
-            "t_suite_grouped_s": t_cold_grouped,
-            "t_suite_per_circuit_s": t_cold_single,
-            "suite_speedup": t_cold_single / t_cold_grouped,
-            "t_warm_grouped_s": t_grouped,
-            "t_warm_per_circuit_s": t_single,
-            "warm_speedup": t_single / t_grouped,
-            "padding_waste_grouped": 1.0 - real / max(padded_grouped, 1),
-            "padding_waste_single_envelope_mean": waste_single,
-            "oracle_match": bool(oracle_ok),
-            # warm-path grouping heuristic: the model's pick, its cost
-            # terms, and whether the measured warm walls agree.  On hosts
-            # where the two paths land within the run-to-run noise band
-            # (the winner flips between recordings), either pick is
-            # correct — "agrees" accounts for that explicitly.
-            "cost_model": model,
-            "warm_measured_winner": warm_winner,
-            "warm_gap_frac": warm_gap,
-            "cost_model_agrees_warm": (model["pick"] == warm_winner
-                                       or warm_gap < 0.25),
-        }
-        print(f"suite_eval[{arch_name:8s}] circuits={len(nets)} "
-              f"groups={stats['n_groups']} "
-              f"suite: grouped={t_cold_grouped:6.2f}s "
-              f"per-circuit={t_cold_single:6.2f}s "
-              f"({t_cold_single/t_cold_grouped:4.1f}x) "
-              f"warm: {t_grouped*1e3:6.1f}ms vs {t_single*1e3:6.1f}ms "
-              f"model_pick={model['pick']} "
-              f"oracle={oracle_ok} gate={gate_ok}", flush=True)
-    rec["suite_speedup_min"] = min(a["suite_speedup"]
-                                   for a in rec["archs"].values())
-    rec["pass_gate"] = (rec["suite_speedup_min"] > 1.0
-                        and all(a["equiv_gate_ok"] and a["oracle_match"]
-                                for a in rec["archs"].values())
-                        and all(a["n_groups"] <= 4
-                                for a in rec["archs"].values()))
-    # read-merge: other recorders (grouping-delta) share this file —
-    # a forced re-run must not drop their keys
-    merged = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            merged = json.load(f)
-    merged.update(rec)
-    with open(path, "w") as f:
-        json.dump(merged, f, indent=1)
-    return merged
-
-
-def record_grouping_delta(arch_name: str = "baseline") -> dict:
-    """Satellite record: the value-buffer padded-row delta from the
-    size-aware grouping term in ``group_plans_by_envelope`` (volume-only
-    cost vs volume + signal-count cost), on the re-elaborated 17-circuit
-    suite.  Appended to ``suite_eval_grouped.json`` under
-    ``size_aware_grouping``."""
-    from repro.core.alm import ARCHS
-    from repro.core.equiv import reelaborate
-    from repro.core.eval_jax import (group_plans_by_envelope,
-                                     grouping_padded_value_rows,
-                                     plan_netlist)
-    from repro.core.packing import pack as pack_fn
-
-    from .common import suites
-
-    nets = [net for nets_ in suites("wallace").values() for net in nets_]
-    phys = [reelaborate(pack_fn(net, ARCHS[arch_name], seed=0)).phys
-            for net in nets]
-    plans = [plan_netlist(p) for p in phys]
-
-    def plan_volume(groups):
-        tot = 0
-        for g in groups:
-            env = [0, 0, 0, 0]
-            for i in g:
-                env = [max(a, b) for a, b in zip(env, plans[i].envelope)]
-            L, M, C, B = env
-            tot += len(g) * L * (M + C * B)
-        return tot
-
-    g_vol = group_plans_by_envelope(plans, signal_weight=0.0)
-    g_size = group_plans_by_envelope(plans)
-    rows_vol = grouping_padded_value_rows(plans, g_vol)
-    rows_size = grouping_padded_value_rows(plans, g_size)
-    rec = {
-        "arch": arch_name,
-        "n_circuits": len(nets),
-        "groups_volume_only": g_vol,
-        "groups_size_aware": g_size,
-        "value_rows_real": rows_vol["real_rows"],
-        "value_rows_volume_only": rows_vol["padded_rows"],
-        "value_rows_size_aware": rows_size["padded_rows"],
-        "value_rows_delta": rows_vol["padded_rows"] - rows_size["padded_rows"],
-        "plan_volume_volume_only": plan_volume(g_vol),
-        "plan_volume_size_aware": plan_volume(g_size),
-    }
-    os.makedirs(OUT, exist_ok=True)
-    path = os.path.join(OUT, "suite_eval_grouped.json")
-    data = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            data = json.load(f)
-    data["size_aware_grouping"] = rec
-    with open(path, "w") as f:
-        json.dump(data, f, indent=1)
-    print(f"grouping_delta[{arch_name}] value rows: real="
-          f"{rec['value_rows_real']} volume-only="
-          f"{rec['value_rows_volume_only']} size-aware="
-          f"{rec['value_rows_size_aware']} "
-          f"(delta {rec['value_rows_delta']}); plan volume "
-          f"{rec['plan_volume_volume_only']} -> "
-          f"{rec['plan_volume_size_aware']}", flush=True)
-    return rec
 
 
 def main():
@@ -388,19 +117,6 @@ def main():
     b4 = run_variant("gemma2_prefill_base", "gemma2-2b", "prefill_32k")
     show(b4)
 
-    print("== cell 4: netlist eval — fused single-jit vs per-level ==")
-    run_netlist_eval_cell()
-
-    print("== cell 5: suite eval — envelope-grouped vs per-circuit ==")
-    run_suite_eval_cell()
-
 
 if __name__ == "__main__":
-    if "netlist-eval" in sys.argv[1:]:
-        run_netlist_eval_cell(force="force" in sys.argv[1:])
-    elif "suite-eval" in sys.argv[1:]:
-        run_suite_eval_cell(force="force" in sys.argv[1:])
-    elif "grouping-delta" in sys.argv[1:]:
-        record_grouping_delta()
-    else:
-        main()
+    main()

@@ -70,7 +70,7 @@ def _count_compiles() -> None:
     jax.monitoring.register_event_listener(on_event)
 
 
-def _eval_programs_have_kernel(nets, stats, n_words: int) -> bool:
+def _eval_programs_have_kernel(nets, n_words: int) -> bool:
     """Re-lower the evaluation programs ``evaluate_suite`` just ran (same
     plans, same grouping) and check each carries the Mosaic kernel."""
     import jax
@@ -81,19 +81,11 @@ def _eval_programs_have_kernel(nets, stats, n_words: int) -> bool:
     def vals(*lead):
         return jax.ShapeDtypeStruct(lead + (n_words,), jnp.uint32)
 
-    texts = []
-    if stats["mode"] == "grouped":
-        prog = flow.prepare_suite(nets)
-        for members, g in zip(prog.groups, prog.programs):
-            texts.append(eval_jax._run_fused_batch.lower(
-                vals(len(members), g.n_sig + 1), g.stacked, flags=g.flags,
-                use_pallas=True).as_text())
-    else:
-        for net in nets:
-            plan = eval_jax.plan_netlist(net)
-            texts.append(eval_jax._run_fused.lower(
-                vals(plan.n_signals + 1), plan.device_arrays(),
-                flags=plan.flags, use_pallas=True).as_text())
+    prog = flow.prepare_suite(nets)
+    texts = [eval_jax._run_fused_batch.lower(
+        vals(len(members), g.n_sig + 1), g.stacked, flags=g.flags,
+        use_pallas=True).as_text()
+        for members, g in zip(prog.groups, prog.programs)]
     return all("tpu_custom_call" in t for t in texts)
 
 
@@ -110,9 +102,9 @@ def phase_eval(suites, seed: int, lane_words, on_chip: bool) -> tuple:
         for net, ln, v in zip(nets, lanes, outs):
             if flow.po_mismatches(net, ln, v, n_words):
                 bad.append(f"{net.name}@{n_words}w")
-        if on_chip and not _eval_programs_have_kernel(nets, stats, n_words):
+        if on_chip and not _eval_programs_have_kernel(nets, n_words):
             bad.append(f"no Mosaic kernel in the {n_words}-word programs")
-        shapes.append(f"{n_words}w:{stats['mode']}x{stats['n_groups']}")
+        shapes.append(f"{n_words}w:{stats['n_groups']}")
     luts = [n.n_luts for n in nets]
     detail = (f"circuits={len(nets)} luts={min(luts)}-{max(luts)} "
               f"programs={','.join(shapes)}")

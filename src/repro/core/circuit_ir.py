@@ -272,8 +272,8 @@ class CircuitIR:
 def levelize(net: Netlist):
     """Nodes grouped by topological level (a node's level is one past its
     deepest input).  Returns ``(by_luts, by_chains, sig_level)``.  The
-    single levelization of the stack — the evaluator, the timing lowering
-    and the seed per-level dispatcher all consume this."""
+    single levelization of the stack — the evaluator and the timing
+    lowering both consume this."""
     sig_level: dict[int, int] = {s: 0 for s in net.pis}
     sig_level[0] = 0
     sig_level[1] = 0

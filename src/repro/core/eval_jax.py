@@ -31,8 +31,10 @@ optimum:
 
 Suite-scale batched evaluation
 ------------------------------
-:func:`eval_netlists_batched_jax` evaluates many circuits per device
-program.  Plans are clustered by *compatible envelopes*
+Every combinational evaluation runs one kind of device program,
+:func:`_run_fused_batch`, prepared by :func:`prepare_suite_program` and
+run by :meth:`SuiteProgram.run`; a single circuit is a suite of one
+(:func:`eval_netlist_jax`).  Plans are clustered by *compatible envelopes*
 (:func:`repro.core.plan.group_by_envelope` — agglomerative merging on the
 padded plan volume plus a signal-count term), capped at ``max_groups``
 groups, so a whole benchmark suite compiles into a handful of vmapped jit
@@ -53,10 +55,8 @@ registers' rows as the carry (:func:`run_sequential`).
 The value buffer is built on the device (:func:`_device_vals`): only the
 primary inputs' lanes cross the host link, and the buffer is donated to
 the evaluation jit (``donate_argnums``), which reuses it in place.  The
-seed per-level dispatcher (one kernel launch per level from a Python loop)
-survives as :func:`eval_netlist_jax_levels` — the baseline the perf
-trajectory measures against — and the Python ``eval_netlist`` oracle in
-``netlist.py`` stays the ground truth in tests.
+Python ``eval_netlist`` oracle in ``netlist.py`` stays the ground truth
+in tests.
 """
 from __future__ import annotations
 
@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 
 from . import plan as _planner
-from .circuit_ir import CircuitIR, levelize, lower_netlist_ir
+from .circuit_ir import CircuitIR, lower_netlist_ir
 from .netlist import CONST0, CONST1, Netlist
 from .plan import segment_levels
 from .spans import span
@@ -80,62 +80,6 @@ DEFAULT_MAX_GROUPS = 4
 
 _PLAN_CACHE = _planner.register_cache("eval_plans", cap=64)
 _GROUP_CACHE = _planner.register_cache("eval_groups", cap=16)
-
-#: shape signatures of evaluation programs that have actually *run* (hence
-#: compiled) in this process — the ground truth behind the cost model's
-#: ``warm="auto"`` derivation (:func:`repro.core.flow.eval_mode_cost_model`).
-#: jax keys its jit cache by argument shapes + static args, so the
-#: signature is shape-based too (:func:`program_signature`): two circuits
-#: whose plans pad to identical bucket envelopes share one compile, and
-#: the marker honestly reports both warm.
-_COMPILED_CACHE = _planner.register_cache("eval_compiled", cap=2048)
-
-
-def program_signature(plan: FusedPlan, n_lane_words: int,
-                      use_pallas: bool, batch: int | None = None) -> tuple:
-    """The jit-cache identity of one evaluation program: per-bucket
-    static flags + padded bucket shapes + value-buffer height + lane
-    words (+ the vmap batch size for grouped programs, ``None`` for
-    single-circuit ones).  Everything jax's compile cache keys on."""
-    return (plan.flags, tuple(bk.shape for bk in plan.buckets),
-            plan.n_signals,
-            None if n_lane_words is None else int(n_lane_words),
-            bool(use_pallas), batch)
-
-
-def layout_program_signature(layout: dict, n_signals: int,
-                             n_lane_words: int | None, use_pallas: bool,
-                             batch: int | None) -> tuple:
-    """:func:`program_signature` derived from a :func:`group_layout`
-    record alone — no plan tensors built.  Mirrors ``_bucket_from_ir``'s
-    padding floors (``max(dim, 1)``) and per-bucket flags, which a test
-    pins against the signature an actual run records."""
-    flags = tuple((M > 0, C > 0) for (M, C, B) in layout["envelopes"])
-    shapes = tuple((max(j - i, 1), max(M, 1), max(C, 1), max(B, 1))
-                   for (i, j), (M, C, B) in zip(layout["bounds"],
-                                                layout["envelopes"]))
-    return (flags, shapes, n_signals,
-            None if n_lane_words is None else int(n_lane_words),
-            bool(use_pallas), batch)
-
-
-def mark_program_run(sig: tuple) -> None:
-    """Record that the program with signature ``sig`` has executed (its
-    compile is cached).  Called by both evaluation paths after a run."""
-    _COMPILED_CACHE.put(sig, True)
-
-
-def program_seen(sig: tuple) -> bool:
-    """Has a program with this signature run in this process?  With
-    ``n_lane_words`` (position 3) set to ``None`` the lane-word count is
-    a wildcard — for cost-model callers that don't know the lane shape
-    yet (a compile at any lane count proves the plan shapes were built
-    and the program dispatched at least once)."""
-    if sig[3] is not None:
-        return sig in _COMPILED_CACHE
-    probe = sig[:3] + sig[4:]
-    return any(k[:3] + k[4:] == probe for k in _COMPILED_CACHE.keys())
-
 
 def netlist_digest(net: Netlist) -> str:
     """Content digest of a netlist's structure (the plan-cache key) —
@@ -206,7 +150,6 @@ class FusedPlan:
     buckets: tuple[PlanBucket, ...]
     real_luts: int = 0
     real_chain_bits: int = 0
-    _dev: tuple | None = field(default=None, repr=False)
 
     @property
     def sink(self) -> int:
@@ -248,14 +191,6 @@ class FusedPlan:
 
     def arrays(self):
         return tuple(bk.arrays() for bk in self.buckets)
-
-    def device_arrays(self):
-        """Plan tensors as device arrays, uploaded once per plan — reusing
-        a plan across calls must not re-transfer megabytes of indices."""
-        if self._dev is None:
-            self._dev = tuple(tuple(jnp.asarray(a) for a in bk)
-                              for bk in self.arrays())
-        return self._dev
 
 
 def _bucket_from_ir(ir: CircuitIR, i: int, j: int, M: int, C: int, B: int,
@@ -436,12 +371,6 @@ def _multi_scan(vals, bucket_arrays, flags, use_pallas, body=_fused_body):
 
 @functools.partial(jax.jit, donate_argnums=(0,),
                    static_argnames=("flags", "use_pallas"))
-def _run_fused(vals, bucket_arrays, *, flags, use_pallas):
-    return _multi_scan(vals, bucket_arrays, flags, use_pallas)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("flags", "use_pallas"))
 def _run_fused_batch(vals, bucket_arrays, *, flags, use_pallas):
     return jax.vmap(
         lambda v, arrs: _multi_scan(v, arrs, flags, use_pallas)
@@ -503,30 +432,6 @@ def _put(rows: np.ndarray) -> jax.Array:
         return jnp.asarray(rows).block_until_ready()
 
 
-def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
-                     n_lane_words: int, use_pallas: bool = True,
-                     plan: FusedPlan | None = None) -> jax.Array:
-    """Fused evaluation; returns ``vals[n_signals, n_lane_words]`` uint32.
-
-    ``pi_lanes[signal]`` is a uint32 vector of packed test vectors, keyed
-    by primary inputs of ``net`` (any other key raises ``ValueError``; a
-    missing input reads 0).  Pass a precompiled ``plan`` to skip the
-    content-digest cache lookup (the jit cache amortizes compilation by
-    shape either way).
-    """
-    if plan is None:
-        plan = plan_netlist(net)
-    rows = _put(_fill_pi_rows([_pi_slots(net)], [pi_lanes],
-                              len(net.sources), n_lane_words)[0])
-    with span("repro.eval.run"):
-        vals = _device_vals(np.asarray(net.sources, dtype=np.int32), rows,
-                            n_rows=plan.n_signals + 1)
-        out = _run_fused(vals, plan.device_arrays(), flags=plan.flags,
-                         use_pallas=use_pallas).block_until_ready()
-    mark_program_run(program_signature(plan, n_lane_words, use_pallas))
-    return out[:plan.n_signals]
-
-
 # ---------------------------------------------------------------------------
 # envelope-grouped suite evaluation
 # ---------------------------------------------------------------------------
@@ -552,23 +457,6 @@ def grouping_padded_value_rows(plans, groups: list[list[int]]) -> dict:
             "waste": 1.0 - real / max(padded, 1)}
 
 
-def group_layout(irs, max_buckets: int = DEFAULT_MAX_BUCKETS):
-    """Shared padded layout of one envelope group: combined width profile,
-    bucket bounds, envelopes and the per-member padded row volume.  Used
-    by the group builder below and by the flow-level grouped-vs-
-    per-circuit cost model (:func:`repro.core.flow.eval_mode_cost_model`)
-    without building any device tensors."""
-    L = max((ir.n_levels for ir in irs), default=0)
-    if L == 0:
-        L = 1
-    m, c, b = _planner.combined_profile([ir.level_profile() for ir in irs],
-                                        L)
-    bounds = segment_levels(m, c, b, max_buckets)
-    envelopes = _planner.bucket_envelopes(m, c, b, bounds)
-    return {"bounds": bounds, "envelopes": envelopes,
-            "rows_per_member": _planner.padded_rows(bounds, envelopes)}
-
-
 class GroupProgram(NamedTuple):
     """One envelope group's cached device program inputs."""
 
@@ -591,8 +479,11 @@ def _build_group(nets: list[Netlist], max_buckets: int) -> GroupProgram:
     """
     irs = [lower_netlist_ir(net) for net in nets]
     n_sig = max(net.n_signals for net in nets)
-    layout = group_layout(irs, max_buckets=max_buckets)
-    bounds, envelopes = layout["bounds"], layout["envelopes"]
+    L = max(max(ir.n_levels for ir in irs), 1)
+    m, c, b = _planner.combined_profile([ir.level_profile() for ir in irs],
+                                        L)
+    bounds = segment_levels(m, c, b, max_buckets)
+    envelopes = _planner.bucket_envelopes(m, c, b, bounds)
     member_plans = [
         plan_from_ir(ir, n_signals=n_sig, bounds=bounds,
                      envelopes=envelopes)
@@ -661,11 +552,6 @@ class SuiteProgram:
             with span("repro.eval.get") as sp:
                 out = np.asarray(out)
                 sp.set(bytes=out.nbytes)
-            # all members share the group layout, so member 0's plan IS
-            # the group's program shape signature
-            mark_program_run(program_signature(
-                g.member_plans[0], n_lane_words, use_pallas,
-                batch=len(members)))
             for row, i in enumerate(members):
                 outs[i] = out[row, :self.n_signals[i]]
         return outs
@@ -673,18 +559,12 @@ class SuiteProgram:
 
 def prepare_suite_program(nets: list[Netlist],
                           max_groups: int = DEFAULT_MAX_GROUPS,
-                          max_buckets: int = DEFAULT_MAX_BUCKETS,
-                          plans: list[FusedPlan] | None = None,
-                          groups: list[list[int]] | None = None
+                          max_buckets: int = DEFAULT_MAX_BUCKETS
                           ) -> SuiteProgram:
     """Cluster a suite into <= ``max_groups`` compatible-envelope groups and
-    build (or fetch from the content cache) each group's stacked tensors.
-    Pass precomputed ``plans``/``groups`` (e.g. from a cost-model pass) to
-    skip re-planning and the O(n^2) agglomerative clustering."""
-    if plans is None:
-        plans = [plan_netlist(net, max_buckets=max_buckets) for net in nets]
-    if groups is None:
-        groups = group_plans_by_envelope(plans, max_groups=max_groups)
+    build (or fetch from the content cache) each group's stacked tensors."""
+    plans = [plan_netlist(net, max_buckets=max_buckets) for net in nets]
+    groups = group_plans_by_envelope(plans, max_groups=max_groups)
     programs = [get_group_program([nets[i] for i in members],
                                   max_buckets=max_buckets)
                 for members in groups]
@@ -703,31 +583,19 @@ def prepare_suite_program(nets: list[Netlist],
                         groups=groups, programs=programs, stats=stats)
 
 
-def eval_netlists_batched_jax(nets: list[Netlist],
-                              pi_lanes_list: list[dict[int, np.ndarray]],
-                              n_lane_words: int,
-                              use_pallas: bool = True,
-                              max_groups: int = DEFAULT_MAX_GROUPS,
-                              max_buckets: int = DEFAULT_MAX_BUCKETS,
-                              return_stats: bool = False,
-                              program: SuiteProgram | None = None):
-    """Evaluate a suite of circuits as a few vmapped jit programs.
+def eval_netlist_jax(net: Netlist, pi_lanes: dict[int, np.ndarray],
+                     n_lane_words: int,
+                     use_pallas: bool = True) -> np.ndarray:
+    """One circuit, evaluated as a suite of one; returns its value buffer
+    ``vals[n_signals, n_lane_words]`` uint32.
 
-    Plans are clustered into <= ``max_groups`` envelope groups (one compile
-    per group) and each group's members are padded to the group's bucketed
-    envelope.  ``max_groups=1, max_buckets=1`` reproduces the old
-    single-worst-case-envelope path exactly.  Pass a prepared ``program``
-    to skip clustering/digesting in hot loops.  Returns per-circuit
-    ``vals`` arrays in input order (plus a stats record when
-    ``return_stats``).
+    ``pi_lanes[signal]`` is a uint32 vector of packed test vectors, keyed
+    by sources of ``net`` (its primary inputs, and any register's Q, which
+    reads as a free input); any other key raises ``ValueError``, a missing
+    source reads 0.
     """
-    if program is None:
-        program = prepare_suite_program(nets, max_groups=max_groups,
-                                        max_buckets=max_buckets)
-    outs = program.run(pi_lanes_list, n_lane_words, use_pallas=use_pallas)
-    if return_stats:
-        return outs, program.stats
-    return outs
+    return prepare_suite_program([net]).run(
+        [pi_lanes], n_lane_words, use_pallas=use_pallas)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -853,9 +721,9 @@ def _run_seq(pi_stream, d_idx, po_idx, bucket_arrays, *, flags, use_pallas,
     buffer is carried, not rebuilt.  Every register starts at 0.
     ``pi_stream`` is ``[T, P, N]``; returns ``[T, O, N]``.
 
-    The combinational programs (:func:`_run_fused`,
-    :func:`_run_fused_batch`) return the whole buffer by signal id, so
-    their levels scatter instead (:func:`_fused_body`)."""
+    The combinational program (:func:`_run_fused_batch`) returns the
+    whole buffer by signal id, so its levels scatter instead
+    (:func:`_fused_body`)."""
     n = pi_stream.shape[-1]
     consts = jnp.broadcast_to(
         jnp.array([[0], [0xFFFFFFFF]], dtype=jnp.uint32), (2, n))
@@ -898,75 +766,3 @@ def run_sequential(prog: SeqProgram, pi_stream: np.ndarray,
         out = np.asarray(out)
         sp.set(bytes=out.nbytes)
     return out
-
-
-# ---------------------------------------------------------------------------
-# seed per-level dispatcher (perf baseline)
-# ---------------------------------------------------------------------------
-
-
-def eval_netlist_jax_levels(net: Netlist, pi_lanes: dict[int, np.ndarray],
-                            n_lane_words: int,
-                            use_pallas: bool = True) -> jax.Array:
-    """The pre-fusion evaluator: one Python-dispatched kernel call per LUT
-    level and one ``lax.scan`` per chain.  Kept as the measured baseline
-    for the fused engine's speedup (see ``benchmarks/perf_iterations.py``).
-    """
-    from repro.kernels import ops
-
-    by_luts, by_chains, _ = levelize(net)
-    levels = sorted(set(by_luts) | set(by_chains))
-
-    vals = jnp.zeros((net.n_signals, n_lane_words), dtype=jnp.uint32)
-    vals = vals.at[CONST1].set(jnp.uint32(0xFFFFFFFF))
-    for s, v in pi_lanes.items():
-        vals = vals.at[s].set(jnp.asarray(v, dtype=jnp.uint32))
-
-    for lv in levels:
-        ids = by_luts.get(lv)
-        if ids:
-            kmax = max(1, max(len(net.lut_inputs[i]) for i in ids))
-            ins = np.zeros((len(ids), kmax), dtype=np.int64)
-            tts = np.zeros(len(ids), dtype=np.uint64)
-            outs = np.zeros(len(ids), dtype=np.int64)
-            for r, i in enumerate(ids):
-                sig_ins = net.lut_inputs[i]
-                k = len(sig_ins)
-                ins[r, :k] = sig_ins
-                tt = net.lut_tt[i]
-                full = 0
-                for rr in range(1 << (kmax - k)):
-                    full |= tt << (rr * (1 << k))
-                tts[r] = full & ((1 << min(64, 1 << kmax)) - 1)
-                outs[r] = net.lut_out[i]
-            gathered = vals[jnp.asarray(ins)]
-            if kmax <= 5:
-                out = ops.lut_eval(gathered, jnp.asarray(
-                    tts.astype(np.uint32)), use_pallas=use_pallas)
-            else:
-                tt_lo = jnp.asarray((tts & np.uint64(0xFFFFFFFF))
-                                    .astype(np.uint32))
-                tt_hi = jnp.asarray((tts >> np.uint64(32)).astype(np.uint32))
-                g5 = gathered[:, :5, :]
-                sel = gathered[:, 5, :]
-                lo = ops.lut_eval(g5, tt_lo, use_pallas=use_pallas)
-                hi = ops.lut_eval(g5, tt_hi, use_pallas=use_pallas)
-                out = (sel & hi) | (~sel & lo)
-            vals = vals.at[jnp.asarray(outs)].set(out)
-        for c in by_chains.get(lv, ()):
-            ch = net.chains[c]
-            av = vals[jnp.asarray(np.array(ch.a))]
-            bv = vals[jnp.asarray(np.array(ch.b))]
-            c0 = vals[ch.cin]
-
-            def step(c_, ab):
-                aa, bb = ab
-                s = aa ^ bb ^ c_
-                cy = (aa & bb) | (c_ & (aa ^ bb))
-                return cy, s
-
-            clast, ss = jax.lax.scan(step, c0, (av, bv))
-            vals = vals.at[jnp.asarray(np.array(ch.sums))].set(ss)
-            if ch.cout is not None:
-                vals = vals.at[ch.cout].set(clast)
-    return vals

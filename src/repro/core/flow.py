@@ -23,12 +23,13 @@ The stages:
   equivalence per arch through :mod:`repro.core.equiv` (symbolic fast
   path first, lane simulation as fallback), so any figure can be gated on
   "the comparison is apples-to-apples".
-* **evaluation** — :func:`evaluate_netlist` / :func:`evaluate_suite` run
-  the width-bucketed fused engine (:mod:`repro.core.eval_jax`).
-  :func:`evaluate_suite` clusters a whole benchmark suite into a few
-  compatible-envelope groups, so Kratos + Koios + VTR evaluate per arch
-  as a handful of vmapped jit programs; plans and grouped tensors are
-  content-cached, so repeated figures reuse compiles.
+* **evaluation** — :func:`evaluate_suite` runs the width-bucketed fused
+  engine (:mod:`repro.core.eval_jax`): it clusters a whole benchmark
+  suite into a few compatible-envelope groups, so Kratos + Koios + VTR
+  evaluate per arch as a handful of vmapped jit programs; plans and
+  grouped tensors are content-cached, so repeated figures reuse
+  compiles.  A single circuit is a suite of one
+  (:func:`repro.core.eval_jax.eval_netlist_jax`).
 * **cycle simulation** — :func:`evaluate_sequential` steps registered
   netlists over clock cycles on the device: one jitted scan over cycles
   per netlist, the fused bucket program its step (on a private row
@@ -51,10 +52,9 @@ import numpy as np
 
 from .alm import ARCHS, ArchParams
 from .equiv import check_pack_equivalence
-from .eval_jax import (DEFAULT_MAX_BUCKETS, DEFAULT_MAX_GROUPS, FusedPlan,
-                       SuiteProgram, eval_netlist_jax,
-                       eval_netlists_batched_jax, plan_netlist,
-                       prepare_suite_program, run_sequential, seq_program)
+from .eval_jax import (DEFAULT_MAX_BUCKETS, DEFAULT_MAX_GROUPS,
+                       SuiteProgram, prepare_suite_program, run_sequential,
+                       seq_program)
 from .netlist import Netlist, eval_netlist
 from .packing import PackedCircuit, pack
 from .spans import span
@@ -262,25 +262,6 @@ def random_lanes(net: Netlist, n_lane_words: int,
                         dtype=np.uint32) for s in net.pis}
 
 
-def evaluate_netlist(net: Netlist, pi_lanes: dict[int, np.ndarray],
-                     n_lane_words: int, use_pallas: bool = True,
-                     max_buckets: int = DEFAULT_MAX_BUCKETS,
-                     plan: FusedPlan | None = None) -> np.ndarray:
-    """Single-circuit fused evaluation through the cached bucketed plan.
-
-    Pass a precomputed ``plan`` in timing loops — it skips even the
-    content-digest cache lookup.
-    """
-    if plan is None:
-        plan = plan_netlist(net, max_buckets=max_buckets)
-    out = eval_netlist_jax(net, pi_lanes, n_lane_words,
-                           use_pallas=use_pallas, plan=plan)
-    with span("repro.eval.get") as sp:
-        out = np.asarray(out)
-        sp.set(bytes=out.nbytes)
-    return out
-
-
 def prepare_suite(nets: list[Netlist],
                   max_groups: int = DEFAULT_MAX_GROUPS,
                   max_buckets: int = DEFAULT_MAX_BUCKETS) -> SuiteProgram:
@@ -290,211 +271,32 @@ def prepare_suite(nets: list[Netlist],
                                  max_buckets=max_buckets)
 
 
-#: padded-row-equivalents charged per program dispatch in the warm-path
-#: cost model below — a program launch (value-buffer init + PI fill,
-#: argument pytree flattening, dispatch, blocking result sync) costs
-#: roughly what streaming this many padded rows through a scan step does.
-#: Calibration: back-solving the measured warm walls of the 17-circuit
-#: suite (``experiments/perf/suite_eval_grouped.json``) across two
-#: recordings gives an implied dispatch cost anywhere from ~3k to ~20k
-#: rows — the two paths sit within the host's run-to-run noise band and
-#: the measured winner flips between recordings.  The constant is set at
-#: the low end of that bracket deliberately: when the margin is inside
-#: noise, a serial host should prefer the padding-free per-circuit
-#: layout, and grouped should win only when envelope compatibility makes
-#: the padding small relative to the saved dispatches.
-EVAL_DISPATCH_ROW_COST = 4096
-
-#: padded-row-equivalents charged per program COMPILE when the program's
-#: shape signature has not run yet (``warm="auto"``, the default — see
-#: :func:`repro.core.eval_jax.program_seen`) or the caller forces
-#: ``warm=False``: the
-#: recorded cold suite walls (``suite_eval_grouped.json``:
-#: ``t_suite_per_circuit_s`` - ``t_suite_grouped_s`` over the compile-
-#: count delta) imply ~3-4 s per program compile, ~10^7 rows at the
-#: measured ~0.25 us/row.  This is what makes one-shot cold callers pick
-#: grouped (few compiles) exactly as the pre-cost-model default did.
-EVAL_COMPILE_ROW_COST = 1 << 24
-
-
-def eval_mode_cost_model(nets: list[Netlist], plans=None, groups=None,
-                         max_groups: int = DEFAULT_MAX_GROUPS,
-                         max_buckets: int = DEFAULT_MAX_BUCKETS,
-                         backend: str | None = None,
-                         warm: bool | str = "auto",
-                         n_lane_words: int | None = None,
-                         use_pallas: bool = True) -> dict:
-    """Backend-aware cost model: grouped vs per-circuit eval.
-
-    Grouped evaluation trades program count (one compile + one dispatch
-    per envelope group instead of one per circuit) for padded volume
-    (every member pads to the group envelope).  On a serial host backend
-    (``cpu``) the vmapped group axis executes sequentially, so the model
-    charges the full ``rows_per_member * len(group)``; on parallel
-    backends (``gpu``/``tpu``) the group axis maps to real parallelism
-    and a group costs one member's padded rows.  Both sides are charged
-    :data:`EVAL_DISPATCH_ROW_COST` rows per program, plus
-    :data:`EVAL_COMPILE_ROW_COST` per program that is not yet compiled.
-
-    Warmness is no longer caller-asserted: the default ``warm="auto"``
-    derives it *per program* from the registry's record of programs that
-    have actually run (:func:`repro.core.eval_jax.program_seen`, shape
-    signatures matching jax's own jit keying) — on a mixed batch two
-    circuits already served stay cheap while a new envelope is charged
-    its compile, which the old all-or-nothing flag got wrong in both
-    directions.  ``warm=True`` / ``False`` remain as forced overrides
-    (benchmark loops that just cleared the jax cache, tests).
-    ``n_lane_words`` sharpens the auto derivation (compiles are
-    per-lane-shape); when unknown, a program compiled at any lane count
-    counts as warm.  All row terms come from the unified
-    :class:`~repro.core.circuit_ir.CircuitIR` profiles — no device
-    tensors are built.  (ROADMAP "warm-path grouped eval" item.)
-    """
-    from .circuit_ir import lower_netlist_ir
-    from .eval_jax import (group_layout, group_plans_by_envelope,
-                           layout_program_signature, program_seen,
-                           program_signature)
-
-    if warm not in (True, False, "auto"):
-        raise ValueError(f"warm must be True, False or 'auto': {warm!r}")
-    if plans is None:
-        plans = [plan_netlist(n, max_buckets=max_buckets) for n in nets]
-    if groups is None:
-        groups = group_plans_by_envelope(plans, max_groups=max_groups)
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    parallel = backend in ("gpu", "tpu")
-
-    def compile_cost(sig) -> int:
-        if warm is True:
-            return 0
-        if warm is False:
-            return EVAL_COMPILE_ROW_COST
-        return 0 if program_seen(sig) else EVAL_COMPILE_ROW_COST
-
-    irs = [lower_netlist_ir(n) for n in nets]
-    single_rows = sum(p.padded_lut_rows + p.padded_chain_bits for p in plans)
-    compile_single = sum(
-        compile_cost(program_signature(p, n_lane_words, use_pallas))
-        for p in plans)
-    grouped_rows = 0
-    compile_grouped = 0
-    for g in groups:
-        layout = group_layout([irs[i] for i in g], max_buckets=max_buckets)
-        grouped_rows += layout["rows_per_member"] * (1 if parallel
-                                                     else len(g))
-        compile_grouped += compile_cost(layout_program_signature(
-            layout, max(irs[i].n_signals for i in g), n_lane_words,
-            use_pallas, len(g)))
-    dispatch = EVAL_DISPATCH_ROW_COST
-    cost_grouped = grouped_rows + dispatch * len(groups) + compile_grouped
-    cost_single = single_rows + dispatch * len(nets) + compile_single
-    return {
-        "backend": backend,
-        "parallel": parallel,
-        "warm": warm,
-        "n_programs_grouped": len(groups),
-        "n_programs_per_circuit": len(nets),
-        "n_cold_programs_grouped": compile_grouped // EVAL_COMPILE_ROW_COST,
-        "n_cold_programs_per_circuit": (compile_single
-                                        // EVAL_COMPILE_ROW_COST),
-        "padded_rows_grouped": int(grouped_rows),
-        "padded_rows_per_circuit": int(single_rows),
-        "dispatch_row_cost": EVAL_DISPATCH_ROW_COST,
-        "compile_row_cost": EVAL_COMPILE_ROW_COST,
-        "compile_rows_grouped": int(compile_grouped),
-        "compile_rows_per_circuit": int(compile_single),
-        "cost_grouped": int(cost_grouped),
-        "cost_per_circuit": int(cost_single),
-        "pick": "grouped" if cost_grouped <= cost_single else "per_circuit",
-    }
-
-
 def evaluate_suite(nets: list[Netlist],
                    pi_lanes_list: list[dict[int, np.ndarray]],
                    n_lane_words: int, use_pallas: bool = True,
                    max_groups: int = DEFAULT_MAX_GROUPS,
                    max_buckets: int = DEFAULT_MAX_BUCKETS,
-                   program: SuiteProgram | None = None,
-                   mode: str = "auto",
-                   warm: bool | str = "auto") -> tuple[list[np.ndarray],
-                                                       dict]:
-    """Whole-suite evaluation as <= ``max_groups`` vmapped jit programs —
-    or per-circuit fused programs, whichever the backend-aware cost model
-    predicts cheaper (``mode="auto"``; force with ``"grouped"`` /
-    ``"per_circuit"``; a prepared ``program`` implies grouped).
-    ``warm="auto"`` (default) derives each candidate program's compile
-    cost from whether its shape signature has actually run
-    (:func:`eval_mode_cost_model`); ``True``/``False`` force the old
-    all-warm / all-cold assumptions for benchmark loops that know
-    better (e.g. right after ``jax.clear_caches()``).
+                   program: SuiteProgram | None = None
+                   ) -> tuple[list[np.ndarray], dict]:
+    """Whole-suite evaluation as <= ``max_groups`` vmapped jit programs:
+    the circuits' plans are clustered into compatible-envelope groups
+    (:func:`repro.core.eval_jax.group_plans_by_envelope`) and each group's
+    members are padded to its bucketed envelope.  Pass a ``program`` from
+    :func:`prepare_suite` to skip planning and clustering.
 
-    Returns ``(per-circuit vals arrays, stats)`` where stats records the
-    envelope groups, their bucket shapes, padded-row counts, the chosen
-    ``mode`` and (in auto) the ``cost_model`` record — both paths are
-    bit-identical, so the choice is purely a throughput matter.
+    Returns ``(per-circuit vals arrays in input order, stats)``: stats
+    records the envelope groups, their bucket shapes and padded-row
+    counts, and ``mode``, always ``"grouped"``.  The benchmark's eval cell
+    reads ``mode``, ``n_groups`` and each group's ``padded_lut_rows``.
     """
     with span("repro.eval.call", circuits=len(nets),
               lane_words=int(n_lane_words)):
         with span("repro.eval.plan"):
-            plans, groups, model, chosen = _plan_suite(
-                nets, program, mode, max_groups, max_buckets, warm,
-                n_lane_words, use_pallas)
-            if chosen == "grouped" and program is None:
-                program = prepare_suite_program(
-                    nets, max_buckets=max_buckets, plans=plans,
-                    groups=groups)
-        if chosen == "grouped":
-            outs, stats = eval_netlists_batched_jax(
-                nets, pi_lanes_list, n_lane_words, use_pallas=use_pallas,
-                return_stats=True, program=program)
-            stats = dict(stats)
-        else:
-            outs = [evaluate_netlist(n, ln, n_lane_words,
-                                     use_pallas=use_pallas, plan=pl)
-                    for n, ln, pl in zip(nets, pi_lanes_list, plans)]
-            # the per-circuit path runs one program per circuit — report
-            # that as the group count regardless of how this branch was
-            # reached (the cost model's candidate clustering, when auto
-            # computed one, is in stats["cost_model"])
-            stats = {"n_groups": len(nets), "groups": [],
-                     "n_programs": len(nets)}
-        stats["mode"] = chosen
-        if model is not None:
-            stats["cost_model"] = model
-    return outs, stats
-
-
-def _plan_suite(nets, program, mode, max_groups, max_buckets, warm,
-                n_lane_words, use_pallas):
-    """:func:`evaluate_suite`'s choice of path: ``(plans, groups,
-    model, chosen)`` — the envelope groups when a branch needs them, the
-    cost model's record in auto."""
-    if program is not None:
-        return None, None, None, "grouped"
-    if mode not in ("auto", "grouped", "per_circuit"):
-        raise ValueError(f"unknown evaluate_suite mode {mode!r}")
-    from .eval_jax import group_plans_by_envelope
-
-    # plans are registry-cached; the O(n^2) agglomerative grouping runs
-    # at most ONCE and only when a branch actually needs it (a forced
-    # per-circuit call never pays for clustering)
-    plans = [plan_netlist(n, max_buckets=max_buckets) for n in nets]
-    model = None
-    chosen = mode
-    groups = None
-    if mode == "auto":
-        groups = group_plans_by_envelope(plans, max_groups=max_groups)
-        model = eval_mode_cost_model(nets, plans=plans, groups=groups,
-                                     max_buckets=max_buckets, warm=warm,
-                                     n_lane_words=n_lane_words,
-                                     use_pallas=use_pallas)
-        chosen = model["pick"]
-    if chosen == "grouped" and groups is None:
-        groups = group_plans_by_envelope(plans, max_groups=max_groups)
-    return plans, groups, model, chosen
+            if program is None:
+                program = prepare_suite_program(nets, max_groups=max_groups,
+                                                max_buckets=max_buckets)
+        outs = program.run(pi_lanes_list, n_lane_words, use_pallas=use_pallas)
+    return outs, dict(program.stats, mode="grouped")
 
 
 def evaluate_sequential(nets: list[Netlist], pi_streams: list[np.ndarray],

@@ -72,11 +72,10 @@ class Cache:
       entry, so the steady-state working set of a request mix survives
       one-off circuits streaming through;
     * **counters** — ``hits`` / ``misses`` / ``evictions`` accumulate per
-      cache and surface through :func:`cache_stats`; the flow server's
-      telemetry and the warm-path cost model both read them.
-      ``__contains__`` is a *probe* and deliberately does not count (or
-      refresh) — cost models may ask "would this hit?" without skewing
-      the stats they are about to report.
+      cache and surface through :func:`cache_stats`, which the flow
+      server's telemetry reads.  ``__contains__`` is a *probe* and
+      deliberately does not count (or refresh): a caller may ask "would
+      this hit?" without skewing the stats.
     """
 
     def __init__(self, name: str, cap: int):
@@ -212,9 +211,8 @@ def set_cache_cap(name: str, cap: int) -> Cache:
 def cache_stats() -> dict[str, dict]:
     """Per-cache telemetry: ``{name: {size, cap, hits, misses,
     evictions, hit_rate}}`` — the single surface the flow server's stats
-    endpoint, the warm-path cost model diagnostics and the cache tests
-    all read.  ``hit_rate`` is derived (``hits / (hits + misses)``; 0.0
-    before the first lookup)."""
+    endpoint and the cache tests read.  ``hit_rate`` is derived
+    (``hits / (hits + misses)``; 0.0 before the first lookup)."""
     return {name: c.stats() for name, c in _REGISTRY.items()}
 
 
@@ -286,13 +284,6 @@ def combined_profile(profiles, n_levels: int):
     c = [col(t, 1) for t in range(L)]
     b = [col(t, 2) for t in range(L)]
     return m, c, b
-
-
-def padded_rows(bounds, envelopes) -> int:
-    """Padded row volume of one segmented profile: ``sum_seg len(seg) *
-    (M + C * B)`` — the unit every planning cost model works in."""
-    return sum(max(j - i, 1) * (M + C * B)
-               for (i, j), (M, C, B) in zip(bounds, envelopes))
 
 
 # ---------------------------------------------------------------------------

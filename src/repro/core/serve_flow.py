@@ -20,8 +20,8 @@ from inference serving to CAD flows:
   evaluator's shared planner (:func:`repro.core.plan.group_by_envelope`)
   and each group runs as one jit program over the class's stacked
   delay-table rows; eval jobs run through
-  :func:`repro.core.flow.evaluate_suite` (``warm="auto"`` — compile
-  costs derived from what has actually run, never caller-asserted);
+  :func:`repro.core.flow.evaluate_suite`, one vmapped program per
+  envelope group;
 * **bounded multi-tenant caches** — every store is a registry LRU
   (:mod:`repro.core.plan`): packs keyed by *pack digest* (structure
   minus truth tables), timing records by (pack digest, arch, seed),
@@ -254,8 +254,7 @@ class FlowServer:
     def __init__(self, batch_window_s: float = 0.002, max_batch: int = 64,
                  timing_backend: str = "jax", max_buckets: int = 3,
                  max_groups: int = 4, use_pallas: bool = True,
-                 memoize: bool = True, eval_mode: str = "auto",
-                 eval_warm: bool | str = "auto",
+                 memoize: bool = True,
                  verify_deltas: bool = True,
                  pad_timing_shapes: bool = True):
         if timing_backend not in ("jax", "numpy"):
@@ -267,8 +266,6 @@ class FlowServer:
         self.max_groups = max_groups
         self.use_pallas = use_pallas
         self.memoize = memoize
-        self.eval_mode = eval_mode
-        self.eval_warm = eval_warm
         #: prove every structurally-delta-served pack: per-cluster
         #: symbolic proof scoped to the touched LBs on the incremental
         #: path, the full-circuit proof on fallbacks
@@ -708,8 +705,7 @@ class FlowServer:
             lanes_list = [tasks[k][1] for k in keys]
             vals_list, _stats = _flow.evaluate_suite(
                 nets, lanes_list, n_lane_words, use_pallas=self.use_pallas,
-                max_groups=self.max_groups, max_buckets=self.max_buckets,
-                mode=self.eval_mode, warm=self.eval_warm)
+                max_groups=self.max_groups, max_buckets=self.max_buckets)
             for key, net, vals in zip(keys, nets, vals_list):
                 po = {name: vals[np.asarray(bus, dtype=np.int64)]
                       for name, bus in net.pos.items()}

@@ -1,4 +1,4 @@
-"""Pallas kernels: bit-parallel k-LUT level evaluation.
+"""Pallas kernel: bit-parallel LUT level evaluation.
 
 The functional simulator (``core/eval_jax.py``) evaluates topological levels
 of LUTs over packed test-vector lanes.  Per LUT the output is a
@@ -11,18 +11,14 @@ XLA tiles the 1-D array differently from the block (e.g. ``T(1024)``
 against ``T(256)``).  ``M`` need not be a multiple of ``BM``: the last
 row tile is partial, its out-of-range rows are never written back.
 
-Two entry points:
-
-* :func:`lut_eval` — the legacy per-level kernel, K <= 5, one uint32 table
-  per LUT (kept for the per-level dispatcher and the kernel sweep tests).
-* :func:`lut_eval6` — the fused-evaluator kernel.  Levels are padded to a
-  uniform ``[M, 6, N]`` layout; the 64-entry table arrives as two uint32
-  words and pin 5 Shannon-selects between them, so the inner loop is the
-  same 32-minterm unroll as the 5-input case with one extra select.
+The kernel is :func:`lut_eval6`.  Levels are padded to a uniform
+``[M, 6, N]`` layout; the 64-entry table arrives as two uint32 words and
+pin 5 Shannon-selects between them, so the inner loop is a 32-minterm
+unroll over pins 0..4 with one extra select.  A LUT of ``k < 6`` inputs
+reads constant-0 lanes on its pins above ``k`` and replicates its table
+to fill the 64 entries.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,44 +26,6 @@ from jax.experimental import pallas as pl
 
 BLOCK_M = 256   # LUTs per tile
 BLOCK_N = 128   # lane words per tile
-
-
-def _kernel(tt_ref, in_ref, o_ref, *, k: int):
-    # tt_ref: [BM, 1] uint32; in_ref: [BM, k, BN] uint32; o_ref: [BM, BN]
-    tts = tt_ref[...]
-    ins = in_ref[...]
-    BM, _, BN = ins.shape
-    out = jnp.zeros((BM, BN), dtype=jnp.uint32)
-    full = jnp.uint32(0xFFFFFFFF)
-    for m in range(1 << k):  # unrolled: 2^k <= 32 minterms
-        bit = (tts >> jnp.uint32(m)) & jnp.uint32(1)
-        term = jnp.full((BM, BN), full, dtype=jnp.uint32)
-        for j in range(k):
-            lane = ins[:, j, :]
-            term = term & (lane if (m >> j) & 1 else ~lane)
-        out = out | (jnp.where(bit == 1, full, jnp.uint32(0)) & term)
-    o_ref[...] = out
-
-
-def lut_eval(inputs: jax.Array, tts: jax.Array,
-             interpret: bool = True) -> jax.Array:
-    """``inputs[M, K, N]`` uint32 lanes + ``tts[M]`` -> ``out[M, N]``."""
-    M, K, N = inputs.shape
-    assert K <= 5
-    bm = min(BLOCK_M, M)
-    bn = min(BLOCK_N, N)
-    grid = (pl.cdiv(M, bm), pl.cdiv(N, bn))
-    return pl.pallas_call(
-        functools.partial(_kernel, k=K),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, K, bn), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.uint32),
-        interpret=interpret,
-    )(tts.astype(jnp.uint32)[:, None], inputs.astype(jnp.uint32))
 
 
 def _kernel6(tt_ref, in_ref, o_ref):

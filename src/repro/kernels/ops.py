@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from . import ref
 from .bitplane_matmul import bitplane_matmul as _bitplane_pallas
 from .flash_attention import flash_attention as _flash_pallas
-from .lut_eval import lut_eval as _lut_pallas
 from .lut_eval import lut_eval6 as _lut6_pallas
 from .popcount_matmul import popcount_matmul as _popcount_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
@@ -36,13 +35,6 @@ def popcount_matmul(x_packed, w_packed, mode="and", k_bits=None,
                                 interpret=not _on_tpu())
     return ref.popcount_matmul_ref(x_packed, w_packed, mode=mode,
                                    k_bits=k_bits)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def lut_eval(inputs, tts, use_pallas=True):
-    if use_pallas:
-        return _lut_pallas(inputs, tts, interpret=not _on_tpu())
-    return ref.lut_eval_ref(inputs, tts)
 
 
 def lut_eval6(inputs, tt_lo, tt_hi, use_pallas=True):

@@ -85,19 +85,6 @@ def test_lut_eval6_compiles_for_v5e(one_chip, m, n_words):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("m", (257, 2330))
-def test_lut_eval_compiles_for_v5e(one_chip, m):
-    """The k <= 5 kernel shares the 2-D truth-table layout."""
-    from repro.kernels.lut_eval import lut_eval
-
-    compiled = jax.jit(
-        lambda ins, tts: lut_eval(ins, tts, interpret=False)
-    ).lower(jax.ShapeDtypeStruct((m, 5, 4), jnp.uint32, sharding=one_chip),
-            jax.ShapeDtypeStruct((m,), jnp.uint32, sharding=one_chip)
-            ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("n_words", (4, 128))
 def test_grouped_eval_compiles_for_v5e(one_chip, suite_nets, n_words,
                                        monkeypatch):

@@ -80,10 +80,14 @@ def test_one_lowering_serves_eval_and_timing(seed):
         if template is not None:
             _assert_same_ir(ir, lower_pack_ir_incremental(packed, template))
         template = ir
-        # (a) eval from the same IR object == python oracle
-        vals = np.asarray(eval_netlist_jax(net, lanes, 2,
-                                           plan=plan_from_ir(ir),
-                                           use_pallas=False))
+        # (a) the same IR object plans the evaluation the netlist gets,
+        # tensor for tensor, and that evaluation == python oracle
+        got, want = plan_from_ir(ir), plan_netlist(net)
+        assert got.flags == want.flags and got.n_signals == want.n_signals
+        for bk_got, bk_want in zip(got.arrays(), want.arrays()):
+            for x, y in zip(bk_got, bk_want):
+                assert np.array_equal(x, y), arch.name
+        vals = eval_netlist_jax(net, lanes, 2, use_pallas=False)
         assert flow.oracle_check(net, lanes, vals, 2), arch.name
         # (b) timing from the same IR object == python oracle, bit for bit
         want = analyze_oracle(packed)
@@ -262,35 +266,6 @@ def test_cone_extraction_pi_leaf_raises_keyerror():
     net.set_po_bus("po", [o])
     with pytest.raises(KeyError):
         _extract_cone_netlist(net, [o], [a, b])   # c is outside the cut
-
-
-def test_eval_mode_cost_model_and_forced_modes():
-    """The warm-path grouping heuristic: the model record carries both
-    sides' costs and a pick; forced grouped / per-circuit evaluation are
-    bit-identical to each other and to the oracle; auto stats record the
-    decision."""
-    nets = [random_netlist(s) for s in (3, 7)]
-    lanes = [flow.random_lanes(n, 1, seed=i) for i, n in enumerate(nets)]
-    model = flow.eval_mode_cost_model(nets)
-    assert model["pick"] in ("grouped", "per_circuit")
-    assert model["cost_grouped"] >= model["padded_rows_grouped"]
-    assert model["cost_per_circuit"] >= model["padded_rows_per_circuit"]
-    outs_g, stats_g = flow.evaluate_suite(nets, lanes, 1, mode="grouped",
-                                          use_pallas=False)
-    outs_p, stats_p = flow.evaluate_suite(nets, lanes, 1,
-                                          mode="per_circuit",
-                                          use_pallas=False)
-    assert stats_g["mode"] == "grouped" and stats_p["mode"] == "per_circuit"
-    for net, ln, g, p in zip(nets, lanes, outs_g, outs_p):
-        assert np.array_equal(g, p), net.name
-        assert flow.oracle_check(net, ln, g, 1)
-    outs_a, stats_a = flow.evaluate_suite(nets, lanes, 1, mode="auto",
-                                          use_pallas=False)
-    assert stats_a["mode"] == stats_a["cost_model"]["pick"]
-    for g, a in zip(outs_g, outs_a):
-        assert np.array_equal(g, a)
-    with pytest.raises(ValueError):
-        flow.evaluate_suite(nets, lanes, 1, mode="bogus")
 
 
 def test_lower_counts_are_plain_ints():

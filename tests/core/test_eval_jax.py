@@ -1,17 +1,17 @@
-"""JAX evaluators (fused single-jit engine + seed per-level dispatcher)
-vs the Python oracle."""
+"""The JAX evaluator (one envelope-grouped program; a single circuit is a
+suite of one) vs the Python oracle."""
 import random
 
 import numpy as np
 import pytest
 
+from repro.core import flow
 from repro.core.circuits import koios_mac_array, kratos_gemm, sha_like
 from repro.core.eval_jax import (_device_vals, _fill_pi_rows,
-                                 eval_netlist_jax, eval_netlist_jax_levels,
-                                 eval_netlists_batched_jax, plan_netlist,
+                                 eval_netlist_jax, plan_netlist,
                                  prepare_suite_program)
 from repro.core.flow import _lanes_int
-from repro.core.netlist import CONST1, bus_to_ints, eval_netlist
+from repro.core.netlist import CONST1, eval_netlist
 
 
 @pytest.mark.parametrize("mk", [
@@ -49,27 +49,34 @@ def test_eval_jax_multiword_lanes():
 
 
 def test_fused_matches_levels_dispatcher():
-    """The fused single-jit engine and the seed per-level dispatcher are
-    the same function of the same netlist."""
+    """The grouped program gives the oracle's value on every signal of a
+    circuit with carry chains, every lane word."""
     net = koios_mac_array(pes=2, width=4, ctrl_nodes=40)
     rng = random.Random(5)
     NW = 2
     lanes = {s: np.array([rng.getrandbits(32) for _ in range(NW)],
                          dtype=np.uint32) for s in net.pis}
-    fused = np.asarray(eval_netlist_jax(net, lanes, NW))
-    levels = np.asarray(eval_netlist_jax_levels(net, lanes, NW))
-    assert np.array_equal(fused, levels)
+    (got,), _ = flow.evaluate_suite([net], [lanes], NW)
+    ref = eval_netlist(net, {s: _lanes_int(v) for s, v in lanes.items()},
+                       32 * NW)
+    for s in range(net.n_signals):
+        assert _lanes_int(got[s]) == ref.get(s, 0), s
 
 
 def test_precompiled_plan_reuse():
+    """A prepared suite program evaluates fresh lanes call after call,
+    each call equal to a fresh evaluation, with nothing rebuilt."""
     net = kratos_gemm(m=3, n=3, width=4, sparsity=0.3)
-    plan = plan_netlist(net)
-    rng = random.Random(9)
-    lanes = {s: np.array([rng.getrandbits(32)], dtype=np.uint32)
-             for s in net.pis}
-    a = np.asarray(eval_netlist_jax(net, lanes, 1))
-    b = np.asarray(eval_netlist_jax(net, lanes, 1, plan=plan))
-    assert np.array_equal(a, b)
+    prog = prepare_suite_program([net])
+    (g,) = prog.programs
+    for seed in (9, 10):
+        lanes = flow.random_lanes(net, 1, seed=seed)
+        (a,) = prog.run([lanes], 1)
+        (b,), _ = flow.evaluate_suite([net], [lanes], 1, program=prog)
+        assert np.array_equal(a, eval_netlist_jax(net, lanes, 1))
+        assert np.array_equal(a, b)
+        assert flow.oracle_check(net, lanes, a, 1)
+    assert prog.programs[0] is g
 
 
 def test_batched_multi_circuit_eval():
@@ -83,7 +90,7 @@ def test_batched_multi_circuit_eval():
     lanes_list = [{s: np.array([rng.getrandbits(32) for _ in range(NW)],
                                dtype=np.uint32) for s in net.pis}
                   for net in nets]
-    outs = eval_netlists_batched_jax(nets, lanes_list, NW)
+    outs, _ = flow.evaluate_suite(nets, lanes_list, NW)
     for net, lanes, got in zip(nets, lanes_list, outs):
         single = np.asarray(eval_netlist_jax(net, lanes, NW))
         for bus in net.pos.values():
@@ -128,8 +135,7 @@ def test_grouped_eval_respects_max_groups_and_matches_single():
     NW = 1
     lanes_list = [{s: np.array([rng.getrandbits(32)], dtype=np.uint32)
                    for s in net.pis} for net in nets]
-    outs, stats = eval_netlists_batched_jax(nets, lanes_list, NW,
-                                            max_groups=2, return_stats=True)
+    outs, stats = flow.evaluate_suite(nets, lanes_list, NW, max_groups=2)
     assert stats["n_groups"] <= 2
     names = sorted(m for g in stats["groups"] for m in g["members"])
     assert names == sorted(n.name for n in nets)
@@ -204,3 +210,55 @@ def test_lanes_keyed_off_the_pis_raise(mode):
             prog.run(lanes_list, NW)
         else:
             eval_netlist_jax(nets[0], lanes_list[0], NW)
+
+
+def test_suite_stats_name_the_grouped_program():
+    """Whatever ran before, ``evaluate_suite`` runs the envelope-grouped
+    program and its stats say so: ``mode``, ``n_groups`` and per group
+    the padded LUT rows of its members, which the benchmark reads."""
+    nets = [kratos_gemm(m=3, n=3, width=4, sparsity=0.3),
+            sha_like(rounds=1)]
+    lanes_list = [flow.random_lanes(n, 1, seed=i)
+                  for i, n in enumerate(nets)]
+    for net, lanes in zip(nets, lanes_list):
+        eval_netlist_jax(net, lanes, 1)
+    outs, stats = flow.evaluate_suite(nets, lanes_list, 1, max_groups=1)
+    assert stats["mode"] == "grouped" and stats["n_groups"] == 1
+    (g,) = prepare_suite_program(nets, max_groups=1).programs
+    assert stats["groups"][0]["padded_lut_rows"] == sum(
+        p.padded_lut_rows for p in g.member_plans)
+    assert sum(plan_netlist(n).padded_lut_rows for n in nets) \
+        <= stats["groups"][0]["padded_lut_rows"]
+    for net, lanes, got in zip(nets, lanes_list, outs):
+        assert flow.oracle_check(net, lanes, got, 1)
+
+
+def test_same_netlist_twice_in_one_suite():
+    """One circuit listed twice is two members, each evaluated on its own
+    lanes."""
+    net = kratos_gemm(m=3, n=3, width=4, sparsity=0.3)
+    NW = 2
+    lanes_list = [flow.random_lanes(net, NW, seed=s) for s in (1, 2)]
+    outs, stats = flow.evaluate_suite([net, net], lanes_list, NW)
+    assert sum(len(g["members"]) for g in stats["groups"]) == 2
+    assert not np.array_equal(outs[0], outs[1])
+    for lanes, got in zip(lanes_list, outs):
+        assert np.array_equal(got, eval_netlist_jax(net, lanes, NW))
+        assert flow.oracle_check(net, lanes, got, NW)
+
+
+@pytest.mark.parametrize("NW", [1, 3, 128])
+def test_single_and_suite_entries_agree(NW):
+    """A circuit alone and the same circuit as a member of a two-circuit
+    group give the same value buffer, at lane widths below, across and at
+    the kernel's lane tile."""
+    nets = [kratos_gemm(m=3, n=3, width=4, sparsity=0.3),
+            koios_mac_array(pes=2, width=4, ctrl_nodes=40)]
+    lanes_list = [flow.random_lanes(n, NW, seed=7 + i)
+                  for i, n in enumerate(nets)]
+    outs, stats = flow.evaluate_suite(nets, lanes_list, NW, max_groups=1)
+    assert stats["n_groups"] == 1
+    for net, lanes, got in zip(nets, lanes_list, outs):
+        assert got.shape == (net.n_signals, NW)
+        assert np.array_equal(got, eval_netlist_jax(net, lanes, NW))
+    assert flow.oracle_check(nets[1], lanes_list[1], outs[1], NW)

@@ -11,8 +11,7 @@ from repro.core import flow
 from repro.core.alm import ARCHS
 from repro.core.circuits import kratos_gemm, sha_like
 from repro.core.equiv import reelaborate
-from repro.core.eval_jax import (eval_netlists_batched_jax,
-                                 group_plans_by_envelope,
+from repro.core.eval_jax import (eval_netlist_jax, group_plans_by_envelope,
                                  grouping_padded_value_rows, plan_netlist)
 from repro.core.netlist import CONST0, CONST1, Netlist
 from repro.core.packing import pack
@@ -61,7 +60,7 @@ def test_bucketed_eval_matches_oracle(seed):
     pallas kernel itself is proven in test_eval_jax / test_kernels."""
     net = random_netlist(seed)
     lanes = flow.random_lanes(net, 2, seed=seed)
-    vals = flow.evaluate_netlist(net, lanes, 2, use_pallas=False)
+    vals = eval_netlist_jax(net, lanes, 2, use_pallas=False)
     assert _oracle_po_match(net, lanes, vals, 2)
 
 
@@ -79,9 +78,8 @@ def test_grouped_eval_matches_oracle_and_single_envelope(seed, max_groups):
                                       use_pallas=False)
     assert stats["n_groups"] <= max_groups
     # the old single-worst-case-envelope path: one group, one bucket
-    outs_single = eval_netlists_batched_jax(nets, lanes, 2, max_groups=1,
-                                            max_buckets=1,
-                                            use_pallas=False)
+    outs_single, _ = flow.evaluate_suite(nets, lanes, 2, max_groups=1,
+                                         max_buckets=1, use_pallas=False)
     for net, ln, got, ref in zip(nets, lanes, outs, outs_single):
         assert np.array_equal(got, ref), net.name
         assert _oracle_po_match(net, ln, got, 2)

@@ -475,6 +475,26 @@ def test_combinational_eval_reads_q_as_a_free_input():
     assert int(outs[0][d1][0]) == 0b0110 ^ (0b1010 & 0b1100)
 
 
+def test_single_circuit_eval_reads_q_as_a_free_input(arrays):
+    """The single-circuit entry takes each Q's lanes like a primary
+    input's too: every signal of a 2x2 systolic array, its primary inputs
+    and registers' Q all driven, equals the oracle's instant."""
+    from repro.core.eval_jax import eval_netlist_jax
+    from repro.core.netlist import eval_netlist
+
+    net = arrays[2]
+    NW = 2
+    rng = np.random.default_rng(12)
+    lanes = {s: rng.integers(0, 2**32, NW, dtype=np.uint32)
+             for s in net.sources}
+    got = eval_netlist_jax(net, lanes, NW, use_pallas=False)
+    ref = eval_netlist(net, {s: flow._lanes_int(v) for s, v in lanes.items()},
+                       32 * NW)
+    assert len(net.sources) > len(net.pis)
+    for s in range(net.n_signals):
+        assert flow._lanes_int(got[s]) == ref.get(s, 0), s
+
+
 # ---------------------------------------------------------------------------
 # packing, timing and equivalence with registers
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ import pytest
 
 from repro.core import flow, plan
 from repro.core.circuits import kratos_gemm, sha_like, vtr_mixed
+from repro.core.eval_jax import eval_netlist_jax
 from repro.core.flow import _METRIC_KEYS, pack_and_analyze
 from repro.core.netlist import Netlist
 from repro.core.repack import cluster_delta, pack_prefix, repack
@@ -358,14 +359,14 @@ def test_cluster_delta_identical_and_disjoint():
 
 
 # ---------------------------------------------------------------------------
-# eval analysis + warm="auto" cost model
+# eval analysis
 # ---------------------------------------------------------------------------
 
 
 def test_serve_eval_matches_oracle_and_memoizes():
     net = sha_like(rounds=1)
     lanes = flow.random_lanes(net, 2, seed=0)
-    ref = flow.evaluate_netlist(net, lanes, 2)
+    ref = eval_netlist_jax(net, lanes, 2)
 
     async def main():
         server = FlowServer()
@@ -388,31 +389,6 @@ def test_serve_eval_matches_oracle_and_memoizes():
     assert stats["n_eval_hits"] == 1
     assert r1.record is None and "eval" in r1.analyses
     assert r0.record is None
-
-
-def test_eval_warm_auto_derives_from_actual_runs():
-    """The cost model's warm='auto' must charge a compile for a program
-    that never ran and none for one that did — derived from the
-    registry's run markers, not caller assertion."""
-    nets = [sha_like(rounds=1), kratos_gemm(m=4, n=4, width=5,
-                                            sparsity=0.5)]
-    model_cold = flow.eval_mode_cost_model(nets, warm="auto",
-                                           n_lane_words=2)
-    assert model_cold["n_cold_programs_grouped"] >= 1
-    assert model_cold["n_cold_programs_per_circuit"] == len(nets)
-    # run the grouped path once; its program signature is now marked
-    lanes = [flow.random_lanes(n, 2, seed=0) for n in nets]
-    flow.evaluate_suite(nets, lanes, 2, mode="grouped")
-    model_warm = flow.eval_mode_cost_model(nets, warm="auto",
-                                           n_lane_words=2)
-    assert model_warm["n_cold_programs_grouped"] == 0
-    # the per-circuit programs still never ran
-    assert model_warm["n_cold_programs_per_circuit"] == len(nets)
-    # forced overrides still win over the markers
-    forced = flow.eval_mode_cost_model(nets, warm=False, n_lane_words=2)
-    assert forced["n_cold_programs_grouped"] >= 1
-    with pytest.raises(ValueError, match="warm"):
-        flow.eval_mode_cost_model(nets, warm="yes")
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ import pytest
 from repro.core import flow
 from repro.core.alm import BASELINE, DD5
 from repro.core.circuits import kratos_gemm, vtr_mixed
+from repro.core.eval_jax import eval_netlist_jax
 from repro.core.packing import HOST_COUNTERS, pack
 from repro.core.plan import clear_caches
 from repro.core.spans import span
@@ -53,11 +54,11 @@ def _lanes(net, n_words, seed):
 
 @pytest.fixture(scope="module")
 def captured(tmp_path_factory):
-    """One profile of a tiny suite evaluation (both paths), a tiny
-    two-class sweep and a baseline and a DD5 pack of an adder circuit,
-    with the outputs each produced.  Every program compiles before the
-    capture starts: a capture that holds compiles takes minutes to
-    write."""
+    """One profile of a tiny suite evaluation (the suite entry and the
+    single-circuit entry), a tiny two-class sweep and a baseline and a DD5
+    pack of an adder circuit, with the outputs each produced.  Every
+    program compiles before the capture starts: a capture that holds
+    compiles takes minutes to write."""
     log_dir = str(tmp_path_factory.mktemp("profile"))
     nets = [vtr_mixed(name="t0", n_in=10, logic_nodes=30, adders=2,
                       add_width=6, seed=0)]
@@ -65,9 +66,11 @@ def captured(tmp_path_factory):
     gemm = kratos_gemm(name="g", m=4, n=4, width=4)
 
     def evaluate():
-        return {mode: flow.evaluate_suite(nets, lanes, 4, use_pallas=False,
-                                          mode=mode, max_buckets=1)
-                for mode in ("grouped", "per_circuit")}
+        outs, stats = flow.evaluate_suite(nets, lanes, 4, use_pallas=False,
+                                          max_buckets=1)
+        return {"suite": (outs, stats),
+                "single": ([eval_netlist_jax(n, ln, 4, use_pallas=False)
+                            for n, ln in zip(nets, lanes)], None)}
 
     def sweep():
         return sweep_suite([gemm], [BASELINE, DD5], backend="jax")
@@ -113,16 +116,14 @@ def test_spans_land_in_the_profile_with_their_stats(captured, name, keys):
 
 def test_eval_spans_count_the_call(captured):
     nets = captured["nets"]
-    calls = _stats(captured, "repro.eval.call")
-    assert len(calls) == 2
-    for st in calls:
-        assert st["circuits"] == 1 and st["lane_words"] == 4
+    (call,) = _stats(captured, "repro.eval.call")
+    assert call["circuits"] == 1 and call["lane_words"] == 4
     moved = {name: sum(st["bytes"] for st in _stats(captured, name))
              for name in ("repro.eval.fill", "repro.eval.put",
                           "repro.eval.get")}
-    # both calls send only the primary inputs' lanes, 4 words of 4 bytes
-    # each (one circuit per group, so no padded PI slot), and get back
-    # every signal
+    # both entries send only the primary inputs' lanes, 4 words of 4
+    # bytes each (one circuit per group, so no padded PI slot), and get
+    # back every signal
     assert moved["repro.eval.put"] == moved["repro.eval.fill"] \
         == 2 * sum(len(n.pis) for n in nets) * 16
     assert moved["repro.eval.get"] >= 2 * sum(n.n_signals for n in nets) * 16
@@ -130,8 +131,9 @@ def test_eval_spans_count_the_call(captured):
 
 def test_spans_change_no_output(captured):
     nets, lanes = captured["nets"], captured["lanes"]
-    for mode, (outs, stats) in captured["outs"].items():
-        assert stats["mode"] == mode
+    for entry, (outs, stats) in captured["outs"].items():
+        if entry == "suite":
+            assert stats["mode"] == "grouped"
         for net, ln, vals in zip(nets, lanes, outs):
             assert flow.oracle_check(net, ln, vals, 4)
     assert oracle_parity(captured["sweep"], [captured["gemm"]],

@@ -51,32 +51,49 @@ def test_popcount_matmul_matches_integer_dot():
 
 
 # ---------------------------------------------------------------------------
-# lut_eval
+# lut_eval6 on k-input LUTs, laid out as the evaluator's plan lays them
 # ---------------------------------------------------------------------------
+
+
+def _k_input_layout(ins_k, tts_k, k):
+    """``[M, 6, N]`` lanes and (lo, hi) table words of k-input LUTs: the
+    pins above ``k`` read 0 (``CONST0``) and each table is replicated to
+    64 entries (:func:`repro.core.netlist.tt_words64`)."""
+    from repro.core.netlist import tt_words64
+
+    m, _, n = ins_k.shape
+    ins = np.zeros((m, 6, n), dtype=np.uint32)
+    ins[:, :k] = ins_k
+    words = np.array([tt_words64(int(t), k) for t in tts_k], dtype=np.uint32)
+    return ins, words[:, 0], words[:, 1]
 
 
 @pytest.mark.parametrize("m,k,nlanes", [(8, 2, 4), (64, 3, 16), (300, 4, 8),
                                         (1000, 5, 2)])
 def test_lut_eval(m, k, nlanes):
     r = rng(m + k)
-    ins = r.integers(0, 2**32, size=(m, k, nlanes), dtype=np.uint32)
+    ins_k = r.integers(0, 2**32, size=(m, k, nlanes), dtype=np.uint32)
     tts = r.integers(0, 2**(2**k), size=(m,),
                      dtype=np.uint64).astype(np.uint32) \
         if k < 5 else r.integers(0, 2**32, size=(m,), dtype=np.uint32)
-    got = ops.lut_eval(jnp.asarray(ins), jnp.asarray(tts))
-    want = ref.lut_eval_ref(jnp.asarray(ins), jnp.asarray(tts))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ins, lo, hi = _k_input_layout(ins_k, tts, k)
+    got = ops.lut_eval6(jnp.asarray(ins), jnp.asarray(lo), jnp.asarray(hi))
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.lut_eval6_ref(
+            jnp.asarray(ins), jnp.asarray(lo), jnp.asarray(hi))))
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.lut_eval_ref(jnp.asarray(ins_k),
+                                                     jnp.asarray(tts))))
 
 
 def test_lut_eval_known_functions():
-    # AND2 / XOR2 bit-parallel
-    ins = np.zeros((2, 2, 1), dtype=np.uint32)
-    ins[0, 0, 0] = 0b1100
-    ins[0, 1, 0] = 0b1010
-    ins[1, 0, 0] = 0b1100
-    ins[1, 1, 0] = 0b1010
-    tts = np.array([0b1000, 0b0110], dtype=np.uint32)  # AND2, XOR2
-    got = np.asarray(ops.lut_eval(jnp.asarray(ins), jnp.asarray(tts)))
+    # AND2 / XOR2 bit-parallel, as 2-input rows of the 6-pin layout
+    ins_k = np.zeros((2, 2, 1), dtype=np.uint32)
+    ins_k[:, 0, 0] = 0b1100
+    ins_k[:, 1, 0] = 0b1010
+    ins, lo, hi = _k_input_layout(ins_k, [0b1000, 0b0110], 2)  # AND2, XOR2
+    got = np.asarray(ops.lut_eval6(jnp.asarray(ins), jnp.asarray(lo),
+                                   jnp.asarray(hi)))
     assert got[0, 0] == 0b1000
     assert got[1, 0] == 0b0110
 
