@@ -536,24 +536,48 @@ class SuiteProgram:
 
     def run(self, pi_lanes_list: list[dict[int, np.ndarray]],
             n_lane_words: int, use_pallas: bool = True) -> list[np.ndarray]:
-        outs: list = [None] * len(self.n_signals)
-        for members, g in zip(self.groups, self.programs):
+        """Each circuit's whole ``[n_signals, N]`` value buffer, on the
+        host, in input order.
+
+        The groups are pipelined: every group is filled, put and
+        dispatched, its result's copy to the host started at once, before
+        any result is fetched, so one group's copy back overlaps the device
+        runs of the groups after it.  The smallest buffer goes first
+        (Johnson's rule for two stages, the device run and the copy back,
+        both about proportional to a group's buffer rows, the copy the
+        longer): the link starts soonest and only the largest copy is left
+        once the device is done.
+
+        Spans: ``repro.eval.put`` until a group's PI rows are on the
+        device; ``repro.eval.run`` around each dispatch and, when results
+        are fetched, around the host's wait until that group's result is
+        ready; ``repro.eval.get`` the wait for the rest of its copy
+        (``bytes`` the buffer, ``queued`` the groups dispatched and not yet
+        fetched, this one included).
+        """
+        order = sorted(range(len(self.groups)), key=lambda k: len(
+            self.groups[k]) * (self.programs[k].n_sig + 1))
+        pending = []
+        for k in order:
+            members, g = self.groups[k], self.programs[k]
             rows = _put(_fill_pi_rows(
                 g.pi_slots, [pi_lanes_list[i] for i in members],
                 g.pi_index.shape[1], n_lane_words))
-            # the program consumes its input right away, and np.asarray
-            # blocks on the result: the two block_until_ready calls move
-            # no work, they only bound the transfer and the device run
             with span("repro.eval.run"):
                 vals = _device_vals(g.pi_index, rows, n_rows=g.n_sig + 1)
                 out = _run_fused_batch(vals, g.stacked, flags=g.flags,
-                                       use_pallas=use_pallas
-                                       ).block_until_ready()
-            with span("repro.eval.get") as sp:
-                out = np.asarray(out)
-                sp.set(bytes=out.nbytes)
+                                       use_pallas=use_pallas)
+                out.copy_to_host_async()
+            pending.append((members, out))
+        outs: list = [None] * len(self.n_signals)
+        for j, (members, out) in enumerate(pending):
+            with span("repro.eval.run"):
+                out.block_until_ready()
+            with span("repro.eval.get", queued=len(pending) - j) as sp:
+                host = np.asarray(out)
+                sp.set(bytes=host.nbytes)
             for row, i in enumerate(members):
-                outs[i] = out[row, :self.n_signals[i]]
+                outs[i] = host[row, :self.n_signals[i]]
         return outs
 
 

@@ -1,5 +1,6 @@
 """The JAX evaluator (one envelope-grouped program; a single circuit is a
 suite of one) vs the Python oracle."""
+import dataclasses
 import random
 
 import numpy as np
@@ -262,3 +263,48 @@ def test_single_and_suite_entries_agree(NW):
         assert got.shape == (net.n_signals, NW)
         assert np.array_equal(got, eval_netlist_jax(net, lanes, NW))
     assert flow.oracle_check(nets[1], lanes_list[1], outs[1], NW)
+
+
+def _unequal_groups(order):
+    """Four circuits in three envelope groups whose value buffers differ
+    in size (one group of two), listed in ``order``, on fresh lanes."""
+    nets = [kratos_gemm(m=3, n=3, width=4, sparsity=0.3),
+            sha_like(rounds=1),
+            koios_mac_array(pes=2, width=4, ctrl_nodes=40),
+            kratos_gemm(m=4, n=4, width=4, sparsity=0.5, seed=7)]
+    nets = [nets[i] for i in order]
+    NW = 2
+    lanes_list = [flow.random_lanes(n, NW, seed=20 + i)
+                  for i, n in enumerate(nets)]
+    prog = prepare_suite_program(nets, max_groups=3)
+    rows = [len(m) * (g.n_sig + 1)
+            for m, g in zip(prog.groups, prog.programs)]
+    assert len(prog.groups) == 3 and len(set(rows)) == 3
+    return nets, lanes_list, NW, prog
+
+
+@pytest.mark.parametrize("order", [(0, 1, 2, 3), (3, 2, 1, 0),
+                                   (2, 0, 3, 1)])
+def test_pipelined_groups_equal_the_serial_order(order):
+    """All groups dispatched before any result is fetched give, for every
+    circuit and in input order, exactly the buffer each group gives run
+    alone, and the oracle's value on every signal, whichever way the
+    input order and the dispatch order (smallest buffer first) differ."""
+    nets, lanes_list, NW, prog = _unequal_groups(order)
+    outs = prog.run(lanes_list, NW, use_pallas=False)
+    serial = [None] * len(nets)
+    for members, g in zip(prog.groups, prog.programs):
+        alone = dataclasses.replace(prog, groups=[members], programs=[g])
+        got = alone.run(lanes_list, NW, use_pallas=False)
+        for i in members:
+            serial[i] = got[i]
+    for net, lanes, got, want in zip(nets, lanes_list, outs, serial):
+        assert isinstance(got, np.ndarray)
+        assert got.shape == (net.n_signals, NW)
+        assert np.array_equal(got, want), net.name
+        assert np.array_equal(
+            got, eval_netlist_jax(net, lanes, NW, use_pallas=False))
+        ref = eval_netlist(net, {s: _lanes_int(v) for s, v in lanes.items()},
+                           32 * NW)
+        for s in range(net.n_signals):
+            assert _lanes_int(got[s]) == ref.get(s, 0), (net.name, s)

@@ -11,7 +11,7 @@ import pytest
 from repro.core import flow
 from repro.core.alm import BASELINE, DD5
 from repro.core.circuits import kratos_gemm, vtr_mixed
-from repro.core.eval_jax import eval_netlist_jax
+from repro.core.eval_jax import eval_netlist_jax, prepare_suite_program
 from repro.core.packing import HOST_COUNTERS, pack
 from repro.core.plan import clear_caches
 from repro.core.spans import span
@@ -101,7 +101,7 @@ def _stats(captured, name):
     ("repro.eval.fill", {"bytes"}),
     ("repro.eval.put", {"bytes"}),
     ("repro.eval.run", set()),
-    ("repro.eval.get", {"bytes"}),
+    ("repro.eval.get", {"bytes", "queued"}),
     ("repro.pack.cluster", {"atoms", "lbs", *HOST_COUNTERS}),
     ("repro.ir.lower", {"incremental"}),
     ("repro.timing.build", {"groups"}),
@@ -127,6 +127,58 @@ def test_eval_spans_count_the_call(captured):
     assert moved["repro.eval.put"] == moved["repro.eval.fill"] \
         == 2 * sum(len(n.pis) for n in nets) * 16
     assert moved["repro.eval.get"] >= 2 * sum(n.n_signals for n in nets) * 16
+
+
+def test_a_suite_of_one_fetches_one_group(captured):
+    """Both entries evaluate one group: each call dispatches it, waits for
+    it and fetches it, one group in flight."""
+    assert [st["queued"] for st in _stats(captured, "repro.eval.get")] \
+        == [1, 1]
+    assert len(_stats(captured, "repro.eval.run")) == 2 * 2
+
+
+@pytest.fixture(scope="module")
+def captured_groups(tmp_path_factory):
+    """One profile of one call of a suite in three envelope groups of
+    unequal buffer sizes, compiled before the capture."""
+    log_dir = str(tmp_path_factory.mktemp("profile_groups"))
+    nets = [vtr_mixed(name=f"v{i}", n_in=8 + 2 * i, logic_nodes=12 + 10 * i,
+                      adders=1 + i % 2, add_width=4 + i, seed=i)
+            for i in range(4)]
+    lanes = [_lanes(n, 4, 10 + i) for i, n in enumerate(nets)]
+    prog = prepare_suite_program(nets, max_groups=3, max_buckets=1)
+
+    def evaluate():
+        return flow.evaluate_suite(nets, lanes, 4, use_pallas=False,
+                                   program=prog)[0]
+
+    evaluate()
+    jax.profiler.start_trace(log_dir)
+    try:
+        outs = evaluate()
+    finally:
+        jax.profiler.stop_trace()
+    return {"events": _repro_events(log_dir), "nets": nets, "lanes": lanes,
+            "prog": prog, "outs": outs}
+
+
+def test_pipelined_gets_count_the_groups_in_flight(captured_groups):
+    """Every group is dispatched before the first get: the gets carry
+    ``queued`` 3, 2, 1, in the dispatch order (the smallest buffer
+    first), and together still bring back every signal of every member."""
+    prog = captured_groups["prog"]
+    rows = sorted(len(m) * (g.n_sig + 1)
+                  for m, g in zip(prog.groups, prog.programs))
+    assert len(rows) == 3 and len(set(rows)) == 3
+    gets = _stats(captured_groups, "repro.eval.get")
+    assert [st["queued"] for st in gets] == [3, 2, 1]
+    assert [st["bytes"] for st in gets] == [r * 4 * 4 for r in rows]
+    assert len(_stats(captured_groups, "repro.eval.run")) == 2 * 3
+    for net, ln, vals in zip(captured_groups["nets"],
+                             captured_groups["lanes"],
+                             captured_groups["outs"]):
+        assert vals.shape == (net.n_signals, 4)
+        assert flow.oracle_check(net, ln, vals, 4)
 
 
 def test_spans_change_no_output(captured):
